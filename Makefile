@@ -32,10 +32,10 @@ export PYTHONPATH := src
 .PHONY: test test-fast bench-smoke swarm-smoke specs docs lint
 
 test:
-	$(PY) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
 
 test-fast:
-	$(PY) -m pytest -q -m "not slow"
+	JAX_PLATFORMS=cpu $(PY) -m pytest -q -m "not slow"
 
 bench-smoke:
 	$(PY) benchmarks/estimator_sweep.py --smoke --preset bench-smoke

@@ -16,7 +16,11 @@ import numpy as np  # noqa: E402
 from repro.configs import opt  # noqa: E402
 from repro.core import zo  # noqa: E402
 from repro.data import synthetic  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
 from repro.models import lm  # noqa: E402
+
+# every benchmark script imports this module before its first compile
+compile_cache.enable()
 
 
 def timeit(fn, *args, warmup=2, iters=5):

@@ -83,7 +83,9 @@ def _swarm_run(spec: api.Experiment, root: Path) -> dict:
 
 
 def run(smoke: bool = False, json_path: str = None, check: bool = False):
-    from repro.swarm import shardstep
+    from repro.swarm import driver, shardstep
+    # this parent coordinates; only its worker processes may hold chips
+    driver.pin_parent_to_cpu()
     steps = 6 if smoke else 10
     spec = _base_spec(steps)
     fo_bytes = 4 * shardstep.trainable_param_count(spec)

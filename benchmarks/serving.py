@@ -202,8 +202,7 @@ def run(smoke: bool = False, json_path=None, preset: str = "bench-smoke",
     results = engine.run(reqs)
     dt_engine = time.perf_counter() - t0
     lat_engine = [r.t_done - r.t_submit for r in results]
-    # -1 = old jax without _cache_size(); the promise is unobservable then
-    assert engine.n_compiles() in (2, -1), "bucket promise broken"
+    assert engine.n_compiles() == 2, "bucket promise broken"
 
     dt_lock, lat_lock = lockstep(reqs)
 

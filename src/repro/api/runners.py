@@ -68,7 +68,7 @@ def derive(spec: Experiment) -> Derived:
     zo_cfg = zo.ZOConfig(
         eps=o.eps, lr=o.lr, n_drop=n_drop, policy=o.policy,
         backend=rt.backend, fused_update=o.fused_update,
-        weight_decay=o.weight_decay, interpret=rt.interpret,
+        weight_decay=o.weight_decay,
         forward_backend=rt.forward_backend,
         paired_probes=rt.paired_probes)
     est_cfg = estimators.from_zo(zo_cfg, name=e.name, q=e.q,

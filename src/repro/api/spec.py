@@ -95,7 +95,6 @@ class Runtime:
     # stack the virtual ±εz pair (and one_sided's q-chunks) onto one
     # paired fused forward — bit-identical floats, half the W-tile loads
     paired_probes: bool = True
-    interpret: bool = True        # axpy pallas interpret mode (CPU container)
     mesh: str = "single"          # single | multi_pod (dryrun/sharded lowering)
     n_loss_shards: int = 1
     quorum: float = 1.0

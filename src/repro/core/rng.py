@@ -70,10 +70,10 @@ def fold_py(seed: int, data: int) -> int:
 
 def _uniform01(bits):
     """uint32 -> float32 uniform in (0, 1]; never 0 so log() is safe."""
-    # Take the top 24 bits -> [0, 2^24), scale to (0,1].
-    return (jnp.asarray(bits >> np.uint32(8), jnp.float32) + 1.0) * np.float32(
-        1.0 / 16777216.0
-    )
+    # Take the top 24 bits -> [0, 2^24), scale to (0,1].  The value fits
+    # int32 exactly, and Mosaic casts int32 (not uint32) to float32.
+    top = (bits >> np.uint32(8)).astype(jnp.int32)
+    return (top.astype(jnp.float32) + 1.0) * np.float32(1.0 / 16777216.0)
 
 
 def counter_normal(seed, counters):

@@ -192,7 +192,7 @@ def uniform_select(spec: ZOSpec, seed, n_drop: int):
 
 # ----------------------------------------------------------------- axpy
 def tree_axpy(params, spec: ZOSpec, seed, scale, masks, idxs=None, *,
-              decay=1.0, backend="dense", interpret=True):
+              decay=1.0, backend="dense"):
     """theta <- decay*theta + scale*z on active layers, identity elsewhere."""
     obs.get_tracer().count(obs.CTR_AXPY)
     leaves, treedef = jax.tree_util.tree_flatten(params)
@@ -203,7 +203,7 @@ def tree_axpy(params, spec: ZOSpec, seed, scale, masks, idxs=None, *,
         aidx = None if (group is None or idxs is None) else idxs[group]
         out.append(kops.zo_axpy(
             leaf, path=path, seed=seed, scale=scale, decay=decay,
-            mask=mask, active_idx=aidx, backend=backend, interpret=interpret))
+            mask=mask, active_idx=aidx, backend=backend))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -271,7 +271,6 @@ class ZOConfig:
     backend: str = "dense"        # dense | scan | gather | pallas
     fused_update: bool = True     # beyond-paper single restore+update pass
     weight_decay: float = 0.0
-    interpret: bool = True        # pallas interpret mode (CPU container)
     # materialized = classic perturb/forward/restore sweeps;
     # virtual[_ref] = fused forward regenerates z in-kernel, the step is
     # 2 forwards + 1 update axpy with zero perturb/restore writes

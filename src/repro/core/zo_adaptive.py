@@ -47,7 +47,6 @@ class ZOMomentumConfig:
     adam: bool = False            # scale by 1/sqrt(v) of projected grads
     adam_beta2: float = 0.999
     adam_eps: float = 1e-8
-    interpret: bool = True
 
 
 def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
@@ -69,8 +68,7 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
     est = estimators.build_estimator(
         spec, estimators.EstimatorConfig(
             name="two_point", eps=cfg.eps, lr=cfg.lr, n_drop=cfg.n_drop,
-            policy="stratified", backend=cfg.backend, fused_update=False,
-            interpret=cfg.interpret))
+            policy="stratified", backend=cfg.backend, fused_update=False))
 
     def init_state():
         return {"g_hist": jnp.zeros((K,), jnp.float32),
@@ -81,8 +79,7 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
         seed = rng.fold(jnp.asarray(base_seed, jnp.uint32),
                         jnp.asarray(step_idx, jnp.uint32))
         ax = lambda p, s, sd, m, i: zo.tree_axpy(
-            p, spec, sd, s, m, i, backend=cfg.backend,
-            interpret=cfg.interpret)
+            p, spec, sd, s, m, i, backend=cfg.backend)
 
         # SPSA probe + immediate restore (unfused: momentum owns the update)
         p, dirs, em = est.estimate(loss_fn, params, batch, seed, state)

@@ -65,7 +65,6 @@ def from_zo(zo_cfg, name: str = "two_point", q: int = 1,
         name=name, eps=zo_cfg.eps, lr=zo_cfg.lr, q=q, n_drop=zo_cfg.n_drop,
         policy=zo_cfg.policy, backend=zo_cfg.backend,
         fused_update=zo_cfg.fused_update, weight_decay=zo_cfg.weight_decay,
-        interpret=zo_cfg.interpret,
         forward_backend=getattr(zo_cfg, "forward_backend", "materialized"),
         paired_probes=getattr(zo_cfg, "paired_probes", True),
         **kw)

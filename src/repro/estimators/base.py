@@ -52,8 +52,6 @@ class EstimatorConfig:
     backend: str = "dense"        # dense | scan | gather | pallas
     fused_update: bool = True
     weight_decay: float = 0.0
-    interpret: bool = True        # pallas interpret mode for the *axpy*
-                                  # kernels (fused forwards auto-detect)
     inner: str = "two_point"      # estimator the importance wrapper drives
     importance_decay: float = 0.99  # EMA for the per-layer |g| scores
     # materialized | virtual | virtual_ref — virtual probes evaluate
@@ -142,8 +140,7 @@ class Estimator:
     # ------------------------------------------------------------- axpy
     def _ax(self, p, scale, seed, masks, idxs, decay=1.0, backend=None):
         return zo.tree_axpy(p, self.spec, seed, scale, masks, idxs,
-                            decay=decay, backend=backend or self.cfg.backend,
-                            interpret=self.cfg.interpret)
+                            decay=decay, backend=backend or self.cfg.backend)
 
     # -------------------------------------------------- virtual probing
     @property
@@ -152,12 +149,9 @@ class Estimator:
 
     def _vloss(self, loss_fn, params, batch, seed, scale, masks):
         """Probe loss(theta + scale*z(seed)) with zero parameter writes:
-        the fused forward regenerates z in its kernels (repro.fused).
-        ``interpret=None`` lets the kernel auto-detect the platform
-        (cfg.interpret governs only the axpy sweeps)."""
+        the fused forward regenerates z in its kernels (repro.fused)."""
         from repro import fused  # local: fused must stay import-light here
-        ctx = fused.make_ctx(seed, scale, masks, self.cfg.forward_backend,
-                             interpret=None)
+        ctx = fused.make_ctx(seed, scale, masks, self.cfg.forward_backend)
         return loss_fn(params, batch, perturb=ctx)
 
     def _vloss_pair(self, loss_fn, params, batch, seed, eps, masks):
@@ -167,7 +161,7 @@ class Estimator:
         regenerated once for the pair (fused.make_pair_ctx)."""
         from repro import fused
         ctx = fused.make_pair_ctx(seed, eps, masks,
-                                  self.cfg.forward_backend, interpret=None)
+                                  self.cfg.forward_backend)
         return loss_fn(params, batch, perturb=ctx)
 
     def _vloss_stack(self, loss_fn, params, batch, seeds, scales, masks):
@@ -177,7 +171,7 @@ class Estimator:
         vmapped per-probe path, one pass over W."""
         from repro import fused
         ctx = fused.make_stack_ctx(seeds, scales, masks,
-                                   self.cfg.forward_backend, interpret=None)
+                                   self.cfg.forward_backend)
         return loss_fn(params, batch, perturb=ctx)
 
     # --------------------------------------------------------- protocol

@@ -67,7 +67,7 @@ class OneSidedBatched(Estimator):
                 return self._vloss(loss_fn, params, batch, seed_i,
                                    cfg.eps, masks_i)
             p = zo.tree_axpy(params, self.spec, seed_i, cfg.eps, masks_i,
-                             None, backend="dense", interpret=cfg.interpret)
+                             None, backend="dense")
             return loss_fn(p, batch)
 
         chunk = cfg.q_chunk if 0 < cfg.q_chunk < q else q

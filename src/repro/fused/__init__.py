@@ -18,7 +18,8 @@ parameter copies).
 Pieces:
   * ``ref``      — pure-JAX oracle + the z-consistency contract with
                    ``kernels/ops.py`` (same streams as the axpy sweeps).
-  * ``pmatmul``  — the Pallas TPU kernel (interpret-mode CPU fallback).
+  * ``pmatmul``  — the Pallas TPU kernel (the Pallas interpreter runs it
+                   off the TPU).
   * ``view``     — PerturbCtx / LayerPerturb lens the model forward
                    consumes (``lm.lm_loss(..., perturb=ctx)``).
   * ``sharded``  — shard_map wrappers with global counter offsets.
@@ -33,12 +34,12 @@ import jax.numpy as jnp
 
 from repro.estimators.costs import FORWARD_BACKENDS
 from repro.fused import ref
-from repro.fused.matmul import default_interpret, pmatmul, pmatmul_stack
+from repro.fused.matmul import pmatmul, pmatmul_stack
 from repro.fused.sharded import pmatmul_col_sharded, pmatmul_row_sharded
 from repro.fused.view import IMPLS, LayerPerturb, PerturbCtx, ProbePair
 
 __all__ = ["FORWARD_BACKENDS", "IMPLS", "LayerPerturb", "PerturbCtx",
-           "ProbePair", "default_interpret", "make_ctx", "make_pair_ctx",
+           "ProbePair", "make_ctx", "make_pair_ctx",
            "make_stack_ctx", "pmatmul", "pmatmul_col_sharded",
            "pmatmul_row_sharded", "pmatmul_stack", "ref"]
 
@@ -51,18 +52,14 @@ def _impl_of(forward_backend: str) -> str:
     return "ref" if forward_backend == "virtual_ref" else "pallas"
 
 
-def make_ctx(seed, scale, masks, forward_backend: str,
-             interpret=None) -> PerturbCtx:
-    """Build the perturbation lens for one probe of ``forward_backend``.
-    ``interpret=None`` auto-detects the platform (compiled on TPU)."""
+def make_ctx(seed, scale, masks, forward_backend: str) -> PerturbCtx:
+    """Build the perturbation lens for one probe of ``forward_backend``."""
     return PerturbCtx(seed=jnp.asarray(seed, jnp.uint32),
                       scale=jnp.asarray(scale, jnp.float32),
-                      masks=masks, impl=_impl_of(forward_backend),
-                      interpret=interpret)
+                      masks=masks, impl=_impl_of(forward_backend))
 
 
-def make_pair_ctx(seed, eps, masks, forward_backend: str,
-                  interpret=None) -> PerturbCtx:
+def make_pair_ctx(seed, eps, masks, forward_backend: str) -> PerturbCtx:
     """The antithetic ±εz pair as ONE stacked ctx: probe 0 is +eps,
     probe 1 is -eps, both drawing the identical z stream (shared seed) —
     the fused forward loads every W tile and regenerates every z tile
@@ -75,12 +72,10 @@ def make_pair_ctx(seed, eps, masks, forward_backend: str,
     return PerturbCtx(seed=jnp.stack([s, s]),
                       scale=jnp.stack([e, -e]),
                       masks=sm, impl=_impl_of(forward_backend),
-                      interpret=interpret,
                       pair=ProbePair(n=2, shared_seed=True))
 
 
-def make_stack_ctx(seeds, scales, masks, forward_backend: str,
-                   interpret=None) -> PerturbCtx:
+def make_stack_ctx(seeds, scales, masks, forward_backend: str) -> PerturbCtx:
     """P independent probes stacked on one forward (one_sided's q
     probes): ``seeds``/``scales`` are (P,) vectors, ``masks`` maps group
     -> (P, L_g).  W tiles are loaded once for all P probes; z streams
@@ -91,5 +86,4 @@ def make_stack_ctx(seeds, scales, masks, forward_backend: str,
                       scale=jnp.broadcast_to(
                           jnp.asarray(scales, jnp.float32), (P,)),
                       masks=masks, impl=_impl_of(forward_backend),
-                      interpret=interpret,
                       pair=ProbePair(n=P, shared_seed=False))

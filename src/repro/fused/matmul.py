@@ -42,25 +42,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng
+from repro.kernels import resolve_interpret
 
 # (8, 128)-aligned f32 tiles; 3 buffers * 64 KiB leaves plenty of VMEM
 # headroom double-buffered.
 BLOCK_M = 128
 BLOCK_N = 128
 BLOCK_K = 128
-
-
-def default_interpret() -> bool:
-    """Pallas interpret-mode default: compiled kernels on a real TPU,
-    the interpreter everywhere else (CPU containers, CI)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover - jax not initialized yet
-        return True
-
-
-def _resolve_interpret(interpret):
-    return default_interpret() if interpret is None else bool(interpret)
 
 
 def grid_cells(M: int, K: int, N: int, block_m: int = BLOCK_M,
@@ -127,9 +115,9 @@ def pmatmul(x, w, seed, scale, active=None, *, trans=False, ld=None,
     scalar bool LeZO predicate (None = always on).  ``ld``/``trans``/
     ``row_off``/``col_off`` define the counter window into the stored
     leaf (see module docstring); oracle: ``fused.ref.pmatmul``.
-    ``interpret=None`` auto-detects the platform (compiled on TPU).
+    ``interpret=None`` follows the platform (compiled on TPU).
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[1]
@@ -247,7 +235,7 @@ def pmatmul_stack(x, w, seeds, scales, active=None, *, trans=False, ld=None,
     regenerates each z tile once.  Counter-window args as in
     :func:`pmatmul`; oracle: ``fused.ref.pmatmul_stack``.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     P = x.shape[0]
     lead = x.shape[1:-1]
     K = x.shape[-1]

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fused.matmul import pmatmul
@@ -34,8 +33,7 @@ def _rep(ndim: int) -> P:
     return P(*([None] * ndim))
 
 
-def pmatmul_col_sharded(mesh, x, w, seed, scale, active, *, axis="model",
-                        interpret=None):
+def pmatmul_col_sharded(mesh, x, w, seed, scale, active, *, axis="model"):
     """Column-parallel fused matmul: w (K, N) sharded on N over ``axis``,
     x replicated, output sharded on its last dim."""
     N = w.shape[1]
@@ -43,20 +41,18 @@ def pmatmul_col_sharded(mesh, x, w, seed, scale, active, *, axis="model",
 
     def local(x_, w_, seed_, scale_, active_):
         c0 = (jax.lax.axis_index(axis) * shard_n).astype(jnp.uint32)
-        return pmatmul(x_, w_, seed_, scale_, active_, col_off=c0, ld=N,
-                       interpret=interpret)
+        return pmatmul(x_, w_, seed_, scale_, active_, col_off=c0, ld=N)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(_rep(x.ndim), P(None, axis), P(), P(), P()),
         out_specs=P(*([None] * (x.ndim - 1)), axis),
-        check_rep=False,
+        check_vma=False,
     )(x, w, jnp.asarray(seed, jnp.uint32), jnp.asarray(scale, jnp.float32),
       jnp.asarray(active, jnp.bool_))
 
 
-def pmatmul_row_sharded(mesh, x, w, seed, scale, active, *, axis="model",
-                        interpret=None):
+def pmatmul_row_sharded(mesh, x, w, seed, scale, active, *, axis="model"):
     """Row-parallel fused matmul: w (K, N) sharded on K over ``axis``,
     x sharded on its last dim, partial products all-reduced."""
     K, N = w.shape
@@ -64,15 +60,14 @@ def pmatmul_row_sharded(mesh, x, w, seed, scale, active, *, axis="model",
 
     def local(x_, w_, seed_, scale_, active_):
         r0 = (jax.lax.axis_index(axis) * shard_k).astype(jnp.uint32)
-        part = pmatmul(x_, w_, seed_, scale_, active_, row_off=r0, ld=N,
-                       interpret=interpret)
+        part = pmatmul(x_, w_, seed_, scale_, active_, row_off=r0, ld=N)
         return jax.lax.psum(part, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(*([None] * (x.ndim - 1)), axis), P(axis, None),
                   P(), P(), P()),
         out_specs=_rep(x.ndim),
-        check_rep=False,
+        check_vma=False,
     )(x, w, jnp.asarray(seed, jnp.uint32), jnp.asarray(scale, jnp.float32),
       jnp.asarray(active, jnp.bool_))

@@ -11,7 +11,7 @@ reproduce the exact per-leaf z streams of the axpy sweeps
 (fused/ref.py's z-consistency contract).
 
 ``impl="pallas"`` routes matmuls through the fused kernel
-(fused/matmul.py; ``interpret=None`` auto-detects the platform);
+(fused/matmul.py; Pallas interpret mode follows the platform);
 ``impl="ref"`` uses the pure-JAX oracle — same floats, ordinary XLA ops,
 shards under pjit.  Vector-sized leaves (norm scale/bias) always use the
 oracle: an O(D) temp is activation-sized, and a kernel launch would cost
@@ -67,7 +67,6 @@ class PerturbCtx:
     scale: Any                      # traced f32 sign*eps — scalar | (P,)
     masks: Optional[Dict[str, Any]]  # group -> (L_g,) | (P, L_g) bool
     impl: str = "pallas"            # pallas | ref      (static)
-    interpret: Optional[bool] = None  # pallas interpret (None = auto)
     pair: Optional[ProbePair] = None  # stacked-probe descriptor (static)
 
     def group_mask(self, group: str, L: int):
@@ -166,8 +165,7 @@ class LayerPerturb:
                 return fref.pmatmul(x, w, seed, self.ctx.scale, self.active,
                                     trans=trans, ld=ld)
             return pk.pmatmul(x, w, seed, self.ctx.scale, self.active,
-                              trans=trans, ld=ld,
-                              interpret=self.ctx.interpret)
+                              trans=trans, ld=ld)
         lead = x.shape
         xs = self._split(x)
         _count_tiles(self.ctx, xs.shape[1], w.shape[0], w.shape[1])
@@ -177,7 +175,6 @@ class LayerPerturb:
         else:
             out = pk.pmatmul_stack(xs, w, seed, self.ctx.scale, self.active,
                                    trans=trans, ld=ld,
-                                   interpret=self.ctx.interpret,
                                    shared_seed=self.ctx.pair.shared_seed)
         return out.reshape(*lead[:-1], w.shape[1])
 

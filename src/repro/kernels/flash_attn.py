@@ -1,4 +1,4 @@
-"""Pallas flash-attention forward kernel (TPU target, interpret-validated).
+"""Pallas flash-attention forward kernel (TPU target).
 
 The forward hot spot of every assigned dense arch.  Grid =
 (batch*kv_heads*q_groups, Sq/BQ); each program owns one (BQ, dh) query
@@ -22,7 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -40,12 +41,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, nk, causal, kv_repeat):
 
     def body(ki, carry):
         m, l, acc = carry
-        # unit slice (not int 0) on the leading axis: interpret-mode
-        # discharge in current jax chokes on mixed int+Slice indexers
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(ki * bk, bk),
-                            slice(None)))[0].astype(F32)   # (BK, dh)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(ki * bk, bk),
-                            slice(None)))[0].astype(F32)
+        k = k_ref[0, pl.ds(ki * bk, bk), :].astype(F32)   # (BK, dh)
+        v = v_ref[0, pl.ds(ki * bk, bk), :].astype(F32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32) * scale
         if causal:
@@ -72,11 +69,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, nk, causal, kv_repeat):
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, bq=128, bk=128,
-                           interpret=True):
+                           interpret=None):
     """q: (BH, Sq, dh), k/v: (BH, Sk, dh) with q already GQA-expanded
     (BH = B*KV*G and k/v repeated per group by the caller/ops wrapper).
     dh should be a multiple of 128 for MXU alignment (any value works in
-    interpret mode)."""
+    interpret mode).  ``interpret=None`` follows the platform."""
     BH, Sq, dh = q.shape
     Sk = k.shape[1]
     bq = min(bq, Sq)
@@ -95,7 +92,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, bq=128, bk=128,
         ],
         out_specs=pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 

@@ -18,8 +18,8 @@ Four implementations of the same contract (oracle: ``ref.zo_axpy_nd``):
                  of one extra gather+scatter stream.
   * ``pallas`` — the fused TPU kernel (``zo_axpy.zo_axpy_2d``): on-the-fly
                  RNG in VMEM, per-layer ``pl.when`` predication, buffer
-                 aliasing.  Validated in interpret mode on CPU; targets
-                 per-shard invocation via shard_map on real TPUs.
+                 aliasing.  Compiled by Mosaic on a TPU, run by the
+                 Pallas interpreter elsewhere (the platform decides).
 
 All backends draw identical z (same counter RNG keyed by (seed, leaf,
 global layer id)) — property-tested against each other.
@@ -70,7 +70,7 @@ def _gather_axpy(theta, active_idx, seed, scale, decay):
 
 
 def zo_axpy(theta, *, path, seed, scale, decay=1.0, mask=None,
-            active_idx=None, backend="dense", interpret=True):
+            active_idx=None, backend="dense"):
     """Apply ``decay*theta + scale*z`` to a parameter leaf.
 
     theta is stacked over layers on axis 0 iff ``mask``/``active_idx`` is
@@ -94,7 +94,6 @@ def zo_axpy(theta, *, path, seed, scale, decay=1.0, mask=None,
         return _gather_axpy(theta, active_idx, leaf_seed, scale, decay)
     if backend == "pallas":
         theta2d = theta.reshape(theta.shape[0], -1)
-        out = kzo.zo_axpy_2d(theta2d, mask, leaf_seed, scale, decay,
-                             interpret=interpret)
+        out = kzo.zo_axpy_2d(theta2d, mask, leaf_seed, scale, decay)
         return out.reshape(theta.shape)
     raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")

@@ -20,15 +20,19 @@ exactly twice (read + write):
     passes fuse into one call with scale = eps - lr*g.
 
 Layout: a parameter leaf is viewed as (L, n) — L stacked layers (L=1 for
-unstacked leaves) by n flattened elements.  Grid = (L, ceil(n / BLOCK));
-BlockSpec tiles (1, BLOCK) of the row into VMEM.  BLOCK is a multiple of
-the 128-lane dimension; 64Ki f32 elements = 256 KiB per buffer, well under
-the ~16 MiB VMEM budget even double-buffered.
+unstacked leaves) by n flattened elements — and each layer row as a
+row-major (rows, lanes) matrix, lanes a multiple of 128 (n is padded up
+to a multiple of 128 when it is not one).  Grid = (L, ceil(rows / br));
+the layer axis is squeezed out of the block, so every VMEM block is a
+(br, lanes) tile that meets the TPU's (8, 128) tiling.  ``block`` bounds
+the elements per tile: 64Ki elements are 256 KiB of f32 per buffer, far
+under the scoped-VMEM budget even double-buffered.
 
-Counters restart at 0 for every (leaf, layer): uniqueness across leaves
-and layers comes from folding (leaf uid, layer index) into the seed, which
-keeps counters within uint32 for any realistic leaf and makes the value of
-z[l, i] independent of sharding.
+Counters restart at 0 for every (leaf, layer) and equal the element's
+flat index within the layer, whatever the tiling: uniqueness across
+leaves and layers comes from folding (leaf uid, layer index) into the
+seed, which keeps counters within uint32 for any realistic leaf and makes
+the value of z[l, i] independent of tiling and sharding.
 
 Kernel backends of the ZO core (DESIGN.md §2).
 """
@@ -42,27 +46,41 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng
+from repro.kernels import resolve_interpret
 
-BLOCK = 65536  # f32 elements per tile: 256 KiB in, 256 KiB out in VMEM.
+BLOCK = 65536   # elements per tile: 256 KiB of f32 in, 256 KiB out in VMEM.
+_LANES = (1024, 512, 256, 128)
+_SUBLANES = 32  # row-block multiple: native (8|16|32, 128) tiles of f32/bf16/int8
 
 
-def _kernel(mask_ref, seed_ref, scale_ref, decay_ref, theta_ref, out_ref, *, block):
+def _layout(n: int, block: int):
+    """-> (padded n, lanes, rows, rows per block) for one layer row."""
+    n_pad = -(-n // 128) * 128
+    lanes = next(w for w in _LANES if n_pad % w == 0)
+    rows = n_pad // lanes
+    br = max(_SUBLANES, block // lanes // _SUBLANES * _SUBLANES)
+    return n_pad, lanes, rows, min(br, rows)
+
+
+def _kernel(mask_ref, seed_ref, scale_ref, decay_ref, theta_ref, out_ref, *,
+            br, lanes):
     l = pl.program_id(0)
     j = pl.program_id(1)
 
-    @pl.when(mask_ref[l])
+    @pl.when(mask_ref[l] != 0)
     def _active():
         # Per-(leaf, layer) seed was pre-folded on the host side up to the
         # leaf uid; fold the layer index here (scalar uint32 math).
-        seed_l = rng.fold(seed_ref[0], jnp.uint32(l))
-        col0 = (j * block).astype(jnp.uint32)
-        idx = col0 + jax.lax.broadcasted_iota(jnp.uint32, (1, block), 1)
-        z = rng.counter_normal(seed_l, idx)
+        seed_l = rng.fold(seed_ref[0], l.astype(jnp.uint32))
+        row = (j * br).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+            jnp.uint32, (br, lanes), 0)
+        col = jax.lax.broadcasted_iota(jnp.uint32, (br, lanes), 1)
+        z = rng.counter_normal(seed_l, row * jnp.uint32(lanes) + col)
         x = theta_ref[...].astype(jnp.float32)
         y = decay_ref[0] * x + scale_ref[0] * z
         out_ref[...] = y.astype(out_ref.dtype)
 
-    @pl.when(jnp.logical_not(mask_ref[l]))
+    @pl.when(mask_ref[l] == 0)
     def _skipped():
         # Write-through keeps interpret-mode semantics correct; on TPU the
         # buffer is aliased so this is a VMEM-local copy, no HBM traffic
@@ -71,32 +89,38 @@ def _kernel(mask_ref, seed_ref, scale_ref, decay_ref, theta_ref, out_ref, *, blo
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def zo_axpy_2d(theta, mask, seed, scale, decay, *, block=BLOCK, interpret=True):
-    """theta: (L, n); mask: (L,) bool; seed uint32 scalar; scale/decay f32 scalars.
+def zo_axpy_2d(theta, mask, seed, scale, decay, *, block=BLOCK,
+               interpret=None):
+    """theta: (L, n); mask: (L,) bool; seed uint32 scalar; scale/decay f32
+    scalars.  ``interpret=None`` follows the platform.
 
     Returns decay*theta + scale*z for rows where mask, theta elsewhere.
     """
     L, n = theta.shape
-    block = min(block, max(128, n))
-    grid = (L, pl.cdiv(n, block))
-    return pl.pallas_call(
-        functools.partial(_kernel, block=block),
-        grid=grid,
+    n_pad, lanes, rows, br = _layout(n, block)
+    view = theta if n_pad == n else jnp.pad(theta, [(0, 0), (0, n_pad - n)])
+    view = view.reshape(L, rows, lanes)
+    tile = pl.BlockSpec((None, br, lanes), lambda l, j: (l, j, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, br=br, lanes=lanes),
+        grid=(L, pl.cdiv(rows, br)),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # mask  (L,)
             pl.BlockSpec(memory_space=pltpu.SMEM),  # seed  (1,)
             pl.BlockSpec(memory_space=pltpu.SMEM),  # scale (1,)
             pl.BlockSpec(memory_space=pltpu.SMEM),  # decay (1,)
-            pl.BlockSpec((1, block), lambda l, j: (l, j)),
+            tile,
         ],
-        out_specs=pl.BlockSpec((1, block), lambda l, j: (l, j)),
-        out_shape=jax.ShapeDtypeStruct(theta.shape, theta.dtype),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(view.shape, theta.dtype),
         input_output_aliases={4: 0},
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(
-        mask,
+        jnp.asarray(mask, jnp.int32),
         jnp.asarray(seed, jnp.uint32).reshape(1),
         jnp.asarray(scale, jnp.float32).reshape(1),
         jnp.asarray(decay, jnp.float32).reshape(1),
-        theta,
+        view,
     )
+    out = out.reshape(L, n_pad)
+    return out if n_pad == n else out[:, :n]

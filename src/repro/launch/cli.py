@@ -530,6 +530,8 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count"
                                      "=512")
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ns = build_parser().parse_args(argv)
     return COMMANDS[ns.cmd](ns)
 

@@ -14,17 +14,18 @@ module does not touch jax device state; the dry-run sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules here are
+    ``NamedSharding`` annotations that XLA propagates, not explicit
+    sharding-in-types (``jax.make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices exist (tests / CPU smoke)."""
-    n = len(jax.devices())
-    assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh(shape, axes)

@@ -10,25 +10,29 @@ serving.py`` measures the two against each other.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.models import lm
 
 
+@functools.partial(jax.jit, static_argnums=(0,))
+def _greedy_step(cfg, params, caches, tok, pos):
+    # module-level jit: one compile per (cfg, cache shape), shared by
+    # every generate() call
+    lg, caches = lm.serve_step(cfg, params, caches, tok, pos)
+    return jnp.argmax(lg, -1)[:, None].astype(jnp.int32), caches
+
+
 def generate(cfg, params, tokens, gen_steps: int, max_seq: int):
     B, S = tokens.shape
     logits, caches = lm.prefill(cfg, params, tokens, max_seq=max_seq)
     out = [jnp.argmax(logits, -1)[:, None]]
-
-    @jax.jit
-    def step(params, caches, tok, pos):
-        lg, caches = lm.serve_step(cfg, params, caches, tok, pos)
-        return jnp.argmax(lg, -1)[:, None].astype(jnp.int32), caches
-
     tok = out[0].astype(jnp.int32)
     for i in range(gen_steps - 1):
-        tok, caches = step(params, caches, tok, jnp.int32(S + i))
+        tok, caches = _greedy_step(cfg, params, caches, tok, jnp.int32(S + i))
         out.append(tok)
     return jnp.concatenate(out, axis=1)
 

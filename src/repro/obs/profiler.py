@@ -5,40 +5,23 @@ The obs spans answer "which *stage* is slow"; when the question drops to
 obs.profile(dir):`` wraps a region in ``jax.profiler.trace`` when a
 directory is given and is a free no-op otherwise, so call sites (the
 trainer loop, the serving drain, the step benchmark) carry exactly one
-line regardless of configuration.  Profiler failures degrade to a
-warning rather than killing a training run — a missing tensorboard
-plugin must not take the experiment down with it (DESIGN.md §13).
+line regardless of configuration.  A trace that was asked for and cannot
+start or stop raises: a run that requested a device trace never ends
+successfully without one (DESIGN.md §13).
 """
 from __future__ import annotations
 
 import contextlib
-import warnings
 from typing import Optional
 
 
 @contextlib.contextmanager
 def profile(profile_dir: Optional[str]):
     """``jax.profiler.trace(profile_dir)`` when a dir is given, else a
-    no-op.  Profiler start/stop failures are demoted to warnings;
-    exceptions from the wrapped body always propagate."""
+    no-op.  Profiler start/stop failures propagate."""
     if not profile_dir:
         yield
         return
-    cm, entered = None, False
-    try:
-        import jax
-        cm = jax.profiler.trace(profile_dir)
-        cm.__enter__()
-        entered = True
-    except Exception as e:  # pragma: no cover - env-dependent
-        warnings.warn(f"obs: jax.profiler unavailable ({e!r}); "
-                      "continuing without a device trace")
-    try:
+    import jax
+    with jax.profiler.trace(profile_dir):
         yield
-    finally:
-        if entered:
-            try:
-                cm.__exit__(None, None, None)
-            except Exception as e:  # pragma: no cover - env-dependent
-                warnings.warn(f"obs: jax.profiler trace close failed "
-                              f"({e!r})")

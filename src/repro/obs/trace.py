@@ -71,11 +71,8 @@ def tracing() -> bool:
     importable without it.  Public so instrumentation sites that must
     concretize a value (e.g. ``int(n_active)`` for a gauge) can skip
     the whole block under tracing."""
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - exotic/old jax
-        return False
+    from jax._src import core
+    return not core.trace_state_clean()
 
 
 _tracing = tracing

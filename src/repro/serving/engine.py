@@ -207,10 +207,7 @@ class Engine:
     def n_compiles(self) -> int:
         """Compiled shapes behind the paged steps — stays at <= 2 (one
         per bucket) for the engine's whole life; the bench asserts it."""
-        try:
-            return self._pstep._cache_size() + self._dstep._cache_size()
-        except AttributeError:  # pragma: no cover - older jax
-            return -1
+        return self._pstep._cache_size() + self._dstep._cache_size()
 
     # ------------------------------------------------------------- submit
     def submit(self, req: Request):

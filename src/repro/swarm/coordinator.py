@@ -184,6 +184,7 @@ class Coordinator:
         self._closed_peers: List[_Peer] = []
         self._next_wid = 0
         self.commit_log: List[dict] = []
+        self.worker_devices: Dict[str, dict] = {}
         self.straggler_steps = 0
         self.stale_rejections = 0
 
@@ -279,6 +280,9 @@ class Coordinator:
             start = max(0, int(msg.get("from_step", 0)))
             peer.send({"type": "commits",
                        "commits": self.commit_log[start:]})
+        elif kind == "device" and msg is not None and peer.wid is not None:
+            self.worker_devices[str(peer.wid)] = {
+                k: v for k, v in msg.items() if k != "type"}
         elif kind == "contribution" and msg is not None and ledger:
             c = proto.StepContribution.from_wire(msg)
             if ledger.add(c, self.epoch) == "stale_epoch":
@@ -439,6 +443,7 @@ class Coordinator:
             full = dict(self.health.summary())
             full.update(summary)
             self.runlog.finalize(full)
+        summary["worker_devices"] = dict(self.worker_devices)
         return summary
 
     def close(self):

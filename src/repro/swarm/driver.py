@@ -11,10 +11,18 @@ rebuilds from the wire-shipped spec, and folds the committed
 
 ``attach`` mode is the worker half: connect to an existing coordinator
 and serve until the run completes.
+
+One process per chip: a chip belongs to one process at a time, so the
+parent pins its own JAX work (the coordinator's selection metrics) to
+the host CPU before any backend starts, and on a TPU host each worker
+slot is given one chip through libtpu's per-process variables.  The
+chips are counted over PCI, which starts no backend; more workers than
+chips is an error.
 """
 from __future__ import annotations
 
 import os
+import socket
 import subprocess
 import sys
 import threading
@@ -37,17 +45,59 @@ def _worker_cmd(host: str, port: int) -> List[str]:
             "--attach", f"{host}:{port}"]
 
 
-def _worker_env() -> Dict[str, str]:
+def tpu_chips() -> int:
+    """TPU chips attached to this host, counted over PCI without
+    starting a JAX backend; 0 where ``JAX_PLATFORMS`` keeps JAX off the
+    TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _free_port() -> int:
+    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _worker_env(slot: int, chips: int) -> Dict[str, str]:
+    """The parent's environment plus, on a TPU host (``chips > 0``),
+    chip ``slot`` alone: a one-chip process topology, with its own
+    libtpu port so the workers' runtimes do not collide."""
     env = dict(os.environ)
     src = _src_root()
     prev = env.get("PYTHONPATH", "")
     if src not in prev.split(os.pathsep):
         env["PYTHONPATH"] = src + (os.pathsep + prev if prev else "")
+    if chips:
+        env.update(TPU_VISIBLE_CHIPS=str(slot),
+                   TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_PORT=str(_free_port()))
     return env
 
 
-def spawn_worker(host: str, port: int) -> subprocess.Popen:
-    return subprocess.Popen(_worker_cmd(host, port), env=_worker_env())
+def spawn_worker(host: str, port: int, slot: int = 0,
+                 chips: int = 0) -> subprocess.Popen:
+    return subprocess.Popen(_worker_cmd(host, port),
+                            env=_worker_env(slot, chips))
+
+
+def pin_parent_to_cpu():
+    """Run this process's JAX on the host CPU so it never holds a chip
+    a worker needs.  Raises if a non-CPU backend already started."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "the swarm parent already holds a "
+                f"{jax.default_backend()} backend; its workers could not "
+                "get the chips — start the swarm before any JAX work")
+        return
+    jax.config.update("jax_platforms", "cpu")
 
 
 def run_swarm(spec, *, respawn: bool = True,
@@ -55,13 +105,18 @@ def run_swarm(spec, *, respawn: bool = True,
     """Coordinator + ``spec.swarm.workers`` supervised local workers.
 
     Returns the coordinator's summary dict (run_id, epochs, straggler
-    steps, wire bytes/step, worker exit codes).
+    steps, wire bytes/step, worker exit codes, worker devices).
     """
     from repro.swarm.coordinator import Coordinator
 
     if spec.swarm.workers < 1:
         raise ValueError("run_swarm needs swarm.workers >= 1 "
                          "(use --attach to join an existing swarm)")
+    chips = tpu_chips()
+    if chips and spec.swarm.workers > chips:
+        raise ValueError(f"swarm.workers={spec.swarm.workers} but this TPU "
+                         f"host has {chips} chips: one worker per chip")
+    pin_parent_to_cpu()
     coord = Coordinator(spec, runs_root=runs_root)
     procs: List[Optional[subprocess.Popen]] = []
     respawns = [0] * spec.swarm.workers
@@ -78,13 +133,14 @@ def run_swarm(spec, *, respawn: bool = True,
                 if (respawn and not done.is_set()
                         and respawns[slot] < _MAX_RESPAWNS_PER_SLOT):
                     respawns[slot] += 1
-                    procs[slot] = spawn_worker(coord.host, coord.port)
+                    procs[slot] = spawn_worker(coord.host, coord.port,
+                                               slot, chips)
             time.sleep(0.1)
 
     sup = threading.Thread(target=supervise, daemon=True)
     try:
-        for _ in range(spec.swarm.workers):
-            procs.append(spawn_worker(coord.host, coord.port))
+        for slot in range(spec.swarm.workers):
+            procs.append(spawn_worker(coord.host, coord.port, slot, chips))
         sup.start()
         summary = coord.serve()
     finally:

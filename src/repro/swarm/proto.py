@@ -24,6 +24,8 @@ Message types:
 ``fetch``       worker → coordinator: resync request for committed steps
                 ``>= from_step`` (elastic join, partition recovery)
 ``commits``     coordinator → worker: the requested commit backlog
+``device``      worker → coordinator: the device it built on (platform,
+                kind, id, coords, held device nodes), once, after start-up
 ``done``        coordinator → worker: run complete, summary attached
 ``bye``         worker → coordinator: clean leave
 ==============  ===========================================================
@@ -41,7 +43,7 @@ _LEN = struct.Struct(">I")
 MAX_FRAME = 1 << 24  # 16 MiB — the spec-carrying welcome is the ceiling
 
 MESSAGE_TYPES = ("hello", "welcome", "assign", "contribution", "commit",
-                 "fetch", "commits", "done", "bye")
+                 "fetch", "commits", "device", "done", "bye")
 
 
 class ProtocolError(RuntimeError):

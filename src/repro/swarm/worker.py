@@ -25,6 +25,7 @@ death/reassignment path is deterministically exercised.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from typing import Dict, List, Optional
 
@@ -34,6 +35,22 @@ from repro.core import rng
 from repro.swarm import chaos as chaos_mod
 from repro.swarm import proto
 from repro.swarm import shardstep
+
+
+def _open_device_files() -> List[str]:
+    """Accelerator device nodes this process holds open (``/dev/accel*``,
+    ``/dev/vfio/*``): which physical chip a worker got, whatever ids its
+    runtime numbers its devices with.  Empty off an accelerator."""
+    fd_dir = "/proc/self/fd"
+    held = set()
+    for fd in os.listdir(fd_dir) if os.path.isdir(fd_dir) else ():
+        try:
+            target = os.readlink(os.path.join(fd_dir, fd))
+        except OSError:   # an fd closed since listdir
+            continue
+        if target.startswith(("/dev/accel", "/dev/vfio/")):
+            held.add(target)
+    return sorted(held)
 
 
 class Worker:
@@ -94,6 +111,13 @@ class Worker:
                 f"seed lineage mismatch: spec folds to {want}, "
                 f"coordinator announced {self.base_seed}")
         self.params = self.trainer.trainable
+        dev = jax.devices()[0]
+        self.conn.send({"type": "device", "platform": dev.platform,
+                        "kind": dev.device_kind, "id": dev.id,
+                        "coords": list(getattr(dev, "coords", None) or ()),
+                        "n_devices": jax.device_count(),
+                        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+                        "device_files": _open_device_files()})
         self.t = 0
         # newest checkpoint <= next_step fast-forwards for free
         ck = self.trainer.ckpt

@@ -23,6 +23,7 @@ from repro.configs import opt
 from repro.core import zo, rng
 from repro.data import synthetic
 from repro.distributed import ctx, sharding
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 
 mcfg = opt.opt_tiny(layers=2, d_model=64, vocab=256)
@@ -61,7 +62,7 @@ def run(mesh):
     return losses, jax.tree.map(np.asarray, p)
 
 l1, p1 = run(None)
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = make_mesh((4, 2), ('data', 'model'))
 l2, p2 = run(mesh)
 d_loss = max(abs(a - b) for a, b in zip(l1, l2))
 d_par = max(float(np.abs(a - b).max())

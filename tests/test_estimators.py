@@ -44,7 +44,7 @@ def _legacy_zo_step(loss_fn, spec, cfg):
             masks, idxs, n_active = zo.uniform_select(spec, seed, cfg.n_drop)
         ax = lambda p, s, d=1.0: zo.tree_axpy(
             p, spec, seed, s, masks, idxs, decay=d,
-            backend=cfg.backend, interpret=cfg.interpret)
+            backend=cfg.backend)
 
         p = ax(params, cfg.eps)
         l_plus = loss_fn(p, batch)

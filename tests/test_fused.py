@@ -578,12 +578,17 @@ def test_paired_structural_counters_halve():
 
 def test_interpret_autodetects_platform():
     """interpret=None resolves per-platform: emulator off TPU, compiled
-    on it — nothing hardcodes interpret=True anymore."""
-    assert fused_matmul.default_interpret() == (
-        jax.default_backend() != "tpu")
-    assert fused_matmul._resolve_interpret(None) == \
-        fused_matmul.default_interpret()
-    assert fused_matmul._resolve_interpret(False) is False
+    on it — no kernel entry point defaults to interpret=True."""
+    import inspect
+    from repro import kernels
+    from repro.kernels import flash_attn, zo_axpy
+    assert kernels.default_interpret() == (jax.default_backend() != "tpu")
+    assert kernels.resolve_interpret(None) == kernels.default_interpret()
+    assert kernels.resolve_interpret(False) is False
+    for fn in (fused_matmul.pmatmul, fused_matmul.pmatmul_stack,
+               zo_axpy.zo_axpy_2d, flash_attn.flash_attention_pallas):
+        sig = inspect.signature(inspect.unwrap(fn))
+        assert sig.parameters["interpret"].default is None, fn
 
 
 # -------------------------------------------------- trainer integration

@@ -1,12 +1,14 @@
 """Golden regression pins for the fused ZO axpy Pallas kernel.
 
-``kernels/zo_axpy.py`` currently runs in interpret mode on CPU; future
-work will de-interpret it on TPU and may retile/revectorize the body.
-These pins freeze today's *semantics* — exact output values for f32 and
-bf16, masked and unmasked rows, and a block size that does not divide n
-— so any change to the RNG stream, the accumulate dtype (f32 math, cast
-on store), the tile indexing, or the mask/aliasing path is caught as a
-value diff, not discovered as a silently-diverged training run.
+``kernels/zo_axpy.py`` runs in the Pallas interpreter on CPU and is
+compiled by Mosaic on a TPU, where it tiles each layer as (rows, lanes)
+blocks under a squeezed layer axis.  These pins freeze its *semantics*
+— exact output values for f32 and bf16, masked and unmasked rows, a
+row that is not a multiple of 128 lanes, and a last row-block that is
+cut short — so any change to the RNG stream, the accumulate dtype (f32
+math, cast on store), the tile indexing, or the mask/aliasing path is
+caught as a value diff, not discovered as a silently-diverged training
+run.
 
 The expected arrays were generated from the kernel at pin time and
 cross-checked bit-exact against the pure-jnp oracle (kernels/ref.py);
@@ -105,6 +107,21 @@ def test_checksum_full_tiles():
     arr = np.asarray(got, np.float64)
     np.testing.assert_allclose(arr.sum(), CHECKSUM_SUM, rtol=1e-12)
     np.testing.assert_allclose(np.abs(arr).sum(), CHECKSUM_ABS, rtol=1e-12)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_ragged_row_block_bitexact_vs_oracle(dtype):
+    """40 rows of 1024 lanes per layer in 32-row blocks: the last block
+    is cut short, and its counters must still be flat element indices."""
+    n = 40 * 1024
+    theta = (jnp.arange(3 * n, dtype=jnp.float32).reshape(3, n)
+             * 1e-4).astype(dtype)
+    got = zo_axpy_2d(theta, jnp.asarray(MASK), jnp.uint32(SEED),
+                     jnp.float32(SCALE), jnp.float32(DECAY), block=32 * 1024)
+    want = ref.zo_axpy_2d(theta, jnp.asarray(MASK), jnp.uint32(SEED),
+                          SCALE, DECAY)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
 
 
 def test_golden_independent_of_block_size():

@@ -319,6 +319,32 @@ def test_spans_and_counters_suppressed_under_jit():
     assert len(ring) == 0 and tr.counters == {}
 
 
+def test_tracing_detects_jit_and_vmap():
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+    from repro.obs import trace
+    seen = []
+
+    def probe(x):
+        seen.append(trace.tracing())
+        return x
+
+    assert trace.tracing() is False
+    jax.jit(probe)(1.0)
+    jax.vmap(probe)(jnp.ones(2))
+    assert seen == [True, True]
+
+
+def test_profile_raises_when_trace_cannot_start(tmp_path):
+    jax = pytest.importorskip("jax")
+    with jax.profiler.trace(str(tmp_path / "outer")):
+        # a second trace cannot start while one runs: the requested
+        # device trace must fail the run, not vanish into a warning
+        with pytest.raises(RuntimeError):
+            with obs.profile(str(tmp_path / "inner")):
+                pass
+
+
 # ------------------------------------------------------------- trainer
 def _tiny_trainer(**tkw):
     import warnings

@@ -96,10 +96,11 @@ def test_dryrun_cell_subprocess():
         "import sys; sys.path.insert(0, 'src');"
         "import jax, jax.numpy as jnp;"
         "from repro.launch import specs;"
+        "from repro.launch.mesh import make_mesh;"
         "from repro import configs;"
         "from repro.configs.shapes import SHAPES;"
         "cfg = configs.get('granite-moe-1b-a400m');"
-        "mesh = jax.make_mesh((8, 8), ('data', 'model'));"
+        "mesh = make_mesh((8, 8), ('data', 'model'));"
         "sf, ps = specs.build_train_step(cfg, mesh, 'optimized');"
         "ins = specs.input_specs(cfg, SHAPES['train_4k']);"
         "f = sf(ins['batch']);"

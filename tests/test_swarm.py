@@ -249,6 +249,41 @@ def test_swarm_shards_derivation():
         api.with_overrides(base, {"swarm.n_shards": 4})) == 4
 
 
+# ======================================================= one chip per worker
+def test_swarm_refuses_more_workers_than_tpu_chips(monkeypatch):
+    """On a TPU host each worker needs a chip of its own: asking for
+    more workers than chips fails before any process or socket starts."""
+    from repro.swarm import coordinator, driver
+    monkeypatch.setattr(driver, "tpu_chips", lambda: 4)
+
+    def no_coordinator(*a, **k):
+        raise AssertionError("coordinator built despite too many workers")
+
+    monkeypatch.setattr(coordinator, "Coordinator", no_coordinator)
+    spec = api.with_overrides(api.preset("swarm-smoke"),
+                              {"swarm.workers": 5})
+    with pytest.raises(ValueError, match="4 chips"):
+        driver.run_swarm(spec)
+
+
+def test_swarm_worker_env_gives_each_slot_one_chip():
+    from repro.swarm import driver
+    envs = [driver._worker_env(slot, chips=4) for slot in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    # off a TPU host the workers inherit the parent's platform untouched
+    assert "TPU_VISIBLE_CHIPS" not in driver._worker_env(0, chips=0)
+
+
+def test_tpu_chips_zero_when_jax_platforms_excludes_tpu(monkeypatch):
+    from repro.swarm import driver
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert driver.tpu_chips() == 0
+
+
 # ==================================================== subprocess e2e (slow)
 def _rows(runs_root):
     (run_dir,) = [d for d in pathlib.Path(runs_root).iterdir() if d.is_dir()]
@@ -279,8 +314,15 @@ def test_two_worker_swarm_bit_identical_to_single_process(tmp_path):
     from repro.swarm import driver
 
     sw = _smoke_spec(tmp_path / "sw")
-    driver.run_swarm(sw, runs_root=str(tmp_path / "sw" / "runs"))
+    summary = driver.run_swarm(sw, runs_root=str(tmp_path / "sw" / "runs"))
     _, rows_sw = _rows(tmp_path / "sw" / "runs")
+    # every worker reported the device it built on
+    assert sorted(summary["worker_devices"]) == ["0", "1"]
+    assert {d["platform"] for d in summary["worker_devices"].values()} \
+        == {jax.default_backend()}
+    # the CPU backend opens no accelerator device node
+    assert all(d["device_files"] == []
+               for d in summary["worker_devices"].values())
 
     sp = _smoke_spec(tmp_path / "sp")
     hist = api.run(sp)["history"]
