@@ -49,8 +49,6 @@ def mix32(x):
 
 def fold(seed, data):
     """Derive a new uint32 seed from (seed, data) — order matters."""
-    from repro.obs import trace as _obs
-    _obs.get_tracer().count(_obs.CTR_RNG_FOLDS)
     seed = jnp.asarray(seed, jnp.uint32)
     data = jnp.asarray(data, jnp.uint32)
     return mix32(seed * _GOLDEN + data + _M2)

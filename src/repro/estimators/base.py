@@ -196,7 +196,7 @@ class Estimator:
         """theta <- decay*theta - lr * sum_i coeffs[i] * z_i, as q fused
         axpy passes (restore folded into the single pass when q == 1)."""
         q = len(dirs)
-        with obs.get_tracer().span(obs.UPDATE) as sp:
+        with obs.stage(obs.UPDATE) as sp:
             if self.cfg.fused_update and q == 1 and dirs.restore[0] != 0.0:
                 return sp.fence(self._ax(
                     params, dirs.restore[0] - lr * dirs.coeffs[0],

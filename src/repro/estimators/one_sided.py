@@ -52,8 +52,7 @@ class OneSidedBatched(Estimator):
         idxs = tuple(s[1] for s in sels)
         n_active = sels[0][2]
 
-        tr = obs.get_tracer()
-        with tr.span(obs.FWD_BASE) as sp:
+        with obs.stage(obs.FWD_BASE) as sp:
             l0 = sp.fence(loss_fn(params, batch))
         seeds_arr = jnp.stack([jnp.asarray(s, jnp.uint32) for s in seeds])
         stacked_masks = ({g: jnp.stack([m[g] for m in masks])
@@ -71,9 +70,9 @@ class OneSidedBatched(Estimator):
             return loss_fn(p, batch)
 
         chunk = cfg.q_chunk if 0 < cfg.q_chunk < q else q
-        # One span over all q probes: the vmapped region itself traces,
-        # so per-probe spans inside it would (correctly) no-op.
-        with tr.span(obs.FWD_PLUS) as sp:
+        # One stage over all q probes: the vmapped region itself traces,
+        # so an eager run could time no span per probe inside it.
+        with obs.stage(obs.FWD_PLUS) as sp:
             parts = []
             for c0 in range(0, q, chunk):
                 sub_masks = {g: m[c0:c0 + chunk]
@@ -90,7 +89,7 @@ class OneSidedBatched(Estimator):
                                                  sub_masks))
             losses = sp.fence(parts[0] if len(parts) == 1
                               else jnp.concatenate(parts))
-        tr.count(obs.CTR_PROBES, q)
+        obs.get_tracer().count(obs.CTR_PROBES, q)
         g = (losses - l0) / cfg.eps                     # (q,) projections
         coeffs = tuple(g[i] / q for i in range(q))
         dirs = DirectionSet(seeds=seeds, coeffs=coeffs, restore=(0.0,) * q,

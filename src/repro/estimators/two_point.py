@@ -21,13 +21,12 @@ class TwoPointSPSA(Estimator):
 
     def estimate(self, loss_fn, params, batch, seed, state):
         cfg = self.cfg
-        tr = obs.get_tracer()
         masks, idxs, n_active = self.select(seed, state)
         if self.virtual and cfg.paired_probes:
             # ONE paired forward for the ±εz pair: each W tile loads and
             # each z tile regenerates once for both signs — the step is
             # 1 paired forward + the single update axpy (DESIGN.md §10)
-            with tr.span(obs.FWD_PAIR) as sp:
+            with obs.stage(obs.FWD_PAIR) as sp:
                 losses = sp.fence(self._vloss_pair(loss_fn, params, batch,
                                                    seed, cfg.eps, masks))
             l_plus, l_minus = losses[0], losses[1]
@@ -35,24 +34,24 @@ class TwoPointSPSA(Estimator):
         elif self.virtual:
             # fused forward: same z, same floats, zero parameter writes —
             # the step collapses to 2 forwards + the single update axpy
-            with tr.span(obs.FWD_PLUS) as sp:
+            with obs.stage(obs.FWD_PLUS) as sp:
                 l_plus = sp.fence(self._vloss(loss_fn, params, batch, seed,
                                               cfg.eps, masks))
-            with tr.span(obs.FWD_MINUS) as sp:
+            with obs.stage(obs.FWD_MINUS) as sp:
                 l_minus = sp.fence(self._vloss(loss_fn, params, batch, seed,
                                                -cfg.eps, masks))
             p, restore = params, 0.0
         else:
-            with tr.span(obs.PERTURB) as sp:
+            with obs.stage(obs.PERTURB) as sp:
                 p = sp.fence(self._ax(params, cfg.eps, seed, masks, idxs))
-            with tr.span(obs.FWD_PLUS) as sp:
+            with obs.stage(obs.FWD_PLUS) as sp:
                 l_plus = sp.fence(loss_fn(p, batch))
-            with tr.span(obs.PERTURB) as sp:
+            with obs.stage(obs.PERTURB) as sp:
                 p = sp.fence(self._ax(p, -2.0 * cfg.eps, seed, masks, idxs))
-            with tr.span(obs.FWD_MINUS) as sp:
+            with obs.stage(obs.FWD_MINUS) as sp:
                 l_minus = sp.fence(loss_fn(p, batch))
             restore = cfg.eps
-        tr.count(obs.CTR_PROBES, 2)
+        obs.get_tracer().count(obs.CTR_PROBES, 2)
         g = (l_plus - l_minus) / (2.0 * cfg.eps)
         dirs = DirectionSet(seeds=(jnp.asarray(seed, jnp.uint32),),
                             coeffs=(g,), restore=(restore,),

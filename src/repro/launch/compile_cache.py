@@ -21,11 +21,17 @@ def default_dir() -> Path:
 
 def enable() -> str:
     """Turn the persistent cache on and return its directory.  Where
-    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
-    nothing is set here; otherwise the cache is :func:`default_dir`."""
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    directory is set here; otherwise the cache is :func:`default_dir`.
+
+    Either way the key keeps the programs' debug metadata: the ZO step's
+    stage names (``obs.stage``) live there, and JAX's default key strips
+    it, so a cache written by a program without them would hand back an
+    executable whose ops name no stage."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get(ENV):
         return os.environ[ENV]
-    import jax
     path = str(default_dir())
     jax.config.update("jax_compilation_cache_dir", path)
     return path

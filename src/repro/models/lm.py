@@ -37,6 +37,7 @@ from jax import lax
 from repro.fused import ref as fused_ref
 from repro.models import layers, moe, ssm
 from repro.models.config import BlockCfg, ModelConfig
+from repro.obs import trace as obs
 
 F32 = jnp.float32
 CE_CHUNK = 512
@@ -324,8 +325,9 @@ def lm_loss(cfg: ModelConfig, params, batch, aux_coef=0.0, perturb=None):
     hidden, _, aux = forward(cfg, params, batch.get("tokens"),
                              embeds=batch.get("embeds"), mode="train",
                              perturb=perturb)
-    loss = chunked_ce(cfg, params, hidden, batch["labels"],
-                      batch["loss_mask"], perturb=perturb)
+    with obs.stage(obs.LOSS_HEAD):
+        loss = chunked_ce(cfg, params, hidden, batch["labels"],
+                          batch["loss_mask"], perturb=perturb)
     return loss + aux_coef * aux
 
 

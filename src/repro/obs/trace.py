@@ -7,7 +7,10 @@ shared tracing layer instead of per-benchmark stopwatch code.  A
 ``perf_counter`` clock, with explicit ``block_until_ready`` *fencing*
 (``Span.fence``) so device-async dispatch cannot lie about where time
 went, plus named counters/gauges for structural facts (probes
-evaluated, axpy sweeps, RNG folds, active layers under LeZO sparsity).
+evaluated, axpy sweeps, active layers under LeZO sparsity).  An enabled
+span also opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<span>``, so under a profiler the program's host spans share the
+device trace's clock.
 
 Three rules keep the hot paths honest (DESIGN.md §13):
 
@@ -21,7 +24,10 @@ Three rules keep the hot paths honest (DESIGN.md §13):
     ``count`` therefore no-op whenever jax reports an active trace, so
     jitted steps stay clean and the same code path yields real stage
     timings when run eagerly (the staged-measurement mode
-    ``benchmarks/step_time.py`` uses).
+    ``benchmarks/step_time.py`` uses).  The ZO step's stages go through
+    :func:`stage` instead: under tracing it names the compiled
+    program's ops (``jax.named_scope``), so the device trace can
+    attribute each op to its stage; eagerly it is a span.
   * **Fence when asked.**  ``Tracer(fence=True)`` makes ``Span.fence``
     call ``jax.block_until_ready`` on the span's result before the
     clock stops; with ``fence=False`` the same call is free, so
@@ -46,15 +52,16 @@ FWD_MINUS = "forward-εz"
 FWD_PAIR = "forward_pair"     # one paired ±εz forward (fused probe stack)
 FWD_BASE = "forward"          # one_sided's unperturbed baseline forward
 UPDATE = "update_axpy"
+LOSS_HEAD = "loss_head"       # the LM head + cross-entropy, inside a probe
 TRAIN_STEP = "train/step"     # the trainer's whole-step record (jit-safe)
 SERVE_PREFILL = "serve/prefill"
 SERVE_DECODE = "serve/decode"
 STAGES: Tuple[str, ...] = (PERTURB, FWD_PLUS, FWD_MINUS, FWD_PAIR, UPDATE)
+ANNOTATION_PREFIX = "repro."  # host spans' names in a profiler trace
 
 # Counter names (structural per-run facts, deterministic under a seed).
 CTR_PROBES = "probes_evaluated"
 CTR_AXPY = "axpy_sweeps"
-CTR_RNG_FOLDS = "rng_folds"
 CTR_SELECTS = "layer_selections"
 # Fused-forward W-traffic counters (repro.fused): VMEM tile loads of
 # weight matrices and z-tile regenerations per step — the structural
@@ -105,7 +112,8 @@ class Span:
     (any pytree of jax arrays) as the span's result: when the owning
     tracer fences, the clock stops only after ``x`` is device-ready."""
 
-    __slots__ = ("_tracer", "name", "meta", "_t0", "_result", "_entry")
+    __slots__ = ("_tracer", "name", "meta", "_t0", "_result", "_entry",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  meta: Optional[Dict[str, Any]] = None):
@@ -119,7 +127,11 @@ class Span:
         return result
 
     def __enter__(self) -> "Span":
+        import jax
         self._entry = self._tracer._enter()
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -128,6 +140,7 @@ class Span:
             import jax
             jax.block_until_ready(self._result)
         dt = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._exit(self, dt)
         self._result = None
         return False
@@ -150,6 +163,42 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+class _Scope:
+    """A stage inside a traced program: ``jax.named_scope(name)`` with
+    the ``Span`` API, so emitters need not know whether they trace."""
+
+    __slots__ = ("_scope",)
+
+    def __init__(self, name: str):
+        import jax
+        self._scope = jax.named_scope(name)
+
+    def __enter__(self) -> "_Scope":
+        self._scope.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return self._scope.__exit__(exc_type, exc, tb)
+
+    def fence(self, result):
+        return result
+
+
+def stage(name: str):
+    """Open the ZO step's stage ``name`` (``with obs.stage(obs.PERTURB)
+    as sp: x = sp.fence(...)``).
+
+    While jax traces, whatever tracer is installed, the stage is a
+    ``jax.named_scope``: its name lands in the ``op_name`` metadata of
+    every op traced inside it, and the TPU profiler reports that path
+    as the op's ``tf_op``, so a device trace attributes each op of the
+    compiled step to its innermost stage.  Eagerly it is the installed
+    tracer's span (free under :data:`NULL`)."""
+    if _tracing():
+        return _Scope(name)
+    return get_tracer().span(name)
 
 
 class Tracer:

@@ -345,7 +345,6 @@ class Engine:
             self.submit(r)
         results: List[GenResult] = []
         guard = 0
-        t_run = self.clock()
         while self.sched.busy:
             before = (self.n_prefill_calls, self.n_decode_steps,
                       len(results), len(self.sched.queue),
@@ -359,10 +358,4 @@ class Engine:
                 raise RuntimeError(
                     "engine stalled: queue head needs "
                     "more pool pages than will ever free up")
-        dt = self.clock() - t_run
-        if dt > 0:
-            self.obs.registry.gauge(
-                "serving_tokens_per_second",
-                "generated tokens / drain wall time, last run()").set(
-                sum(len(r.tokens) for r in results) / dt)
         return results

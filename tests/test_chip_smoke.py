@@ -103,3 +103,38 @@ def test_compile_cache_defaults_to_checkout_dir():
     name = "cache_probe_checkout_dir"
     assert _compile(name) == str(ROOT / ".jax_cache")
     assert _entries(ROOT / ".jax_cache", name)
+
+
+_SCOPED = r"""
+import sys
+sys.path.insert(0, {src!r})
+from repro.launch import compile_cache
+compile_cache.enable()
+import jax, jax.numpy as jnp
+print(jax.config.jax_compilation_cache_include_metadata_in_key)
+
+def {name}(x):
+    with jax.named_scope({scope!r}):
+        return jnp.sin(x) * {salt}
+
+jax.jit({name}).lower(jnp.ones(7)).compile()
+"""
+
+
+def test_compile_cache_key_keeps_stage_names(tmp_path):
+    """Two programs that differ only in a named scope (a ZO stage's
+    name) are two cache entries: a cache warmed by a program without
+    the stage names never serves one with them."""
+    mine = tmp_path / "cache"
+    name = "cache_probe_scoped"
+    for scope in ("perturb", "update_axpy"):
+        r = subprocess.run(
+            [sys.executable, "-c",
+             _SCOPED.format(src=str(ROOT / "src"), name=name, scope=scope,
+                            salt=f"{os.getpid()}.0")],
+            capture_output=True, text=True, timeout=300,
+            env=_env(JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                     JAX_COMPILATION_CACHE_DIR=str(mine)))
+        assert r.returncode == 0, r.stderr
+        assert r.stdout.split() == ["True"]
+    assert len(_entries(mine, name)) == 2

@@ -419,7 +419,11 @@ def test_engine_exports_metrics():
     assert "serving_ttft_seconds_count 2" in text
     assert "serving_latency_seconds_count 2" in text
     assert "serving_pages_in_use 0" in text   # drained
-    assert "serving_tokens_per_second" in text
+    assert "serving_queue_depth 0" in text
+    assert "serving_lanes_active 0" in text
+    for gauge in ("serving_page_utilization", "serving_page_hit_rate",
+                  "serving_preemptions", "serving_cow_copies"):
+        assert f"# TYPE {gauge} gauge" in text
     names = {r.name for r in sess.ring.records()}
     assert obs.SERVE_PREFILL in names and obs.SERVE_DECODE in names
     for r in results:
