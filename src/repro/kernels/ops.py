@@ -7,9 +7,13 @@ Four implementations of the same contract (oracle: ``ref.zo_axpy_nd``):
                  does), but XLA fuses RNG+axpy into one HBM-speed loop
                  and it shards with zero communication.  MeZO (n_drop=0)
                  uses this: every layer is active anyway.
-  * ``scan``   — lax.scan over the layer axis + lax.cond per layer: a
-                 real runtime branch, dropped layers skip RNG + axpy
-                 compute.  Paper-faithful "skip" in pure JAX.
+  * ``scan``   — with ``active_idx`` (stratified / weighted policies):
+                 a fori_loop over the k active slots updates each listed
+                 row of the donated leaf in place; dropped rows are never
+                 read or written and no stacked buffer is made.  Without
+                 it (uniform policy, dynamic per-group count): lax.scan
+                 over the layer axis + lax.cond per layer, dropped layers
+                 skip RNG + axpy compute into a fresh stacked buffer.
   * ``gather`` — beyond-paper: LeZO's active set has *static* size
                  k = N - n_drop, so gather the k active rows, run the
                  dense pass on the compact (k, ...) buffer, scatter back.
@@ -35,6 +39,7 @@ from jax import lax
 from repro.core import rng
 from repro.kernels import ref as kref
 from repro.kernels import zo_axpy as kzo
+from repro.obs import trace as obs
 
 BACKENDS = ("dense", "scan", "gather", "pallas")
 
@@ -61,6 +66,27 @@ def _scan_axpy(theta, mask, seed, scale, decay):
     return out
 
 
+def _rows_axpy(theta, active_idx, seed, scale, decay):
+    """Update only the rows listed in active_idx, in place: a fori_loop
+    over the k slots carries the leaf, slicing row ``active_idx[j]`` out
+    and writing it back.  Dropped rows are neither read nor written, no
+    stacked buffer is made, and the counters are built inside the body,
+    where they fuse into the RNG."""
+    row_shape = theta.shape[1:]
+    scale = jnp.asarray(scale, jnp.float32)
+    decay = jnp.asarray(decay, jnp.float32)
+
+    def body(j, theta):
+        l = active_idx[j]
+        row = lax.dynamic_index_in_dim(theta, l, keepdims=False)
+        idx = kref._within_layer_index((1,) + row_shape)[0]
+        z = rng.counter_normal(rng.fold(seed, l), idx)
+        row = (decay * row.astype(jnp.float32) + scale * z).astype(theta.dtype)
+        return lax.dynamic_update_index_in_dim(theta, row, l, 0)
+
+    return lax.fori_loop(0, active_idx.shape[0], body, theta)
+
+
 def _gather_axpy(theta, active_idx, seed, scale, decay):
     """Perturb exactly the rows listed in active_idx (static length k)."""
     rows = theta[active_idx]
@@ -76,7 +102,10 @@ def zo_axpy(theta, *, path, seed, scale, decay=1.0, mask=None,
     theta is stacked over layers on axis 0 iff ``mask``/``active_idx`` is
     given.  ``path`` (tree-path string) keys the leaf's z stream.
     ``active_idx``: static-size index vector of active layers — required
-    for the gather backend, ignored otherwise.
+    for the gather backend, the in-place rows for scan, ignored
+    otherwise.  Each stacked leaf counts the rows its sweep reads and
+    writes (``axpy_rows_swept``): k where only the active rows are
+    touched, L where the whole stack is.
     """
     leaf_seed = rng.fold(jnp.asarray(seed, jnp.uint32),
                          jnp.uint32(rng.leaf_uid(path)))
@@ -84,9 +113,14 @@ def zo_axpy(theta, *, path, seed, scale, decay=1.0, mask=None,
         # whole leaf always active: single pseudo-layer, natural shape
         return kref.zo_axpy_nd(theta[None], None, leaf_seed, scale,
                                decay)[0]
+    rows_only = active_idx is not None and backend in ("scan", "gather")
+    obs.get_tracer().count(obs.CTR_AXPY_ROWS, active_idx.shape[0]
+                           if rows_only else theta.shape[0])
     if backend == "dense":
         return kref.zo_axpy_nd(theta, mask, leaf_seed, scale, decay)
     if backend == "scan":
+        if active_idx is not None:
+            return _rows_axpy(theta, active_idx, leaf_seed, scale, decay)
         return _scan_axpy(theta, mask, leaf_seed, scale, decay)
     if backend == "gather":
         if active_idx is None:

@@ -62,6 +62,7 @@ ANNOTATION_PREFIX = "repro."  # host spans' names in a profiler trace
 # Counter names (structural per-run facts, deterministic under a seed).
 CTR_PROBES = "probes_evaluated"
 CTR_AXPY = "axpy_sweeps"
+CTR_AXPY_ROWS = "axpy_rows_swept"   # stacked-leaf rows a sweep touches
 CTR_SELECTS = "layer_selections"
 # Fused-forward W-traffic counters (repro.fused): VMEM tile loads of
 # weight matrices and z-tile regenerations per step — the structural

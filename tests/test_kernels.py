@@ -33,6 +33,58 @@ def test_backend_matches_dense(L, shape, dtype, backend):
     assert np.array_equal(np.asarray(got)[drop], np.asarray(theta)[drop])
 
 
+_SHAPES = [(1, (7,)), (3, (16,)), (4, (8, 8)), (6, (5, 3, 4)), (2, (1000,)),
+           (5, (129,)), (2, (257, 3))]
+# active sets of the in-place scan: one row, every row (MeZO, k = L),
+# every other row (non-contiguous from L = 3 on)
+_ACTIVE = {"one": lambda L: [L - 1], "all": lambda L: list(range(L)),
+           "gaps": lambda L: list(range(0, L, 2))}
+
+
+@pytest.mark.parametrize("L,shape", _SHAPES)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("active", sorted(_ACTIVE))
+def test_scan_active_rows_in_place_match_dense(L, shape, dtype, active):
+    """With active_idx the scan backend updates only the listed rows,
+    in place: the same z and floats as dense, dropped rows bit-identical,
+    also with the leaf donated to a jitted sweep."""
+    theta = jax.random.normal(jax.random.PRNGKey(1), (L,) + shape,
+                              jnp.dtype(dtype))
+    aidx = np.asarray(_ACTIVE[active](L), np.int32)
+    mask = np.zeros(L, bool)
+    mask[aidx] = True
+    want = ops.zo_axpy(theta, path="w", seed=jnp.uint32(5), scale=0.05,
+                       decay=0.99, mask=jnp.asarray(mask), backend="dense")
+    sweep = jax.jit(lambda t, a: ops.zo_axpy(
+        t, path="w", seed=jnp.uint32(5), scale=0.05, decay=0.99,
+        active_idx=a, backend="scan"), donate_argnums=0)
+    before = np.asarray(theta)
+    got = np.asarray(sweep(jnp.array(theta), jnp.asarray(aidx)))
+    tol = 1e-6 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(got.astype(np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    assert np.array_equal(got[~mask], before[~mask])
+
+
+@pytest.mark.parametrize("L,shape", _SHAPES)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_scan_masked_path_matches_dense(L, shape, dtype):
+    """Without active_idx (the uniform policy) scan keeps its masked
+    lax.scan and still matches dense."""
+    theta = jax.random.normal(jax.random.PRNGKey(2), (L,) + shape,
+                              jnp.dtype(dtype))
+    mask = jnp.asarray(np.random.default_rng(L + 7).random(L) > 0.5)
+    want = ops.zo_axpy(theta, path="w", seed=jnp.uint32(9), scale=-0.1,
+                       mask=mask, backend="dense")
+    got = ops.zo_axpy(theta, path="w", seed=jnp.uint32(9), scale=-0.1,
+                      mask=mask, backend="scan")
+    tol = 1e-6 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    drop = ~np.asarray(mask)
+    assert np.array_equal(np.asarray(got)[drop], np.asarray(theta)[drop])
+
+
 @pytest.mark.parametrize("n", [64, 100, 65536, 65537])
 def test_pallas_tile_boundaries(n):
     theta = jnp.arange(2 * n, dtype=jnp.float32).reshape(2, n)

@@ -296,6 +296,35 @@ def test_eager_step_emits_stage_spans():
     assert counters[obs.CTR_SELECTS] == 1
 
 
+@pytest.mark.parametrize("policy,backend,rows", [
+    ("stratified", "scan", 2),      # in place: the k = 4 - 2 active rows
+    ("uniform", "scan", 4),         # masked scan: every stacked row
+    ("stratified", "dense", 4),
+    ("stratified", "gather", 2),
+])
+def test_axpy_rows_swept_counts_rows_each_sweep_touches(policy, backend,
+                                                       rows):
+    """One eager LeZO step on a 4-layer toy with n_drop=2: three sweeps
+    (perturb, perturb, fused update) over two stacked leaves."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+    from repro import estimators
+    from repro.core import zo
+    params = {"layers": {"w": jnp.ones((4, 3, 3)), "b": jnp.zeros((4, 3))},
+              "head": jnp.ones((3,))}
+    spec = zo.build_spec(
+        params, lambda p: "layers" if p.startswith("layers") else None)
+    est = estimators.build_estimator(spec, estimators.EstimatorConfig(
+        name="two_point", n_drop=2, policy=policy, backend=backend))
+    loss = lambda p, b, perturb=None: (
+        (p["layers"]["w"].sum(0) @ b["x"]).sum() + p["head"].sum()) ** 2
+    with jax.disable_jit():
+        _, counters = _one_eager_step(est, loss, params,
+                                      {"x": jnp.ones((3,))}, 11)
+    assert counters[obs.CTR_AXPY] == 3
+    assert counters[obs.CTR_AXPY_ROWS] == 3 * 2 * rows
+
+
 def test_counters_deterministic_across_identical_seeded_runs():
     pytest.importorskip("jax")
     est, loss, params, batch = _toy_estimator()

@@ -1,11 +1,14 @@
-"""Compile rehearsals of the main-path Pallas kernels for a TPU v5e.
+"""Compile rehearsals of the main path for a TPU v5e.
 
 The TPU compiler is installed even where no chip is attached, and it
-compiles for a *described* chip: these tests lower each kernel at real
-OPT widths for one v5e core and assert that Mosaic accepted it (a
-``tpu_custom_call`` in the compiled HLO).  They catch what interpret
+compiles for a *described* chip: these tests lower each Pallas kernel
+at real OPT widths for one v5e core and assert that Mosaic accepted it
+(a ``tpu_custom_call`` in the compiled HLO).  They catch what interpret
 mode cannot — casts Mosaic refuses, block shapes that break the (8, 128)
-tiling, kernels that overflow VMEM — at no chip time.
+tiling, kernels that overflow VMEM — at no chip time.  The ``scan``
+axpy's sweeps are checked the same way for what the CPU cannot show:
+that the TPU compiler updates the active rows of a donated stacked leaf
+in place, with no fill or copy of the whole stack.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and the
@@ -13,6 +16,8 @@ test workers all import this file.  Nothing runs here; a compile that
 passes is not a chip run.
 """
 import os
+import re
+from collections import Counter
 
 import pytest
 
@@ -20,8 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro import estimators
+from repro.core import zo
 from repro.fused import matmul as fused_matmul
-from repro.kernels import zo_axpy
+from repro.kernels import ops, zo_axpy
+from repro.models import lm
+from repro.models.config import dense_lm
 
 
 @pytest.fixture(scope="module")
@@ -93,3 +102,79 @@ def test_pmatmul_tied_head(one_chip):
                    _spec((K, N), jnp.bfloat16, one_chip),
                    _spec((), jnp.uint32, one_chip),
                    _spec((), jnp.float32, one_chip))
+
+
+def _ops_of_shapes(hlo, shapes):
+    """Count the compiled HLO's instructions by opcode whose result is
+    (or, for a tuple, holds) one of ``shapes``, e.g. ``bf16[10,5120,20480]``."""
+    found = Counter()
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = (.+?) ([a-z][a-z0-9-]*)\(",
+                         hlo, re.M):
+        if any(sh + "{" in m.group(1) for sh in shapes):
+            found[m.group(2)] += 1
+    return found
+
+
+_WHOLE_LEAF = ("broadcast", "copy", "copy-start", "copy-done")
+_HLO_DTYPE = {"bfloat16": "bf16", "float32": "f32"}
+
+
+def _hlo_shape(x):
+    return (f"{_HLO_DTYPE[jnp.dtype(x.dtype).name]}"
+            f"[{','.join(map(str, x.shape))}]")
+
+
+def test_scan_sweep_updates_active_rows_in_place(one_chip):
+    """One LeZO sweep over OPT-13B's stacked up-projection, 10 layers of
+    5120 x 20480 bf16, k = 3 active rows, the leaf donated: no fill or
+    copy of the stack, and less scratch than one layer row."""
+    L, k, shape = 10, 3, (5120, 20480)
+
+    def sweep(theta, aidx, seed, scale):
+        return ops.zo_axpy(theta, path="w", seed=seed, scale=scale,
+                           active_idx=aidx, backend="scan")
+
+    leaf = _spec((L,) + shape, jnp.bfloat16, one_chip)
+    compiled = jax.jit(sweep, donate_argnums=0).lower(
+        leaf, _spec((k,), jnp.int32, one_chip),
+        _spec((), jnp.uint32, one_chip),
+        _spec((), jnp.float32, one_chip)).compile()
+    found = _ops_of_shapes(compiled.as_text(), [_hlo_shape(leaf)])
+    assert found["dynamic-update-slice"] >= 1       # the in-place row write
+    assert not any(found[op] for op in _WHOLE_LEAF), found
+    row_bytes = 5120 * 20480 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < row_bytes
+
+
+def test_two_point_step_sweeps_in_place(one_chip):
+    """A whole materialized LeZO step (perturb, probe, -2eps sweep,
+    probe, update) on a 10-layer bf16 model, params donated: each sweep
+    writes the buffer the probe before it read, with no copy of any
+    stacked leaf between them and no fresh stacked buffer."""
+    mcfg = dense_lm("opt-aot", 10, 256, 4, 4, 1024, 512, act="relu",
+                    norm="ln", pos_emb="learned", tie_embeddings=True,
+                    max_seq=128)
+    params = jax.eval_shape(lambda: lm.init_params(mcfg,
+                                                   jax.random.PRNGKey(0)))
+    spec = zo.build_spec(params, lm.zo_group_fn)
+    step, init = estimators.make_step(
+        lambda p, b, perturb=None: lm.lm_loss(mcfg, p, b, perturb=perturb),
+        spec, estimators.EstimatorConfig(name="two_point", n_drop=7,
+                                         backend="scan"))
+    on_chip = lambda x: _spec(x.shape, x.dtype, one_chip)
+    B, S = 2, 16
+    batch = {"tokens": _spec((B, S), jnp.int32, one_chip),
+             "labels": _spec((B, S), jnp.int32, one_chip),
+             "loss_mask": _spec((B, S), jnp.float32, one_chip)}
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        jax.tree.map(on_chip, params),
+        jax.tree.map(on_chip, jax.eval_shape(init)), batch,
+        _spec((), jnp.int32, one_chip),
+        _spec((), jnp.uint32, one_chip)).compile()
+    stacked = [leaf for leaf, g in zip(jax.tree.leaves(params), spec.groups)
+               if g is not None]
+    found = _ops_of_shapes(compiled.as_text(),
+                           {_hlo_shape(leaf) for leaf in stacked})
+    # three sweeps, each one in-place row write per stacked leaf
+    assert found["dynamic-update-slice"] == 3 * len(stacked), found
+    assert not any(found[op] for op in _WHOLE_LEAF), found
