@@ -28,6 +28,10 @@ PRESETS: Dict[str, Experiment] = {
     "fo-opt13b": dataclasses.replace(
         _LEZO_OPT13B, optimizer=dataclasses.replace(
             _LEZO_OPT13B.optimizer, mode="fo")),
+    # the same LeZO recipe on DeepSeek-V2-Lite (MLA + drop-free MoE, a
+    # dense first layer: two layer groups of unequal size)
+    "lezo-dsv2lite": dataclasses.replace(
+        _LEZO_OPT13B, model=Model(arch="deepseek-v2-lite-16b")),
     # fused virtual-perturbation runtime (DESIGN.md §10); virtual_ref is
     # the pure-JAX oracle so the preset runs on the CPU container too
     "lezo-opt13b-virtual": dataclasses.replace(

@@ -1,11 +1,18 @@
 """deepseek-v2-lite-16b [moe] — MLA (kv_lora=512) + 2 shared / 64 routed top-6.
 
 27L d_model=2048 16H d_head=128(+64 rope) moe d_ff=1408 vocab=102400;
-layer 0 uses a dense FFN (width 10944).  [arXiv:2405.04434; hf]
+layer 0 uses a dense FFN (width 10944).  YaRN rope_scaling (factor 40
+over 4096 positions, mscale = mscale_all_dim = 0.707), rms_norm_eps
+1e-6, top-k weights not renormalized (norm_topk_prob false).
+[arXiv:2405.04434; hf]
 
 Model-zoo config (DESIGN.md §8).
 """
-from repro.models.config import BlockCfg, ModelConfig, StageCfg
+from repro.models.config import BlockCfg, ModelConfig, StageCfg, YarnCfg
+
+YARN = YarnCfg(factor=40.0, original_max_position_embeddings=4096,
+               beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+               mscale_all_dim=0.707)
 
 
 def full() -> ModelConfig:
@@ -14,9 +21,9 @@ def full() -> ModelConfig:
         d_head=128, d_ff=1408, vocab=102400,
         stages=(StageCfg(1, (BlockCfg("mla", "dense", d_ff=10944),)),
                 StageCfg(26, (BlockCfg("mla", "moe"),))),
-        kv_lora=512, rope_head_dim=64,
+        kv_lora=512, rope_head_dim=64, rope_scaling=YARN, norm_eps=1e-6,
         n_experts=64, n_shared_experts=2, top_k=6, moe_d_ff=1408,
-        tie_embeddings=False, max_seq=32768,
+        norm_topk_prob=False, tie_embeddings=False, max_seq=32768,
     )
 
 
@@ -26,7 +33,8 @@ def smoke() -> ModelConfig:
         d_head=16, d_ff=48, vocab=512,
         stages=(StageCfg(1, (BlockCfg("mla", "dense", d_ff=96),)),
                 StageCfg(2, (BlockCfg("mla", "moe"),))),
-        kv_lora=32, rope_head_dim=8,
+        kv_lora=32, rope_head_dim=8, rope_scaling=YARN, norm_eps=1e-6,
         n_experts=4, n_shared_experts=2, top_k=2, moe_d_ff=48,
-        tie_embeddings=False, dtype="float32", max_seq=128,
+        norm_topk_prob=False, tie_embeddings=False, dtype="float32",
+        max_seq=128,
     )

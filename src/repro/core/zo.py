@@ -30,16 +30,23 @@ multi-direction, and importance-weighted estimators are one config away
 Layer selection
 ---------------
 ``policy="uniform"`` is the paper's policy: drop n_drop of the N global
-layers uniformly.  ``policy="stratified"`` (default here) fixes a static
-per-group quota (largest-remainder apportionment of n_drop over groups)
-and samples uniformly *within* each group — statistically equivalent for
-single-group models (i.e. all of the paper's OPT experiments) and
-required by the ``gather`` backend, whose compact active buffer needs a
-static size per stacked leaf.
+layers uniformly.  ``policy="stratified"`` (default here) apportions
+n_drop over the groups and samples uniformly *within* each group —
+statistically equivalent for single-group models (i.e. all of the
+paper's OPT experiments).  A group of L_g layers is owed
+n_drop * L_g / N drops a step: it always takes the integer part, and
+the leftover drops (as many as the fractional parts sum to) go to the
+groups by a low-discrepancy schedule over the step index
+(:meth:`ZOSpec.quotas`), so every layer of every group is active with
+probability (N - n_drop) / N and the step's total stays N - n_drop.
+Each group's active index vector keeps a static length (its largest
+active count) for the ``gather`` and in-place ``scan`` paths; in a step
+that drops one more, its last slot is disabled.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -76,26 +83,66 @@ class ZOSpec:
         return {g: jax.lax.dynamic_slice(active, (s,), (l,))
                 for g, (s, l) in self.slices.items()}
 
-    def quotas(self, n_drop: int) -> Dict[str, int]:
-        """Largest-remainder apportionment of n_drop over groups."""
+    def quota_parts(self, n_drop: int):
+        """The static part of the apportionment: {g: int(n_drop * L_g / N)}
+        and {g: the fractional part's numerator over N}."""
         if self.num_layers == 0:
             # No stacked groups (e.g. a flat toy tree): nothing to drop.
             if n_drop:
                 raise ValueError("n_drop > 0 but the spec has no layer groups")
-            return {}
+            return {}, {}
         if not 0 <= n_drop < self.num_layers:
             raise ValueError(f"n_drop must be in [0, {self.num_layers})")
-        exact = {g: n_drop * L / self.num_layers
-                 for g, (_, L) in self.slices.items()}
-        base = {g: min(int(e), self.slices[g][1]) for g, e in exact.items()}
-        order = sorted(exact, key=lambda g: exact[g] - base[g], reverse=True)
-        i = 0
-        while sum(base.values()) < n_drop:
-            g = order[i % len(order)]
-            if base[g] < self.slices[g][1]:
-                base[g] += 1
-            i += 1
-        return base
+        N = self.num_layers
+        base = {g: n_drop * L // N for g, (_, L) in self.slices.items()}
+        frac = {g: n_drop * L % N for g, (_, L) in self.slices.items()}
+        return base, frac
+
+    def quotas(self, n_drop: int, step=None) -> Dict[str, Any]:
+        """Drops per group at ``step``: the integer part of each group's
+        share, plus the leftover drops placed by a low-discrepancy
+        schedule.
+
+        The fractional parts, as numerators over N, lie end to end on
+        [0, r*N) (r leftover drops); the step's r points u_t + j*N,
+        j < r, give one more drop to each group whose interval holds
+        one (an interval is shorter than N, so it holds at most one).
+        u_t = t * a mod N (``_stride``) visits the multiples of
+        gcd(a, N) evenly and every interval end is one of them, so each
+        group's rate is exact; with two groups each group's count in
+        every window of W steps is within +-1 of its expectation.
+        Groups with no fractional part get Python ints; the others
+        traced int32 unless ``step`` is a Python int.  ``step`` may be
+        left out only where no group has a fractional part."""
+        base, frac = self.quota_parts(n_drop)
+        if not any(frac.values()):
+            return base
+        if step is None:
+            raise ValueError("groups whose share of n_drop is fractional "
+                             "need the step index")
+        N = self.num_layers
+        ends = list(itertools.accumulate(frac[g] for g in self.slices))
+        if not isinstance(step, int):
+            step = jnp.asarray(step, jnp.int32)
+        u = (step % N) * _stride(N, frac) % N
+        ceil_div = lambda x: -((u - x) // N)
+        out, lo = {}, 0
+        for g, hi in zip(self.slices, ends):
+            out[g] = base[g] + (ceil_div(hi) - ceil_div(lo) if frac[g] else 0)
+            lo = hi
+        return out
+
+
+def _stride(N: int, frac: Dict[str, int]) -> int:
+    """The schedule's stride.  With two fractional shares (n and N - n),
+    a = n: each group's drops then follow a Bresenham line.  With more,
+    the stride coprime to N nearest N / golden ratio, which spreads
+    every share's points evenly."""
+    shares = [f for f in frac.values() if f]
+    if len(shares) == 2:
+        return shares[0]
+    return min((a for a in range(1, N) if math.gcd(a, N) == 1),
+               key=lambda a: abs(a - 0.618 * N))
 
 
 def build_spec(params, group_fn: Callable[[str], Optional[str]]) -> ZOSpec:
@@ -133,42 +180,64 @@ def _mask_from_active(act, L: int):
     return jnp.zeros((L,), jnp.bool_).at[act].set(True)
 
 
-def stratified_select(spec: ZOSpec, seed, n_drop: int):
-    """Per-group masks + static-size active index vectors.
+def _slots(ranked, k_max: int, n_off, L: int):
+    """Active index slots from ``ranked`` (layer ids, most-kept first):
+    the first k_max - n_off of ranked[:k_max], ascending.  With a
+    traced ``n_off`` the vector keeps length k_max: the disabled slots
+    come last and hold L (out of range: gathers clamp it, scatters and
+    masks drop it), and the slots travel as (vector, enabled count)."""
+    cand = ranked[:k_max]
+    if isinstance(n_off, int):
+        return jnp.sort(cand[:k_max - n_off]).astype(jnp.int32)
+    off = jnp.arange(k_max) >= k_max - n_off
+    idx = jnp.sort(jnp.where(off, L, cand)).astype(jnp.int32)
+    return idx, (k_max - n_off).astype(jnp.int32)
 
-    Returns (masks: {g: (L_g,) bool}, idxs: {g: (L_g - quota_g,) int32},
-    n_active).
-    """
-    quotas = spec.quotas(n_drop)
+
+def _select(spec: ZOSpec, n_drop: int, step, rank_fn):
+    """Masks, active slots and the step's active count, group by group:
+    ``rank_fn(g, start, L)`` ranks a group's layers, most-kept first."""
+    base, _ = spec.quota_parts(n_drop)
+    drops = spec.quotas(n_drop, step=step)
     masks, idxs = {}, {}
-    n_active = 0
     for g, (start, L) in spec.slices.items():
-        q = quotas[g]
-        bits = _group_rank_bits(seed, "sel/", g, L)
-        order = jnp.argsort(bits)
-        act = jnp.sort(order[q:]).astype(jnp.int32)      # active, ascending
-        masks[g] = _mask_from_active(act, L)
-        idxs[g] = act
-        n_active += L - q
-    return masks, idxs, n_active
+        slots = _slots(rank_fn(g, start, L), L - base[g],
+                       drops[g] - base[g], L)
+        idxs[g] = slots
+        masks[g] = _mask_from_active(
+            slots[0] if isinstance(slots, tuple) else slots, L)
+    return masks, idxs, spec.num_layers - n_drop
 
 
-def stratified_select_weighted(spec: ZOSpec, seed, n_drop: int, weights):
-    """Importance-weighted LeZO selection with static per-group quotas.
+def stratified_select(spec: ZOSpec, seed, n_drop: int, step=None):
+    """Per-group masks + static-size active slots.
+
+    Returns (masks: {g: (L_g,) bool}, idxs: {g: slots}, n_active), where
+    a group's slots are its (k_g,) int32 active layers, ascending, or,
+    for a group whose drop count varies by step (:meth:`ZOSpec.quotas`),
+    a (vector, enabled count) pair (see ``_slots``).  ``step``, the
+    step index, places the leftover drops (:meth:`ZOSpec.quotas`).
+    """
+    def rank(g, start, L):
+        # drop the lowest-ranked layers: most-kept first is descending
+        return jnp.argsort(_group_rank_bits(seed, "sel/", g, L))[::-1]
+
+    return _select(spec, n_drop, step, rank)
+
+
+def stratified_select_weighted(spec: ZOSpec, seed, n_drop: int, weights,
+                               step=None):
+    """Importance-weighted LeZO selection under the same quotas.
 
     ``weights`` (num_layers,) >= 0, globally indexed like ZOSpec.slices.
     Gumbel top-k by log-weight within each group: heavier layers are kept
     more often, selection stays fully stochastic (every layer has nonzero
-    keep probability), and the per-group active count is the same static
-    ``L_g - quota_g`` as :func:`stratified_select`, so the gather
-    backend's compact buffers keep their shapes.  Uniform weights recover
-    the unweighted distribution.
+    keep probability), and each group's slots have the same static
+    length as :func:`stratified_select`'s, so the gather backend's
+    compact buffers keep their shapes.  Uniform weights recover the
+    unweighted distribution.
     """
-    quotas = spec.quotas(n_drop)
-    masks, idxs = {}, {}
-    n_active = 0
-    for g, (start, L) in spec.slices.items():
-        k = L - quotas[g]
+    def rank(g, start, L):
         w = jax.lax.dynamic_slice(jnp.asarray(weights, jnp.float32),
                                   (start,), (L,))
         bits = _group_rank_bits(seed, "wsel/", g, L)
@@ -176,12 +245,9 @@ def stratified_select_weighted(spec: ZOSpec, seed, n_drop: int, weights):
                      / jnp.float32(1 << 24), 1e-7, 1.0 - 1e-7)
         gumbel = -jnp.log(-jnp.log(u))
         score = jnp.log(jnp.clip(w, 1e-9, None)) + gumbel
-        order = jnp.argsort(-score)
-        act = jnp.sort(order[:k]).astype(jnp.int32)      # active, ascending
-        masks[g] = _mask_from_active(act, L)
-        idxs[g] = act
-        n_active += k
-    return masks, idxs, n_active
+        return jnp.argsort(-score)
+
+    return _select(spec, n_drop, step, rank)
 
 
 def uniform_select(spec: ZOSpec, seed, n_drop: int):

@@ -82,7 +82,8 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
             p, spec, sd, s, m, i, backend=cfg.backend)
 
         # SPSA probe + immediate restore (unfused: momentum owns the update)
-        p, dirs, em = est.estimate(loss_fn, params, batch, seed, state)
+        p, dirs, em = est.estimate(loss_fn, params, batch, seed, state,
+                                   step_idx)
         p = est.restore_probe(p, dirs)
         g = dirs.coeffs[0]
 
@@ -100,7 +101,7 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
             t_j = step_idx - j
             seed_j = rng.fold(jnp.asarray(base_seed, jnp.uint32),
                               jnp.asarray(t_j, jnp.uint32))
-            masks_j, idxs_j, _ = est.select(seed_j, state)
+            masks_j, idxs_j, _ = est.select(seed_j, state, t_j)
             scale = -lr * (cfg.beta ** j.astype(jnp.float32)) * g_hist[j]
             valid = (t_j >= 0).astype(jnp.float32)
             return ax(p, scale * valid, seed_j, masks_j, idxs_j)

@@ -57,3 +57,25 @@ def constrain(x, *axes):
             spec.append(None)
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*spec)))
+
+
+def per_batch_shard(fn, x, *rest):
+    """``fn(x, *rest)`` run on each shard of x's leading (batch) dim
+    alone: a ``shard_map`` that is manual over the mesh's batch axes
+    only (the other axes stay with the partitioner), ``rest`` whole on
+    every shard, and every output with a leading batch dim.  Row-local
+    work (the MoE dispatch's sort, gather and scatter) then never
+    crosses a data shard.  Without a registered mesh, or where the
+    batch does not divide over its axes, ``fn`` sees the whole batch."""
+    if _MESH is None:
+        return fn(x, *rest)
+    ba = _batch_axes(_MESH)
+    n = 1
+    for ax in ba:
+        n *= _MESH.shape[ax]
+    if n == 1 or x.shape[0] % n:
+        return fn(x, *rest)
+    return jax.shard_map(fn, mesh=_MESH, in_specs=(P(ba),) + (P(),) * len(rest),
+                         out_specs=P(ba), axis_names=set(ba),
+                         check_vma=False)(x, *rest)
+

@@ -85,7 +85,8 @@ def make_step(loss_fn: Callable, spec: zo.ZOSpec, cfg: EstimatorConfig,
     def step(params, state, batch, step_idx, base_seed):
         seed = rng.fold(jnp.asarray(base_seed, jnp.uint32),
                         jnp.asarray(step_idx, jnp.uint32))
-        p, dirs, metrics = est.estimate(loss_fn, params, batch, seed, state)
+        p, dirs, metrics = est.estimate(loss_fn, params, batch, seed, state,
+                                        step_idx)
         lr = sched(step_idx)
         decay = 1.0 - lr * cfg.weight_decay
         p = est.apply_update(p, dirs, lr, decay)

@@ -23,7 +23,7 @@ from repro.estimators.base import DirectionSet, Estimator, direction_seeds
 class AveragedSPSA(Estimator):
     name = "averaged"
 
-    def estimate(self, loss_fn, params, batch, seed, state):
+    def estimate(self, loss_fn, params, batch, seed, state, step=None):
         cfg = self.cfg
         q = cfg.q
         seeds = direction_seeds(seed, q)
@@ -32,7 +32,7 @@ class AveragedSPSA(Estimator):
         loss_acc = g_acc = 0.0
         n_active = None
         for s in seeds:
-            m, ix, na = self.select(s, state)
+            m, ix, na = self.select(s, state, step)
             n_active = na if n_active is None else n_active
             if self.virtual and cfg.paired_probes:
                 # the ±εz pair rides one paired fused forward — W tiles

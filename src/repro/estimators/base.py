@@ -115,13 +115,14 @@ class Estimator:
         self._select = select_fn
 
     # -------------------------------------------------------- selection
-    def select(self, seed, state):
+    def select(self, seed, state, step=None):
         """-> (masks: {g: (L_g,) bool}, idxs: {g: (k_g,) int32} | None,
-        n_active)."""
+        n_active); ``step`` is the step index (``zo.ZOSpec.quotas``)."""
         if self._select is not None:
-            sel = self._select(seed, state)
+            sel = self._select(seed, state, step)
         elif self.cfg.policy == "stratified":
-            sel = zo.stratified_select(self.spec, seed, self.cfg.n_drop)
+            sel = zo.stratified_select(self.spec, seed, self.cfg.n_drop,
+                                       step=step)
         else:
             sel = zo.uniform_select(self.spec, seed, self.cfg.n_drop)
         tr = obs.get_tracer()
@@ -175,8 +176,9 @@ class Estimator:
         return loss_fn(params, batch, perturb=ctx)
 
     # --------------------------------------------------------- protocol
-    def estimate(self, loss_fn, params, batch, seed, state):
-        """Probe the loss.  -> (probed_params, DirectionSet, metrics).
+    def estimate(self, loss_fn, params, batch, seed, state, step=None):
+        """Probe the loss at step index ``step``.  -> (probed_params,
+        DirectionSet, metrics).
 
         ``probed_params`` may still carry a residual perturbation (see
         DirectionSet.restore); callers either ``apply_update`` (which

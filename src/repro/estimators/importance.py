@@ -5,7 +5,7 @@ score: every step, each *active* layer's score takes an EMA step toward
 the magnitude of that step's projected gradient (the only attribution a
 ZO step yields without extra forwards — a layer that was active while
 |g| was large is credited).  Selection is Gumbel top-k by score within
-each group under the same static largest-remainder quotas as
+each group under the same per-group quotas as
 ``stratified_select``, so the gather backend's compact buffers keep
 their static shapes and every backend works unchanged.
 
@@ -40,12 +40,13 @@ class ImportanceSelect(Estimator):
                                select_fn=select_fn or self._weighted_select)
 
     # -------------------------------------------------------- selection
-    def _weighted_select(self, seed, state):
+    def _weighted_select(self, seed, state, step):
         return zo.stratified_select_weighted(self.spec, seed,
-                                             self.cfg.n_drop, state["imp"])
+                                             self.cfg.n_drop, state["imp"],
+                                             step=step)
 
-    def select(self, seed, state):
-        return self.inner.select(seed, state)
+    def select(self, seed, state, step=None):
+        return self.inner.select(seed, state, step)
 
     # ------------------------------------------------------------ state
     def init_state(self):
@@ -71,8 +72,8 @@ class ImportanceSelect(Estimator):
         return zo.global_layer_mask(self.spec, masks)
 
     # ------------------------------------------------- delegate probing
-    def estimate(self, loss_fn, params, batch, seed, state):
-        return self.inner.estimate(loss_fn, params, batch, seed, state)
+    def estimate(self, loss_fn, params, batch, seed, state, step=None):
+        return self.inner.estimate(loss_fn, params, batch, seed, state, step)
 
     def restore_probe(self, params, dirs):
         return self.inner.restore_probe(params, dirs)

@@ -43,11 +43,11 @@ from repro.obs import trace as obs
 class OneSidedBatched(Estimator):
     name = "one_sided"
 
-    def estimate(self, loss_fn, params, batch, seed, state):
+    def estimate(self, loss_fn, params, batch, seed, state, step=None):
         cfg = self.cfg
         q = cfg.q
         seeds = direction_seeds(seed, q)
-        sels = [self.select(s, state) for s in seeds]
+        sels = [self.select(s, state, step) for s in seeds]
         masks = tuple(s[0] for s in sels)
         idxs = tuple(s[1] for s in sels)
         n_active = sels[0][2]

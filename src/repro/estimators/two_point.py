@@ -19,9 +19,9 @@ from repro.obs import trace as obs
 class TwoPointSPSA(Estimator):
     name = "two_point"
 
-    def estimate(self, loss_fn, params, batch, seed, state):
+    def estimate(self, loss_fn, params, batch, seed, state, step=None):
         cfg = self.cfg
-        masks, idxs, n_active = self.select(seed, state)
+        masks, idxs, n_active = self.select(seed, state, step)
         if self.virtual and cfg.paired_probes:
             # ONE paired forward for the ±εz pair: each W tile loads and
             # each z tile regenerates once for both signs — the step is

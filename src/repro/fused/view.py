@@ -208,10 +208,11 @@ class LayerPerturb:
         xs = x.reshape(self.nprobes, -1, shp[-1])
         bc = lambda v: v[:, None, :]                  # (P, D) -> (P, 1, D)
         if cfg.norm == "rms":
-            y = layers.rms_norm(xs, bc(sub.vec(p["scale"], "scale")))
+            y = layers.rms_norm(xs, bc(sub.vec(p["scale"], "scale")),
+                                cfg.norm_eps)
         else:
             y = layers.layer_norm(xs, bc(sub.vec(p["scale"], "scale")),
-                                  bc(sub.vec(p["bias"], "bias")))
+                                  bc(sub.vec(p["bias"], "bias")), cfg.norm_eps)
         return y.reshape(shp)
 
     def rms_norm(self, x, w, name: str = ""):

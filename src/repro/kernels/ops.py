@@ -8,9 +8,10 @@ Four implementations of the same contract (oracle: ``ref.zo_axpy_nd``):
                  and it shards with zero communication.  MeZO (n_drop=0)
                  uses this: every layer is active anyway.
   * ``scan``   — with ``active_idx`` (stratified / weighted policies):
-                 a fori_loop over the k active slots updates each listed
-                 row of the donated leaf in place; dropped rows are never
-                 read or written and no stacked buffer is made.  Without
+                 a fori_loop over the enabled active slots updates each
+                 listed row of the donated leaf in place; dropped rows
+                 and disabled slots are never read or written and no
+                 stacked buffer is made.  Without
                  it (uniform policy, dynamic per-group count): lax.scan
                  over the layer axis + lax.cond per layer, dropped layers
                  skip RNG + axpy compute into a fresh stacked buffer.
@@ -66,12 +67,14 @@ def _scan_axpy(theta, mask, seed, scale, decay):
     return out
 
 
-def _rows_axpy(theta, active_idx, seed, scale, decay):
-    """Update only the rows listed in active_idx, in place: a fori_loop
-    over the k slots carries the leaf, slicing row ``active_idx[j]`` out
-    and writing it back.  Dropped rows are neither read nor written, no
-    stacked buffer is made, and the counters are built inside the body,
-    where they fuse into the RNG."""
+def _rows_axpy(theta, active_idx, n, seed, scale, decay):
+    """Update only the rows listed in the first ``n`` slots of
+    active_idx, in place: a fori_loop over those slots carries the leaf,
+    slicing row ``active_idx[j]`` out and writing it back.  Dropped rows
+    are neither read nor written, no stacked buffer is made, and the
+    counters are built inside the body, where they fuse into the RNG.
+    A traced ``n`` is the loop's trip count: disabled slots cost
+    nothing."""
     row_shape = theta.shape[1:]
     scale = jnp.asarray(scale, jnp.float32)
     decay = jnp.asarray(decay, jnp.float32)
@@ -84,11 +87,13 @@ def _rows_axpy(theta, active_idx, seed, scale, decay):
         row = (decay * row.astype(jnp.float32) + scale * z).astype(theta.dtype)
         return lax.dynamic_update_index_in_dim(theta, row, l, 0)
 
-    return lax.fori_loop(0, active_idx.shape[0], body, theta)
+    return lax.fori_loop(0, n, body, theta)
 
 
 def _gather_axpy(theta, active_idx, seed, scale, decay):
-    """Perturb exactly the rows listed in active_idx (static length k)."""
+    """Perturb exactly the rows listed in active_idx (static length k);
+    a disabled slot's index L gathers a clamped row whose write-back
+    the scatter drops."""
     rows = theta[active_idx]
     rows = kref.zo_axpy_nd(rows, None, seed, scale, decay,
                            layer_ids=active_idx.astype(jnp.uint32))
@@ -101,11 +106,14 @@ def zo_axpy(theta, *, path, seed, scale, decay=1.0, mask=None,
 
     theta is stacked over layers on axis 0 iff ``mask``/``active_idx`` is
     given.  ``path`` (tree-path string) keys the leaf's z stream.
-    ``active_idx``: static-size index vector of active layers — required
-    for the gather backend, the in-place rows for scan, ignored
-    otherwise.  Each stacked leaf counts the rows its sweep reads and
-    writes (``axpy_rows_swept``): k where only the active rows are
-    touched, L where the whole stack is.
+    ``active_idx``: static-size index vector of active layers, or a
+    (vector, enabled count) pair whose disabled slots hold L (as
+    ``core.zo.stratified_select`` gives for a group whose drop count
+    varies by step) — required for the gather backend, the in-place rows
+    for scan, ignored otherwise.  Each stacked leaf counts the rows its
+    sweep reads and writes (``axpy_rows_swept``, eager runs): the
+    enabled slots where only the active rows are touched, L where the
+    whole stack is.
     """
     leaf_seed = rng.fold(jnp.asarray(seed, jnp.uint32),
                          jnp.uint32(rng.leaf_uid(path)))
@@ -113,14 +121,22 @@ def zo_axpy(theta, *, path, seed, scale, decay=1.0, mask=None,
         # whole leaf always active: single pseudo-layer, natural shape
         return kref.zo_axpy_nd(theta[None], None, leaf_seed, scale,
                                decay)[0]
-    rows_only = active_idx is not None and backend in ("scan", "gather")
-    obs.get_tracer().count(obs.CTR_AXPY_ROWS, active_idx.shape[0]
-                           if rows_only else theta.shape[0])
+    n_on = None
+    if isinstance(active_idx, tuple):
+        active_idx, n_on = active_idx
+    elif active_idx is not None:
+        n_on = active_idx.shape[0]
+    tr = obs.get_tracer()
+    if tr.enabled and not obs.tracing():
+        rows_only = n_on is not None and backend in ("scan", "gather")
+        tr.count(obs.CTR_AXPY_ROWS, int(n_on) if rows_only
+                 else theta.shape[0])
     if backend == "dense":
         return kref.zo_axpy_nd(theta, mask, leaf_seed, scale, decay)
     if backend == "scan":
         if active_idx is not None:
-            return _rows_axpy(theta, active_idx, leaf_seed, scale, decay)
+            return _rows_axpy(theta, active_idx, n_on, leaf_seed, scale,
+                              decay)
         return _scan_axpy(theta, mask, leaf_seed, scale, decay)
     if backend == "gather":
         if active_idx is None:

@@ -33,6 +33,23 @@ class BlockCfg:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnCfg:
+    """A published ``rope_scaling`` of type yarn (arXiv:2309.00071, as
+    DeepSeek-V2 applies it at every length): rotary frequencies blended
+    between extrapolated and ``factor``-interpolated by a linear ramp
+    over the dims that ``beta_fast`` / ``beta_slow`` rotations bound at
+    ``original_max_position_embeddings``; cos/sin scaled by
+    m(mscale) / m(mscale_all_dim) and the softmax by m(mscale_all_dim)^2,
+    m(s) = 0.1 * s * ln(factor) + 1."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class StageCfg:
     repeat: int
     pattern: Tuple[BlockCfg, ...]
@@ -55,8 +72,10 @@ class ModelConfig:
                                   # traffic ~ 1/attn_k_chunk; hillclimbed)
     pos_emb: str = "rope"            # rope | learned | none
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnCfg] = None   # published yarn rope_scaling
     act: str = "silu"                # silu(=swiglu) | gelu | relu
     norm: str = "rms"                # rms | ln
+    norm_eps: float = 1e-5           # published rms_norm_eps / ln eps
     # MLA (deepseek)
     kv_lora: int = 0
     rope_head_dim: int = 64
@@ -65,7 +84,7 @@ class ModelConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalize top-k weights (published)
     first_dense_d_ff: int = 0        # deepseek: layer-0 dense FFN width
     # mamba
     mamba_d_state: int = 16

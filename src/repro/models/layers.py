@@ -16,6 +16,7 @@ Model stack (DESIGN.md §8); paged attention: DESIGN.md §12.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -45,8 +46,8 @@ def layer_norm(x, scale, bias, eps=1e-5):
 
 def apply_norm(cfg, p, x):
     if cfg.norm == "rms":
-        return rms_norm(x, p["scale"])
-    return layer_norm(x, p["scale"], p["bias"])
+        return rms_norm(x, p["scale"], cfg.norm_eps)
+    return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
 
 
 def norm_params(cfg, d):
@@ -56,14 +57,45 @@ def norm_params(cfg, d):
 
 
 # ------------------------------------------------------------------ rope
-def rope(x, positions, theta=10000.0):
+def yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_freqs(d: int, theta: float, scaling=None):
+    """(d/2,) inverse frequencies; with a ``config.YarnCfg`` the YaRN
+    blend: dims below the beta_fast bound keep theta's frequency, dims
+    above the beta_slow bound take it divided by ``factor``, a linear
+    ramp between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
+    if scaling is None:
+        return freqs
+    s = scaling
+
+    def bound(rotations):
+        return (d * math.log(s.original_max_position_embeddings
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(bound(s.beta_fast)), 0)
+    hi = min(math.ceil(bound(s.beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=F32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return freqs / s.factor * ramp + freqs * (1.0 - ramp)
+
+
+def rope(x, positions, theta=10000.0, scaling=None):
     """x: (B, S, n, d) with d even; positions: (S,) shared across the
     batch, or (B, S) per-lane (the paged serving path, where every lane
-    sits at its own decode position — DESIGN.md §12)."""
+    sits at its own decode position — DESIGN.md §12).  ``scaling``: a
+    ``config.YarnCfg`` or None (plain RoPE)."""
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
+    freqs = rope_freqs(d, theta, scaling)
     ang = positions.astype(F32)[..., None] * freqs       # (S, d/2) | (B, S, d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     if positions.ndim == 1:
         cos, sin = cos[None], sin[None]
     cos = cos[:, :, None, :]
@@ -75,14 +107,15 @@ def rope(x, positions, theta=10000.0):
 
 # -------------------------------------------------------- flash attention
 @functools.partial(jax.jit, static_argnames=("causal", "q_chunk", "k_chunk",
-                                              "k_offset"))
+                                              "k_offset", "sm_scale"))
 def flash_attention(q, k, v, *, causal=True, q_offset=0, k_offset=0,
-                    q_chunk=512, k_chunk=512):
+                    q_chunk=512, k_chunk=512, sm_scale=None):
     """q: (B,Sq,KV,G,dh), k/v: (B,Sk,KV,dh). Returns (B,Sq,KV,G,dh).
 
     ``q_offset``: absolute position of q[0] (for prefill continuation).
     ``k_offset``: position of k[0]; a negative value marks leading
-    always-visible tokens (prefix tuning).
+    always-visible tokens (prefix tuning).  ``sm_scale``: the softmax
+    scale, dh ** -0.5 when None.
     """
     B, Sq, KV, G, dh = q.shape
     Sk = k.shape[1]
@@ -94,7 +127,7 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, k_offset=0,
         k = jnp.pad(k, [(0, 0), (0, pad), (0, 0), (0, 0)])
         v = jnp.pad(v, [(0, 0), (0, pad), (0, 0), (0, 0)])
     nq, nk = Sq // q_chunk, k.shape[1] // k_chunk
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if sm_scale is None else sm_scale
     q_offset = jnp.asarray(q_offset, jnp.int32)
 
     qc = q.reshape(B, nq, q_chunk, KV, G, dh).transpose(1, 0, 2, 3, 4, 5)
@@ -251,8 +284,8 @@ def attn_fwd(cfg, p, x, *, mode, cache=None, pos=0, pc=None, pages=None):
     else:
         positions = pos + jnp.arange(S)
     if cfg.pos_emb == "rope":
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     q = q.reshape(B, S, KV, G, dh)
     if mode not in ("decode", "paged"):
         mesh = ctx.get_mesh()
@@ -336,6 +369,8 @@ def mla_fwd(cfg, p, x, *, mode, cache=None, pos=0):
     Cache holds only (c_kv, k_rope): (lora + rope_dim) per token.  Decode
     uses the weight-absorbed latent form — scores and values are computed
     directly against the latent cache, never materializing per-head K/V.
+    The softmax scale is (dn + dr) ** -0.5, times mscale(mscale_all_dim)
+    squared under a YaRN ``rope_scaling``.
     """
     B, S, D = x.shape
     H, dn, dr, lora = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim, cfg.kv_lora
@@ -343,12 +378,16 @@ def mla_fwd(cfg, p, x, *, mode, cache=None, pos=0):
     q = (h @ p["wq"]).reshape(B, S, H, dn + dr)
     qn, qr = q[..., :dn], q[..., dn:]
     dkv = h @ p["wdkv"]
-    ckv = rms_norm(dkv[..., :lora], p["kv_norm"]["scale"])   # (B,S,lora)
+    ckv = rms_norm(dkv[..., :lora], p["kv_norm"]["scale"],
+                   cfg.norm_eps)                              # (B,S,lora)
     kr = dkv[..., lora:].reshape(B, S, 1, dr)
     positions = pos + jnp.arange(S)
-    qr = rope(qr, positions, cfg.rope_theta)
-    kr = rope(kr, positions, cfg.rope_theta)
-    scale_fix = (dn + dr) ** -0.5  # flash/decode divide by per-part dims
+    qr = rope(qr, positions, cfg.rope_theta, cfg.rope_scaling)
+    kr = rope(kr, positions, cfg.rope_theta, cfg.rope_scaling)
+    scale_fix = (dn + dr) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale_fix *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
 
     if mode == "decode":
         ckv_c = lax.dynamic_update_slice(cache["ckv"], ckv, (0, pos, 0))
@@ -377,7 +416,8 @@ def mla_fwd(cfg, p, x, *, mode, cache=None, pos=0):
         qfull = jnp.concatenate([qn, qr], -1).reshape(B, S, H, 1, dn + dr)
         vpad = jnp.pad(vv, [(0, 0), (0, 0), (0, 0), (0, dr)])
         o = flash_attention(qfull, kfull, vpad, causal=True, q_offset=pos,
-                            q_chunk=cfg.attn_q_chunk, k_chunk=cfg.attn_k_chunk)
+                            q_chunk=cfg.attn_q_chunk, k_chunk=cfg.attn_k_chunk,
+                            sm_scale=scale_fix)
         o = o.reshape(B, S, H, dn + dr)[..., :dn]
         new_cache = None
         if mode == "prefill":

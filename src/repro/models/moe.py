@@ -1,19 +1,28 @@
-"""Mixture-of-Experts FFN with sort-based static-shape dispatch.
+"""Mixture-of-Experts FFN with drop-free grouped-matmul dispatch.
 
-Routing is DeepSeek/Granite-style: softmax over experts, top-k, renormalize.
-Dispatch uses the production sort-based scheme (static shapes, capacity
-drop): token-expert assignments are sorted by expert id, each expert gets a
-contiguous capacity-C slab of a (E*C, D) buffer, expert FFNs run as one
-batched einsum, and outputs scatter back weighted.  All shapes are static
--> jit/pjit friendly.
+Routing is DeepSeek/Granite-style: softmax over experts, greedy top-k,
+renormalized over the k picks where ``cfg.norm_topk_prob`` says so (the
+published ``norm_topk_prob``; DeepSeek-V2-Lite's is false).  Dispatch
+never drops an assignment: a data shard's b*S*k token-expert assignments
+are sorted by expert, each expert's FFN runs over its contiguous run of
+sorted rows as one grouped matmul with dynamic group sizes
+(``lax.ragged_dot``), and the outputs are weighted and scatter-added
+back to their tokens.  Every shape is static (b*S*k rows); only the
+split of the rows among experts moves with the routing.
+
+The three parts are named scopes of the compiled program (``obs``):
+``moe_dispatch`` (router, top-k, sort, gather), ``moe_experts`` (the
+grouped matmuls and their activation) and ``moe_combine`` (weighting
+and scatter-add).
 
 Sharding note (see distributed/sharding.py): expert weights are sharded
 over the *d_ff* axis (tensor parallelism inside every expert) rather than
-over the expert axis.  Router + dispatch then stay device-local (no
-all-to-all); the only collective is the usual TP reduce of the FFN output.
-An expert-sharded (EP) layout is the classic alternative — for ZO
-fine-tuning the TP layout wins because perturbation touches all experts
-uniformly and the dispatch buffers never cross devices.
+over the expert axis, and the dispatch runs on each data shard's own
+rows (``ctx.per_batch_shard``), so router and dispatch stay device-local
+(no all-to-all); the only collective is the usual TP reduce of the FFN
+output.  For ZO fine-tuning the TP layout wins because perturbation
+touches all experts uniformly and the dispatch buffers never cross
+devices.
 
 Model stack / zoo (DESIGN.md §8).
 """
@@ -24,18 +33,9 @@ import jax.numpy as jnp
 
 from repro.distributed import ctx
 from repro.models import layers
+from repro.obs import trace as obs
 
 F32 = jnp.float32
-
-
-def capacity(cfg, tokens: int) -> int:
-    """Per-dispatch-group expert capacity.
-
-    Never exceeds ``tokens`` (a token contributes each expert at most one
-    assignment since top-k picks are distinct), so single-token decode
-    groups get C=1."""
-    c = -(-int(tokens * cfg.top_k * cfg.capacity_factor) // cfg.n_experts)
-    return max(1, min(tokens, -(-c // 4) * 4 if tokens >= 4 else c))
 
 
 def moe_params(cfg, key):
@@ -59,72 +59,23 @@ def moe_params(cfg, key):
 
 
 def moe_fwd(cfg, p, x):
-    """x: (B, S, D) -> (y, aux).
-
-    Dispatch groups = batch rows: capacity is per-row, so every sort /
-    cumsum / scatter is row-local and stays on the owning data shard.
-    Written batched (explicit B dim, not vmap) so the big intermediates
-    can carry sharding constraints — without them the SPMD partitioner
-    all-gathers the dispatch buffers globally.
-    """
-    B, S, D = x.shape
+    """x: (B, S, D) -> (y, aux): every token's k assignments computed,
+    none dropped, whatever the load on an expert.  Routing and dispatch
+    run per data shard (``ctx.per_batch_shard``): the sort, gather and
+    scatter-add only ever move rows of the shard that holds them."""
+    B, S, _ = x.shape
     E, k = cfg.n_experts, cfg.top_k
-    C = capacity(cfg, S)
+    tr = obs.get_tracer()
+    if tr.enabled and not obs.tracing():
+        tr.count(obs.CTR_MOE_ROUTED, B * S * k)
     h = ctx.constrain(layers.apply_norm(cfg, p["norm"], x),
                       "batch", None, None)                     # (B, S, D)
-
-    logits = jnp.einsum("bsd,de->bse", h.astype(F32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, k)                     # (B, S, k)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-
-    # Switch-style load-balance loss
-    me = jnp.mean(probs, axis=(0, 1))
-    ce = jnp.zeros((B, E), F32).at[
-        jnp.arange(B)[:, None], top_e.reshape(B, -1)].add(1.0) / (S * k)
-    aux = E * jnp.sum(me * jnp.mean(ce, axis=0))
-
-    # ---- per-row sort-based dispatch (all ops row-local) ----------------
-    row = lambda a, *ax: ctx.constrain(a, "batch", *ax)
-    e_flat = top_e.reshape(B, S * k)
-    w_flat = top_w.reshape(B, S * k)
-    tok_flat = jnp.broadcast_to(
-        jnp.repeat(jnp.arange(S), k)[None], (B, S * k))
-    order = jnp.argsort(e_flat, axis=-1)
-    take = lambda a: jnp.take_along_axis(a, order, axis=-1)
-    e_s, w_s, tok_s = (row(take(e_flat), None), row(take(w_flat), None),
-                       row(take(tok_flat), None))
-    counts = jax.vmap(lambda e: jnp.zeros((E,), jnp.int32).at[e].add(1))(e_s)
-    starts = jnp.cumsum(counts, axis=-1) - counts
-    pos = jnp.arange(S * k)[None] - jnp.take_along_axis(starts, e_s, axis=-1)
-    keep = pos < C
-    slot = row(jnp.where(keep, e_s * C + pos, E * C), None)    # E*C = trash
-
-    h_s = row(jnp.take_along_axis(h, tok_s[..., None], axis=1),
-              None, None)                                      # (B, S*k, D)
-    # vmapped scatter => batched scatter HLO: partitions on the batch dim
-    # (explicit arange(B) row indices would force a global gather).
-    buf = jax.vmap(lambda s, u: jnp.zeros((E * C + 1, D), x.dtype)
-                   .at[s].set(u))(slot, h_s)
-    buf = row(buf, None, None)
-    eb = ctx.constrain(buf[:, :-1].reshape(B, E, C, D),
-                       "batch", None, None, None)
-
-    # ---- expert FFN (batched swiglu, TP on d_ff) ------------------------
-    g = jnp.einsum("becd,edf->becf", eb, p["we_g"], preferred_element_type=F32)
-    u = jnp.einsum("becd,edf->becf", eb, p["we_u"], preferred_element_type=F32)
-    a = ctx.constrain((jax.nn.silu(g) * u).astype(x.dtype),
-                      "batch", None, None, "model")
-    o = jnp.einsum("becf,efd->becd", a, p["we_d"], preferred_element_type=F32)
-    o = ctx.constrain(o, "batch", None, None, None).reshape(B, E * C, D)
-
-    # ---- combine ---------------------------------------------------------
-    o_s = row(jnp.take_along_axis(o, jnp.clip(slot, 0, E * C - 1)[..., None],
-                                  axis=1), None, None)
-    contrib = row(o_s * jnp.where(keep, w_s, 0.0)[..., None], None, None)
-    y = jax.vmap(lambda t, c: jnp.zeros((S, D), F32).at[t].add(c))(
-        tok_s, contrib)
+    y, me, ce = ctx.per_batch_shard(
+        lambda h, *w: _routed(cfg, h, *w), h, p["router"], p["we_g"],
+        p["we_u"], p["we_d"])
     y = ctx.constrain(y, "batch", None, None)
+    # Switch-style load-balance loss over the whole batch
+    aux = E * jnp.sum(jnp.mean(me, axis=0) * jnp.mean(ce, axis=0))
 
     if cfg.n_shared_experts:
         sg = jax.nn.silu((h @ p["ws_g"]).astype(F32))
@@ -133,3 +84,37 @@ def moe_fwd(cfg, p, x):
                            p["ws_d"], preferred_element_type=F32)
 
     return y.astype(x.dtype), aux
+
+
+def _routed(cfg, h, router, we_g, we_u, we_d):
+    """The routed experts on the rows of h (b, S, D) -> (y (b, S, D) f32,
+    each row's mean router probabilities (b, E) and share of the
+    assignments (b, E))."""
+    b, S, D = h.shape
+    E, k = cfg.n_experts, cfg.top_k
+    T = b * S
+    with obs.stage(obs.MOE_DISPATCH):
+        logits = jnp.einsum("bsd,de->bse", h.astype(F32), router)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(probs, k)                 # (b, S, k)
+        if cfg.norm_topk_prob:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        me = jnp.mean(probs, axis=1)
+        ce = jax.vmap(lambda e: jnp.zeros((E,), F32).at[e].add(1.0))(
+            top_e.reshape(b, S * k)) / (S * k)
+        e_flat = top_e.reshape(T * k)
+        order = jnp.argsort(e_flat)                  # stable: token order
+        tok = order // k                             # kept within an expert
+        sizes = jnp.bincount(e_flat, length=E).astype(jnp.int32)
+        xs = h.reshape(T, D)[tok]                              # (T*k, D)
+
+    with obs.stage(obs.MOE_EXPERTS):
+        gmm = lambda a, w: jax.lax.ragged_dot(a, w, sizes,
+                                              preferred_element_type=F32)
+        a = (jax.nn.silu(gmm(xs, we_g)) * gmm(xs, we_u)).astype(h.dtype)
+        o = gmm(a, we_d)                                       # (T*k, D)
+
+    with obs.stage(obs.MOE_COMBINE):
+        w_s = top_w.reshape(T * k)[order]
+        y = jnp.zeros((T, D), F32).at[tok].add(o * w_s[:, None])
+    return y.reshape(b, S, D), me, ce

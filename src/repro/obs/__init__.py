@@ -39,20 +39,23 @@ from repro.obs.runlog import (DEFAULT_RUNS_DIR, RunDir, RunLog, list_runs,
 from repro.obs.runtime import NULL_SESSION, Session, session
 from repro.obs.sinks import (JSONLSink, RingSink, read_jsonl,
                              spans_from_jsonl)
-from repro.obs.trace import (CTR_AXPY, CTR_AXPY_ROWS, CTR_PROBES,
-                             CTR_SELECTS, CTR_WLOAD, CTR_ZREGEN, FWD_BASE,
-                             FWD_MINUS, FWD_PAIR, FWD_PLUS, GAUGE_ACTIVE,
-                             LOSS_HEAD, NULL, PERTURB, SERVE_DECODE,
+from repro.obs.trace import (CTR_AXPY, CTR_AXPY_ROWS, CTR_MOE_ROUTED,
+                             CTR_PROBES, CTR_SELECTS, CTR_WLOAD, CTR_ZREGEN,
+                             FWD_BASE, FWD_MINUS, FWD_PAIR, FWD_PLUS,
+                             GAUGE_ACTIVE, LOSS_HEAD, MOE_COMBINE,
+                             MOE_DISPATCH, MOE_EXPERTS, NULL, PERTURB,
+                             SERVE_DECODE,
                              SERVE_PREFILL, STAGES, Span, SpanRecord,
                              TRAIN_STEP, Tracer, UPDATE, get_tracer,
                              set_tracer, stage, tracing, use)
 
 __all__ = [
-    "CTR_AXPY", "CTR_AXPY_ROWS", "CTR_PROBES", "CTR_SELECTS", "CTR_WLOAD",
-    "CTR_ZREGEN",
+    "CTR_AXPY", "CTR_AXPY_ROWS", "CTR_MOE_ROUTED", "CTR_PROBES",
+    "CTR_SELECTS", "CTR_WLOAD", "CTR_ZREGEN",
     "Counter", "DEFAULT_RUNS_DIR", "FWD_BASE", "FWD_MINUS", "FWD_PAIR",
     "FWD_PLUS", "GAUGE_ACTIVE", "Gauge", "HealthAccumulator", "Histogram",
-    "JSONLSink", "LATENCY_BUCKETS", "LOSS_HEAD", "NULL", "NULL_SESSION",
+    "JSONLSink", "LATENCY_BUCKETS", "LOSS_HEAD", "MOE_COMBINE",
+    "MOE_DISPATCH", "MOE_EXPERTS", "NULL", "NULL_SESSION",
     "PERTURB", "Registry", "RingSink", "RunDir", "RunLog", "SERVE_DECODE",
     "SERVE_PREFILL", "STAGES", "Session", "Span", "SpanRecord",
     "TRAIN_STEP", "Tracer", "UPDATE", "get_tracer", "list_runs",

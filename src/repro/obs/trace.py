@@ -53,6 +53,11 @@ FWD_PAIR = "forward_pair"     # one paired ±εz forward (fused probe stack)
 FWD_BASE = "forward"          # one_sided's unperturbed baseline forward
 UPDATE = "update_axpy"
 LOSS_HEAD = "loss_head"       # the LM head + cross-entropy, inside a probe
+# The MoE FFN's parts, scopes inside a probe (models.moe), outside STAGES:
+# a stage split counts their ops under the probe they run in.
+MOE_DISPATCH = "moe_dispatch"  # router, top-k, sort by expert, row gather
+MOE_EXPERTS = "moe_experts"    # the grouped expert matmuls + activation
+MOE_COMBINE = "moe_combine"    # routing weights + scatter-add to tokens
 TRAIN_STEP = "train/step"     # the trainer's whole-step record (jit-safe)
 SERVE_PREFILL = "serve/prefill"
 SERVE_DECODE = "serve/decode"
@@ -64,6 +69,7 @@ CTR_PROBES = "probes_evaluated"
 CTR_AXPY = "axpy_sweeps"
 CTR_AXPY_ROWS = "axpy_rows_swept"   # stacked-leaf rows a sweep touches
 CTR_SELECTS = "layer_selections"
+CTR_MOE_ROUTED = "moe_assignments_routed"  # token-expert pairs computed
 # Fused-forward W-traffic counters (repro.fused): VMEM tile loads of
 # weight matrices and z-tile regenerations per step — the structural
 # numbers the paired ±εz probe halves (counted host-side from the same

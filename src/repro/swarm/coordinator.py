@@ -346,7 +346,7 @@ class Coordinator:
             "arrived": np.asarray(scal["arrived"], np.int32),
             "shard_losses": commit_mod.shard_losses_dict(pairs),
         }
-        metrics.update(self.oracle.metrics(seed))
+        metrics.update(self.oracle.metrics(seed, t))
         self.health.record(t, metrics, seed=seed)
         if self.log_every and (t % self.log_every == 0
                                or t == self.steps - 1):
@@ -396,7 +396,8 @@ class Coordinator:
             cm = proto.StepCommit(
                 step=t, seed=seed, g=float(scal["projected_grad"]),
                 loss=float(scal["loss"]),
-                active_layers=int(self.oracle.metrics(seed)["active_layers"]),
+                active_layers=int(
+                    self.oracle.metrics(seed, t)["active_layers"]),
                 membership_epoch=self.epoch, arrived=scal["arrived"],
                 ckpt_worker=ckpt_wid).to_wire()
             self.commit_log.append(cm)

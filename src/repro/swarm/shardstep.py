@@ -77,8 +77,8 @@ class ShardedZOStep:
         est = estimators.build_estimator(zspec, cfg)
         self.est = est
 
-        def probe(params, shard, seed):
-            masks, idxs, _ = est.select(seed, {})
+        def probe(params, shard, seed, step):
+            masks, idxs, _ = est.select(seed, {}, step)
             if est.virtual and cfg.paired_probes:
                 losses = est._vloss_pair(loss_fn, params, shard, seed,
                                          cfg.eps, masks)
@@ -94,14 +94,14 @@ class ShardedZOStep:
             # p (= params - eps*z) dies here: probes never mutate params
             return jnp.stack([lp, lm])
 
-        def commit(params, seed, g):
-            masks, idxs, _ = est.select(seed, {})
+        def commit(params, seed, g, step):
+            masks, idxs, _ = est.select(seed, {}, step)
             decay = 1.0 - cfg.lr * cfg.weight_decay
             return est._ax(params, -jnp.float32(cfg.lr) * g, seed, masks,
                            idxs, decay)
 
-        def sel_metrics(seed):
-            masks, _, n_active = est.select(seed, {})
+        def sel_metrics(seed, step):
+            masks, _, n_active = est.select(seed, {}, step)
             out = {
                 "active_layers": jnp.asarray(n_active, jnp.int32),
                 "n_active_params": jnp.stack(
@@ -117,21 +117,22 @@ class ShardedZOStep:
         self._sel_metrics = jax.jit(sel_metrics)
 
     # ------------------------------------------------------ shard-level
-    def probe_shard(self, params, shard, seed: int) -> np.ndarray:
-        """(l+, l-) for one shard as host float32 — what a worker puts
-        in its :class:`~repro.swarm.proto.StepContribution`."""
-        return np.asarray(self._probe(params, shard, jnp.uint32(seed)),
-                          np.float32)
+    def probe_shard(self, params, shard, seed: int, step: int) -> np.ndarray:
+        """(l+, l-) for one shard of step ``step`` as host float32 — what
+        a worker puts in its :class:`~repro.swarm.proto.StepContribution`."""
+        return np.asarray(self._probe(params, shard, jnp.uint32(seed),
+                                      jnp.int32(step)), np.float32)
 
-    def apply_commit(self, params, seed: int, g: float):
-        """Fold one committed ``(seed, g)`` into params — the elastic
-        fast-forward primitive (donates the old params)."""
-        return self._commit(params, jnp.uint32(seed), jnp.float32(g))
+    def apply_commit(self, params, seed: int, g: float, step: int):
+        """Fold one committed ``(seed, g)`` of step ``step`` into params —
+        the elastic fast-forward primitive (donates the old params)."""
+        return self._commit(params, jnp.uint32(seed), jnp.float32(g),
+                            jnp.int32(step))
 
-    def selection_metrics(self, seed: int) -> Dict:
-        """The layer-selection health scalars for a committed seed;
-        pure function of the seed — no parameters involved."""
-        return dict(self._sel_metrics(jnp.uint32(seed)))
+    def selection_metrics(self, seed: int, step: int) -> Dict:
+        """The layer-selection health scalars for a committed step;
+        pure function of its seed and index — no parameters involved."""
+        return dict(self._sel_metrics(jnp.uint32(seed), jnp.int32(step)))
 
     # ------------------------------------------------------- trainer API
     def __call__(self, params, state, batch, step_idx, base_seed,
@@ -146,13 +147,14 @@ class ShardedZOStep:
                              f"{self.n_shards} shards")
         # dispatch every arrived probe before fetching any — the host
         # reduction then drains them in fixed shard order
-        pending = {i: self._probe(params, shards[i], jnp.uint32(seed))
+        pending = {i: self._probe(params, shards[i], jnp.uint32(seed),
+                                  jnp.int32(t))
                    for i in range(self.n_shards) if arrived[i]}
         pairs = [np.asarray(pending[i], np.float32) if i in pending else None
                  for i in range(self.n_shards)]
         scal = commit_mod.commit_scalars(pairs, self.cfg.eps)
         g = scal["projected_grad"]
-        params = self.apply_commit(params, seed, g)
+        params = self.apply_commit(params, seed, g, t)
         metrics = {
             "loss": scal["loss"],
             "projected_grad": g,
@@ -163,7 +165,7 @@ class ShardedZOStep:
             "arrived": np.asarray(scal["arrived"], np.int32),
             "shard_losses": commit_mod.shard_losses_dict(pairs),
         }
-        metrics.update(self.selection_metrics(seed))
+        metrics.update(self.selection_metrics(seed, t))
         return params, state, metrics
 
 
@@ -233,8 +235,8 @@ class SelectionOracle:
         est = estimators.build_estimator(self.zspec, d.est_cfg)
         zspec, shapes = self.zspec, self.shapes
 
-        def sel_metrics(seed):
-            masks, _, n_active = est.select(seed, {})
+        def sel_metrics(seed, step):
+            masks, _, n_active = est.select(seed, {}, step)
             out = {
                 "active_layers": jnp.asarray(n_active, jnp.int32),
                 "n_active_params": jnp.stack(
@@ -262,5 +264,5 @@ class SelectionOracle:
     def num_layers(self) -> int:
         return self.zspec.num_layers or 0
 
-    def metrics(self, seed: int) -> Dict:
-        return dict(self._sel_metrics(jnp.uint32(seed)))
+    def metrics(self, seed: int, step: int) -> Dict:
+        return dict(self._sel_metrics(jnp.uint32(seed), jnp.int32(step)))

@@ -190,7 +190,8 @@ class Worker:
                 raise proto.ProtocolError(
                     f"commit step {cm.step} carries seed {cm.seed}, "
                     f"lineage says {want}")
-            self.params = self.step.apply_commit(self.params, cm.seed, cm.g)
+            self.params = self.step.apply_commit(self.params, cm.seed, cm.g,
+                                                 cm.step)
             if cm.ckpt_worker == self.wid and self.trainer.ckpt is not None:
                 self.trainer.ckpt.save(
                     self.t + 1, self.params, int(self.base_seed),
@@ -210,7 +211,7 @@ class Worker:
         shards_all = shardstep.shard_batch(batch, self.step.n_shards)
         pairs = {str(s): [float(v) for v in
                           self.step.probe_shard(self.params, shards_all[s],
-                                                seed)]
+                                                seed, t)]
                  for s in self.shards}
         c = proto.StepContribution(
             run_id=self.run_id, membership_epoch=self.epoch, step=t,

@@ -77,6 +77,53 @@ def test_decode_consistency_mla():
         rope_head_dim=8, d_head=16, dtype="float32", max_seq=64))
 
 
+def test_decode_consistency_mla_yarn():
+    """Decode through the latent cache agrees with the train forward
+    under DeepSeek-V2-Lite's YaRN rope, softmax scale and norm eps."""
+    from repro.configs.deepseek_v2_lite import YARN
+    _decode_check(ModelConfig(
+        name="m", d_model=64, n_heads=4, n_kv_heads=4, d_ff=128, vocab=256,
+        stages=(StageCfg(2, (BlockCfg("mla", "dense"),)),), kv_lora=32,
+        rope_head_dim=8, d_head=16, rope_scaling=YARN, norm_eps=1e-6,
+        dtype="float32", max_seq=64))
+
+
+def test_yarn_frequencies_follow_the_published_formula():
+    """DeepSeek-V2-Lite's 64 rope dims, written out from the YaRN
+    definition: dims 0-9 keep base^(-2i/d), dims 23-31 take it over 40,
+    a linear ramp between; cos/sin factor 1, softmax factor mscale^2."""
+    import math
+    from repro.configs.deepseek_v2_lite import YARN
+    d, base = 64, 10000.0
+    extra = base ** (-np.arange(0, d, 2) / d)
+    # correction dims: d ln(4096 / (2 pi rot)) / (2 ln base), rot 32 and 1
+    lo = math.floor(d * math.log(4096 / (32 * 2 * math.pi))
+                    / (2 * math.log(base)))
+    hi = math.ceil(d * math.log(4096 / (2 * math.pi)) / (2 * math.log(base)))
+    assert (lo, hi) == (10, 23)
+    ramp = np.clip((np.arange(32) - lo) / (hi - lo), 0, 1)
+    want = extra / 40 * ramp + extra * (1 - ramp)
+    np.testing.assert_allclose(np.asarray(layers.rope_freqs(d, base, YARN)),
+                               want, rtol=1e-6)
+    m = 0.1 * 0.707 * math.log(40) + 1
+    assert m == pytest.approx(1.2608, abs=1e-4)
+    assert layers.yarn_mscale(40.0, 0.707) ** 2 == pytest.approx(1.5896,
+                                                                 abs=1e-4)
+    # equal mscale and mscale_all_dim: the rotation keeps its length
+    x = jnp.ones((1, 3, 1, d))
+    y = layers.rope(x, jnp.arange(3), base, YARN)
+    np.testing.assert_allclose(
+        np.asarray(jnp.sum(y * y, -1)), np.full((1, 3, 1), d), rtol=1e-5)
+
+
+def test_norm_eps_is_the_configs():
+    cfg = dense_lm("d", 1, 8, 2, 2, 16, 32, dtype="float32", norm_eps=1e-2)
+    x = jnp.full((1, 1, 8), 0.1)
+    y = layers.apply_norm(cfg, {"scale": jnp.ones((8,))}, x)
+    np.testing.assert_allclose(np.asarray(y), 0.1 / np.sqrt(0.01 + 0.01),
+                               rtol=1e-5)
+
+
 def test_decode_consistency_mamba():
     _decode_check(ModelConfig(
         name="mm", d_model=64, n_heads=4, n_kv_heads=4, d_ff=0, vocab=256,
@@ -93,12 +140,11 @@ def test_decode_consistency_xlstm():
 
 
 def test_decode_consistency_moe_dropless():
-    # capacity_factor high enough that no token ever drops
+    # MoE dispatch never drops an assignment, so decode matches train
     _decode_check(ModelConfig(
         name="moe", d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, vocab=256,
         stages=(StageCfg(2, (BlockCfg("attn", "moe"),)),), n_experts=4,
-        top_k=2, moe_d_ff=32, capacity_factor=4.0, dtype="float32",
-        max_seq=64))
+        top_k=2, moe_d_ff=32, dtype="float32", max_seq=64))
 
 
 def test_multi_step_decode_matches_train():

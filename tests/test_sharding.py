@@ -89,7 +89,8 @@ def test_hlo_cost_scan_multiplier():
 @pytest.mark.slow
 def test_dryrun_cell_subprocess():
     """Real lower+compile of one small arch cell on a 64-device host mesh
-    (subprocess so the device-count env doesn't leak into this process)."""
+    (subprocess so the device-count env doesn't leak into this process),
+    with no all-gather under the MoE dispatch's scopes."""
     code = (
         "import os;"
         "os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=64';"
@@ -108,6 +109,13 @@ def test_dryrun_cell_subprocess():
         "            jax.ShapeDtypeStruct((), jnp.uint32));"
         "c = l.compile();"
         "assert c.memory_analysis() is not None;"
+        # the MoE dispatch runs on each data shard's own rows: nothing
+        # under its scopes gathers across shards
+        "import re;"
+        "ag = [ln for ln in c.as_text().splitlines()"
+        "      if re.search(r'= [^ ]+ all-gather(-start)?[(]', ln)"
+        "      and re.search(r'moe_(dispatch|experts|combine)', ln)];"
+        "assert not ag, ag[:2];"
         "print('OK')"
     )
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -178,3 +178,43 @@ def test_two_point_step_sweeps_in_place(one_chip):
     # three sweeps, each one in-place row write per stacked leaf
     assert found["dynamic-update-slice"] == 3 * len(stacked), found
     assert not any(found[op] for op in _WHOLE_LEAF), found
+
+
+def test_dsv2lite_stage_step_sweeps_experts_in_place(one_chip):
+    """The ``lezo-dsv2lite`` step at the benchmark stage's widths
+    (DeepSeek-V2-Lite's dense layer 0 and six MoE layers, batch 16 x 512
+    tokens, params donated): every expert slab (6 x 64 x 2048 x 1408
+    bf16) is updated in place, one row write per sweep, with no copy or
+    fill of its stacked shape, and the step fits one v5e."""
+    from repro.configs import deepseek_v2_lite
+    from repro.models.config import StageCfg
+    full = deepseek_v2_lite.full()
+    mcfg = full.with_(stages=(full.stages[0],
+                              StageCfg(6, full.stages[1].pattern)))
+    params = jax.eval_shape(lambda: lm.init_params(mcfg,
+                                                   jax.random.PRNGKey(0)))
+    spec = zo.build_spec(params, lm.zo_group_fn)
+    step, init = estimators.make_step(
+        lambda p, b, perturb=None: lm.lm_loss(mcfg, p, b, perturb=perturb),
+        spec, estimators.EstimatorConfig(name="two_point", n_drop=5,
+                                         backend="scan"))
+    on_chip = lambda x: _spec(x.shape, x.dtype, one_chip)
+    B, S = 16, 512
+    batch = {"tokens": _spec((B, S), jnp.int32, one_chip),
+             "labels": _spec((B, S), jnp.int32, one_chip),
+             "loss_mask": _spec((B, S), jnp.float32, one_chip)}
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        jax.tree.map(on_chip, params),
+        jax.tree.map(on_chip, jax.eval_shape(init)), batch,
+        _spec((), jnp.int32, one_chip),
+        _spec((), jnp.uint32, one_chip)).compile()
+    moe = params["stages"]["s1"]["b0"]["ffn"]
+    experts = {_hlo_shape(moe[k]) for k in ("we_g", "we_u", "we_d")}
+    assert len(experts) == 2          # we_g and we_u share a shape
+    found = _ops_of_shapes(compiled.as_text(), experts)
+    assert found["dynamic-update-slice"] == 3 * 3, found
+    assert not any(found[op] for op in _WHOLE_LEAF), found
+    mem = compiled.memory_analysis()
+    # AOT: arguments 8,020,831,232 B, temp 5,310,749,696 B
+    assert mem.argument_size_in_bytes == 8_020_831_232
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9

@@ -89,3 +89,24 @@ def test_zo_momentum_beats_zo_sgd():
                                         backend="scan"))
         res[mode] = tr.train()["loss"][-1]
     assert res["zo_momentum"] < res["zo"]
+
+
+def test_lezo_dsv2lite_preset_trains_both_layer_groups():
+    """The DeepSeek-V2-Lite preset (MLA + drop-free MoE after a dense
+    first layer) runs through Trainer.from_spec's jitted step, and the
+    dense first layer, a group of one, is trained too."""
+    import jax
+    from repro import api
+    spec = api.with_overrides(api.preset("lezo-dsv2lite"), {
+        "run.steps": 3, "run.batch_size": 2, "model.seq_len": 16,
+        "run.eval_every": 0, "run.log_every": 1})
+    tr = Trainer.from_spec(spec)
+    before = jax.tree.map(np.asarray, tr.trainable)
+    out = tr.train()
+    assert np.isfinite(out["loss"]).all()
+    after = out["final_params"]
+    for g in ("s0", "s1"):
+        moved = [not np.array_equal(a, np.asarray(b)) for a, b in zip(
+            jax.tree.leaves(before["stages"][g]),
+            jax.tree.leaves(after["stages"][g]))]
+        assert all(moved), g

@@ -114,9 +114,13 @@ def test_selection_coverage(seed):
 def test_quota_apportionment():
     params = {"a": {"w": jnp.ones((21, 2))}, "b": {"w": jnp.ones((3, 2))}}
     spec = zo.build_spec(params, lambda p: p.split("/")[0])
-    q = spec.quotas(18)
-    assert sum(q.values()) == 18
-    assert q["a"] <= 20 and q["b"] <= 2
+    # 18 drops over 21 + 3 layers: 15.75 and 2.25 a step, the leftover
+    # one placed by the step index
+    qs = [spec.quotas(18, step=t) for t in range(24)]
+    for q in qs:
+        assert sum(q.values()) == 18
+        assert q["a"] in (15, 16) and q["b"] in (2, 3)
+    assert sum(q["b"] for q in qs) == 24 * 2.25
 
 
 def test_round_robin_policy():
