@@ -6,8 +6,9 @@ published ``norm_topk_prob``; DeepSeek-V2-Lite's is false).  Dispatch
 never drops an assignment: a data shard's b*S*k token-expert assignments
 are sorted by expert, each expert's FFN runs over its contiguous run of
 sorted rows as one grouped matmul with dynamic group sizes
-(``lax.ragged_dot``), and the outputs are weighted and scatter-added
-back to their tokens.  Every shape is static (b*S*k rows); only the
+(``kernels.gmm.grouped_matmul``: the Pallas megablox kernel on a TPU,
+``lax.ragged_dot`` elsewhere), and the outputs are weighted and
+scatter-added back to their tokens.  Every shape is static (b*S*k rows); only the
 split of the rows among experts moves with the routing.
 
 The three parts are named scopes of the compiled program (``obs``):
@@ -32,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import ctx
+from repro.kernels import gmm as kgmm
 from repro.models import layers
 from repro.obs import trace as obs
 
@@ -109,8 +111,7 @@ def _routed(cfg, h, router, we_g, we_u, we_d):
         xs = h.reshape(T, D)[tok]                              # (T*k, D)
 
     with obs.stage(obs.MOE_EXPERTS):
-        gmm = lambda a, w: jax.lax.ragged_dot(a, w, sizes,
-                                              preferred_element_type=F32)
+        gmm = lambda a, w: kgmm.grouped_matmul(a, w, sizes)
         a = (jax.nn.silu(gmm(xs, we_g)) * gmm(xs, we_u)).astype(h.dtype)
         o = gmm(a, we_d)                                       # (T*k, D)
 

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.models import moe
 from repro.models.config import BlockCfg, ModelConfig, StageCfg
@@ -145,3 +146,125 @@ print('OK')
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300)
     assert "OK" in r.stdout, r.stderr[-2000:]
+
+
+# -- the grouped matmul: megablox gmm (interpreted) against lax.ragged_dot
+
+_GMM_CASES = {
+    # name: (m, k, n, group sizes, (tm, tk, tn), dtype)
+    "empty_groups": (256, 128, 128, (0, 100, 0, 156, 0), (128, 128, 128),
+                     "float32"),
+    "one_group": (256, 128, 128, (0, 0, 256, 0), (128, 128, 128), "float32"),
+    "bounds_on_tiles": (384, 128, 256, (128, 0, 256, 0), (128, 128, 128),
+                        "float32"),
+    "bounds_off_tiles": (384, 128, 256, (37, 90, 129, 128), (128, 128, 256),
+                         "float32"),
+    "irregular_k_n": (256, 384, 384, (60, 120, 76), (128, 256, 256),
+                      "float32"),
+    "bf16_in_f32_out": (256, 256, 128, (17, 0, 200, 39), (128, 128, 128),
+                        "bfloat16"),
+}
+
+
+def _gmm_case(case):
+    m, k, n, sizes, t, dt = _GMM_CASES[case]
+    sizes = jnp.asarray(sizes, jnp.int32)
+    ka, kb = jax.random.split(jax.random.PRNGKey(7))
+    lhs = jax.random.normal(ka, (m, k)).astype(dt)
+    rhs = (jax.random.normal(kb, (len(sizes), k, n)) * k ** -0.5).astype(dt)
+    return lhs, rhs, sizes, t
+
+
+@pytest.mark.parametrize("case", sorted(_GMM_CASES))
+def test_grouped_matmul_kernel_matches_ragged_dot(case, monkeypatch):
+    """With each case's tiling given by the rule, ``grouped_matmul``
+    runs the kernel; its f32 output equals ``lax.ragged_dot``'s, and
+    the eager counter reads the rows the kernel computes (megablox's
+    own count of visited m-tiles x tm)."""
+    import importlib
+    from repro import obs
+    from repro.kernels import gmm
+    lhs, rhs, sizes, t = _gmm_case(case)
+    m, n = lhs.shape[0], rhs.shape[2]
+    monkeypatch.setattr(gmm, "tiling", lambda *shape: t)
+    tr = obs.Tracer()
+    with obs.use(tr):
+        got = gmm.grouped_matmul(lhs, rhs, sizes, interpret=True)
+    want = jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32)
+    assert got.dtype == jnp.float32 and got.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+    mb = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    _, tiles = mb.make_group_metadata(
+        group_sizes=sizes, m=m, tm=t[0], start_group=jnp.int32(0),
+        num_nonzero_groups=len(sizes), visit_empty_groups=False)
+    assert tr.counters[obs.CTR_MOE_GMM_ROWS] == int(tiles) * t[0]
+    assert gmm.rows_computed(sizes, t[0]) == int(tiles) * t[0]
+
+
+@pytest.mark.parametrize("case", ["bounds_off_tiles", "bf16_in_f32_out"])
+def test_grouped_matmul_kernel_gradient_is_ragged_dots(case, monkeypatch):
+    """A first-order gradient through the kernel (interpreted) equals
+    ``lax.ragged_dot``'s, in the inputs' dtypes: the backward is
+    ragged_dot's VJP, not megablox's, whose tgmm reuses the forward's
+    tiling."""
+    from repro.kernels import gmm
+    lhs, rhs, sizes, t = _gmm_case(case)
+    monkeypatch.setattr(gmm, "tiling", lambda *shape: t)
+    cot = jax.random.normal(jax.random.PRNGKey(3),
+                            (lhs.shape[0], rhs.shape[2]))
+
+    def loss(mm):
+        return lambda a, w: jnp.sum(mm(a, w) * cot)
+
+    got = jax.grad(loss(lambda a, w: gmm.grouped_matmul(
+        a, w, sizes, interpret=True)), argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(loss(lambda a, w: jax.lax.ragged_dot(
+        a, w, sizes, preferred_element_type=jnp.float32)),
+        argnums=(0, 1))(lhs, rhs)
+    for g, w, x in zip(got, want, (lhs, rhs)):
+        assert g.dtype == x.dtype and g.shape == x.shape
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   atol=1e-4, rtol=1e-4)
+
+
+def test_grouped_matmul_falls_back_where_no_tiling_fits():
+    """Shapes no tiling fits (a width off the 128-lane tile, as the tiny
+    configs' moe_d_ff 48; rows no m-tile divides; an expert block over
+    the scoped VMEM, as jamba's 4096 x 14336) and the default off a TPU
+    run ``lax.ragged_dot`` itself, and count nothing; the cell's shapes
+    and granite's keep each expert's whole block; a mesh that leaves
+    the call to the partitioner keeps the kernel off."""
+    from jax.sharding import AbstractMesh
+    from repro import obs
+    from repro.distributed import ctx
+    from repro.kernels import gmm
+    assert gmm.tiling(256, 64, 48) is None
+    assert gmm.tiling(100, 128, 128) is None
+    assert gmm.tiling(16384, 4096, 14336) is None
+    assert gmm.tiling(49152, 2048, 1408, 4) is None        # f32 block
+    assert gmm.tiling(49152, 2048, 1408) == (128, 2048, 1408)
+    assert gmm.tiling(49152, 1408, 2048) == (128, 1408, 2048)
+    assert gmm.tiling(6144, 1024, 512) == (128, 1024, 512)
+    sizes = jnp.asarray([3, 0, 2], jnp.int32)
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (5, 16))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (3, 16, 48))
+    want = jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32)
+    tr = obs.Tracer()
+    with obs.use(tr):
+        for interpret in (True, None):
+            got = gmm.grouped_matmul(lhs, rhs, sizes, interpret=interpret)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert obs.CTR_MOE_GMM_ROWS not in tr.counters
+    try:
+        assert ctx.device_local()
+        ctx.set_mesh(AbstractMesh((1, 1), ("data", "model")))
+        assert ctx.device_local()
+        ctx.set_mesh(AbstractMesh((4, 1), ("data", "model")))
+        assert not ctx.device_local()        # outside per_batch_shard
+    finally:
+        ctx.set_mesh(None)

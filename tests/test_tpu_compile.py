@@ -2,7 +2,8 @@
 
 The TPU compiler is installed even where no chip is attached, and it
 compiles for a *described* chip: these tests lower each Pallas kernel
-at real OPT widths for one v5e core and assert that Mosaic accepted it
+at real OPT widths (and the MoE experts' grouped matmul at
+DeepSeek-V2-Lite's) for one v5e core and assert that Mosaic accepted it
 (a ``tpu_custom_call`` in the compiled HLO).  They catch what interpret
 mode cannot — casts Mosaic refuses, block shapes that break the (8, 128)
 tiling, kernels that overflow VMEM — at no chip time.  The ``scan``
@@ -34,7 +35,8 @@ from repro.models.config import dense_lm
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """A described v5e host of four chips (no chip is attached)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -48,9 +50,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -102,6 +109,101 @@ def test_pmatmul_tied_head(one_chip):
                    _spec((K, N), jnp.bfloat16, one_chip),
                    _spec((), jnp.uint32, one_chip),
                    _spec((), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_dsv2lite_experts(one_chip, k, n):
+    """The MoE cell's grouped matmuls (16 x 512 tokens x top-6 = 49152
+    rows over DeepSeek-V2-Lite's 64 experts) with the swept tiling:
+    Mosaic accepts the blocks and they fit the scoped VMEM."""
+    from repro.kernels import gmm
+    m, E = 49152, 64
+
+    def f(lhs, rhs, sizes):
+        return gmm.grouped_matmul(lhs, rhs, sizes, interpret=False)
+
+    _assert_mosaic(f, _spec((m, k), jnp.bfloat16, one_chip),
+                   _spec((E, k, n), jnp.bfloat16, one_chip),
+                   _spec((E,), jnp.int32, one_chip))
+
+
+def _kernel_calls(hlo):
+    """The compiled HLO's Pallas custom calls (the grouped matmul's
+    ``op_name`` ends ``jit(gmm)/pallas_call``, ``jvp(jit(gmm))/...``
+    under a VJP); XLA's own ragged dot lowers to ``ragged-dot-*``."""
+    return [ln for ln in hlo.splitlines()
+            if "custom-call(" in ln and re.search(r'op_name="[^"]*pallas_call"', ln)]
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_dsv2lite_experts_vjp(one_chip, k, n):
+    """A first-order gradient through the cell's grouped matmuls
+    compiles for one v5e: the forward is the kernel and the backward
+    ``lax.ragged_dot``'s (megablox's own backward runs tgmm with the
+    forward's whole-block tiling, which overflows the scoped VMEM)."""
+    from repro.kernels import gmm
+    m, E = 49152, 64
+
+    def f(lhs, rhs, sizes, g):
+        out, vjp = jax.vjp(lambda a, w: gmm.grouped_matmul(
+            a, w, sizes, interpret=False), lhs, rhs)
+        return (out,) + vjp(g)
+
+    hlo = jax.jit(f).lower(_spec((m, k), jnp.bfloat16, one_chip),
+                           _spec((E, k, n), jnp.bfloat16, one_chip),
+                           _spec((E,), jnp.int32, one_chip),
+                           _spec((m, n), jnp.float32, one_chip)
+                           ).compile().as_text()
+    assert len(_kernel_calls(hlo)) == 1
+    assert "ragged-dot" in hlo
+
+
+@pytest.mark.parametrize("shape,axes,kernels", [
+    ((4,), ("data",), 3),
+    ((4, 1), ("data", "model"), 3),
+    ((2, 2), ("data", "model"), 0),
+], ids=["data4", "data4_model1", "data2_model2"])
+def test_moe_grouped_matmul_on_data_shards(v5e_2x2, monkeypatch, shape,
+                                           axes, kernels):
+    """The MoE FFN on a described four-chip host, batch over 'data' and
+    the weights replicated: where every axis beside 'data' has size 1,
+    each data shard runs the three grouped matmuls as the kernel inside
+    ``per_batch_shard``; where 'model' splits the experts, XLA's ragged
+    dot.  Nothing under the MoE scopes gathers across devices."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro import kernels as kernel_mod
+    from repro.distributed import ctx
+    from repro.models import moe
+    from repro.models.config import BlockCfg, ModelConfig, StageCfg
+    monkeypatch.setattr(kernel_mod, "default_interpret", lambda: False)
+    cfg = ModelConfig(name="m", d_model=256, n_heads=2, n_kv_heads=2,
+                      d_ff=0, vocab=32,
+                      stages=(StageCfg(1, (BlockCfg("attn", "moe"),)),),
+                      n_experts=8, top_k=2, moe_d_ff=256, dtype="bfloat16",
+                      max_seq=64)
+    params = jax.eval_shape(lambda: moe.moe_params(cfg,
+                                                   jax.random.PRNGKey(0)))
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(shape), axes)
+    rep = NamedSharding(mesh, P())
+    ps = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), params)
+    xs = jax.ShapeDtypeStruct((8, 64, 256), jnp.bfloat16,
+                              sharding=NamedSharding(mesh, P("data")))
+    ctx.set_mesh(mesh)
+    try:
+        hlo = jax.jit(lambda p, x: moe.moe_fwd(cfg, p, x)).lower(
+            ps, xs).compile().as_text()
+    finally:
+        ctx.set_mesh(None)
+    assert len(_kernel_calls(hlo)) == kernels
+    assert ("ragged-dot" in hlo) == (kernels == 0)
+    ag = [ln for ln in hlo.splitlines()
+          if re.search(r"= [^ ]+ all-gather(-start)?[(]", ln)
+          and re.search(r"moe_(dispatch|experts|combine)", ln)]
+    assert not ag, ag[:2]
 
 
 def _ops_of_shapes(hlo, shapes):
