@@ -42,8 +42,7 @@ def _train(task, mode, estimator, q, n_drop, steps, seed=0):
                        log_every=0, mode=mode, seed=seed,
                        estimator=estimator, est_q=q)
     tr = Trainer(MCFG, task, tcfg,
-                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=1e-3, n_drop=n_drop,
-                                    backend="scan"),
+                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=1e-3, n_drop=n_drop),
                  fo_cfg=fo.FOConfig(lr=5e-4))
     h = tr.train()
     metric = h["val_acc"][-1] if h["val_acc"] else -1.0

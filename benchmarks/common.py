@@ -51,10 +51,10 @@ def make_batch(cfg, batch, seq, seed=0):
             "loss_mask": jnp.ones((batch, seq), jnp.float32)}
 
 
-def make_zo_parts(cfg, n_drop, backend="scan", lr=1e-4, eps=1e-3):
+def make_zo_parts(cfg, n_drop, lr=1e-4, eps=1e-3):
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     spec = zo.build_spec(params, lm.zo_group_fn)
-    zcfg = zo.ZOConfig(eps=eps, lr=lr, n_drop=n_drop, backend=backend)
+    zcfg = zo.ZOConfig(eps=eps, lr=lr, n_drop=n_drop)
     step = jax.jit(zo.make_zo_step(lambda p, b: lm.lm_loss(cfg, p, b),
                                    spec, zcfg))
     return params, spec, zcfg, step

@@ -3,9 +3,8 @@
 two_point vs one_sided(q in {4,16}) vs averaged(q=4), all at LeZO
 sparsity 0.75:
 
-  * wall-clock per optimizer step (CPU, pallas in interpret mode via the
-    default dense backend) — multi-probe estimators pay more compute per
-    step, visible here;
+  * wall-clock per optimizer step (CPU) — multi-probe estimators pay
+    more compute per step, visible here;
   * steps-to-target-loss on the synthetic classification task — the
     FZOO claim: q batched one-sided probes cut the *step count* to a
     fixed loss.  Each estimator runs at the variance-matched learning

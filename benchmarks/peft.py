@@ -22,8 +22,7 @@ def run():
         tr = Trainer(MCFG, TASK,
                      TrainConfig(steps=300, batch_size=16, eval_every=300,
                                  log_every=0, peft=peft),
-                     zo_cfg=zo.ZOConfig(eps=eps, lr=lr, n_drop=n_drop,
-                                        backend="dense"))
+                     zo_cfg=zo.ZOConfig(eps=eps, lr=lr, n_drop=n_drop))
         h = tr.train()
         acc = h["val_acc"][-1] if h["val_acc"] else -1
         vl = h["val_loss"][-1] if h["val_loss"] else -1

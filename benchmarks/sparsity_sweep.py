@@ -24,7 +24,7 @@ def run():
     base = None
     for frac in (0.0, 0.25, 0.5, 0.75):
         n_drop = int(frac * N)
-        params, _, _, step = make_zo_parts(cfg, n_drop, backend="scan")
+        params, _, _, step = make_zo_parts(cfg, n_drop)
         t = timeit(step, params, batch, jnp.int32(0), jnp.uint32(1))
         base = base or t
         rows.append((f"steptime_rho{frac:.2f}", t * 1e6,
@@ -37,8 +37,7 @@ def run():
         tr = Trainer(mcfg, task,
                      TrainConfig(steps=250, batch_size=16, eval_every=0,
                                  log_every=249),
-                     zo_cfg=zo.ZOConfig(eps=1e-3, lr=3e-4, n_drop=n_drop,
-                                        backend="scan"))
+                     zo_cfg=zo.ZOConfig(eps=1e-3, lr=3e-4, n_drop=n_drop))
         h = tr.train()
         rows.append((f"final_loss_drop{n_drop}of4", 0.0,
                      f"{h['loss'][-1]:.3f}"))

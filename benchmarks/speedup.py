@@ -25,7 +25,7 @@ def run():
     n = cfg.num_layers
     times = {}
     for name, n_drop in [("mezo", 0), ("lezo75", int(0.75 * n))]:
-        params, _, _, step = make_zo_parts(cfg, n_drop, backend="scan")
+        params, _, _, step = make_zo_parts(cfg, n_drop)
         times[name] = timeit(step, params, batch, jnp.int32(0), jnp.uint32(1))
         rows.append((f"step_time_{name}", times[name] * 1e6, f"n_drop={n_drop}"))
     rows.append(("computation_speedup", 0.0,
@@ -47,8 +47,7 @@ def run():
             tr = Trainer(mcfg, task,
                          TrainConfig(steps=400, batch_size=16, eval_every=0,
                                      log_every=10),
-                         zo_cfg=zo.ZOConfig(eps=1e-3, lr=lr, n_drop=n_drop,
-                                            backend="scan"))
+                         zo_cfg=zo.ZOConfig(eps=1e-3, lr=lr, n_drop=n_drop))
             h = tr.train()
             idx = next((s for s, l in zip(h["step"], h["loss"])
                         if l < target), None)

@@ -31,14 +31,15 @@ def run():
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     spec = zo.build_spec(params, lm.zo_group_fn)
     masks = {g: jnp.ones((l,), bool) for g, (_, l) in spec.slices.items()}
+    idxs = {g: jnp.arange(l, dtype=jnp.int32)
+            for g, (_, l) in spec.slices.items()}
 
     fwd = jax.jit(lambda p, b: lm.lm_loss(cfg, p, b))
     perturb = jax.jit(functools.partial(
-        zo.tree_axpy, spec=spec, seed=jnp.uint32(1), scale=1e-3,
-        masks=masks, backend="dense"))
+        zo.tree_axpy, spec=spec, seed=jnp.uint32(1), scale=1e-3, idxs=idxs))
     update = jax.jit(functools.partial(
         zo.tree_axpy, spec=spec, seed=jnp.uint32(1), scale=-1e-6,
-        masks=masks, backend="dense"))
+        idxs=idxs))
 
     t_fwd = timeit(fwd, params, batch)
     t_pert = timeit(perturb, params)

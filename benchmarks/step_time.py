@@ -46,7 +46,7 @@ from repro import api, estimators, obs  # noqa: E402
 from repro.core import zo  # noqa: E402
 from repro.models import lm  # noqa: E402
 
-BACKENDS = ("materialized", "virtual_ref")
+FORWARDS = ("materialized", "virtual_ref")
 # Stages each backend must emit: virtual probes never write parameters,
 # so a virtual step has no perturb spans at all — and with paired probes
 # (the default) the ±εz pair rides ONE forward_pair span instead of the
@@ -228,7 +228,7 @@ def run(smoke=False, json_path=None, preset="bench-smoke", jsonl_path=None,
     jit_iters = 3 if smoke else 5
 
     rows, backends = [], {}
-    for fb in BACKENDS:
+    for fb in FORWARDS:
         params, est, loss_fn, ecfg, step, init = _parts(mcfg, espec, fb)
         eager = stage_profile(est, loss_fn, params, batch, eager_iters,
                               jsonl_path=(jsonl_path

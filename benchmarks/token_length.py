@@ -18,7 +18,7 @@ def run():
         batch = make_batch(cfg, 16, seq)
         t = {}
         for name, nd in [("mezo", 0), ("lezo", int(0.75 * N))]:
-            params, _, _, step = make_zo_parts(cfg, nd, backend="scan")
+            params, _, _, step = make_zo_parts(cfg, nd)
             t[name] = timeit(step, params, batch, jnp.int32(0), jnp.uint32(1))
         rows.append((f"seqlen_{seq}", t["mezo"] * 1e6,
                      f"speedup={t['mezo'] / t['lezo']:.2f}x"))

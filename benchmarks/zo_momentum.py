@@ -23,8 +23,7 @@ def run():
         tr = Trainer(MCFG, TASK,
                      TrainConfig(steps=300, batch_size=16, eval_every=300,
                                  log_every=100, mode=mode),
-                     zo_cfg=zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=3,
-                                        backend="scan"))
+                     zo_cfg=zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=3))
         h = tr.train()
         acc = h["val_acc"][-1] if h["val_acc"] else -1
         rows.append((f"lezo75_{mode}", 0.0,

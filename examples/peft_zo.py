@@ -13,7 +13,7 @@ from repro import api
 
 BASE = api.with_overrides(api.preset("tiny-smoke"), {
     "task.signal_rate": 0.35, "model.seq_len": 64,
-    "optimizer.n_drop": 2, "runtime.backend": "dense",
+    "optimizer.n_drop": 2,
     "run.steps": 300, "run.batch_size": 16,
     "run.eval_every": 100, "run.log_every": 100,
 })

@@ -9,7 +9,7 @@ import dataclasses
 from typing import Dict
 
 from repro.api.spec import (Experiment, Estimator, Model, Optimizer, Run,
-                            Runtime, SpecError, Swarm)
+                            SpecError, Swarm)
 
 # The paper's headline recipe at CPU-runnable scale: LeZO (75% of layers
 # dropped per step) + two-point SPSA on the OPT stack.  This preset IS
@@ -50,8 +50,6 @@ PRESETS: Dict[str, Experiment] = {
     "bench-smoke": Experiment(
         model=Model(arch="opt-13b", variant="bench", seq_len=32),
         optimizer=Optimizer(lr=1e-4),
-        # dense axpy backend: the benchmark suite's historical baseline
-        runtime=Runtime(backend="dense"),
         run=Run(steps=120, batch_size=8, eval_every=0, log_every=0)),
     # fast-tier fixture: the 4L/128d CPU model, a handful of steps
     "tiny-smoke": Experiment(

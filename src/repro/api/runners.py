@@ -67,7 +67,7 @@ def derive(spec: Experiment) -> Derived:
         peft=rt.peft, forward_backend=rt.forward_backend)
     zo_cfg = zo.ZOConfig(
         eps=o.eps, lr=o.lr, n_drop=n_drop, policy=o.policy,
-        backend=rt.backend, fused_update=o.fused_update,
+        fused_update=o.fused_update,
         weight_decay=o.weight_decay,
         forward_backend=rt.forward_backend,
         paired_probes=rt.paired_probes)

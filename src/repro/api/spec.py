@@ -13,7 +13,7 @@ Sections:
   * ``task``      — registry task name, or the synthetic stream's knobs
   * ``optimizer`` — the step recipe: mode, eps, lr, sparsity, policy
   * ``estimator`` — ZO gradient estimator and its direction budget
-  * ``runtime``   — kernel/forward backends, mesh, quorum, PEFT
+  * ``runtime``   — forward backend, mesh, quorum, PEFT
   * ``swarm``     — multi-process scalar-sync topology (DESIGN.md §14)
   * ``run``       — steps, batch, seed, eval cadence, checkpoint policy
 
@@ -90,7 +90,6 @@ class Estimator:
 
 @dataclasses.dataclass(frozen=True)
 class Runtime:
-    backend: str = "scan"         # axpy kernel: dense | scan | gather | pallas
     forward_backend: str = "materialized"   # | virtual | virtual_ref
     # stack the virtual ±εz pair (and one_sided's q-chunks) onto one
     # paired fused forward — bit-identical floats, half the W-tile loads

@@ -20,7 +20,6 @@ from repro.api.spec import Experiment, SpecError, UnknownTaskError
 
 MODES = ("zo", "zo_momentum", "fo")
 POLICIES = ("stratified", "uniform")
-BACKENDS = ("dense", "scan", "gather", "pallas")
 FO_OPTIMIZERS = ("sgd", "momentum", "adamw")
 PEFTS = (None, "lora", "prefix")
 MESHES = ("single", "multi_pod")
@@ -111,12 +110,10 @@ def validate(spec: Experiment):
     _require(0.0 < e.importance_decay <= 1.0, "estimator.importance_decay",
              f"must be in (0, 1], got {e.importance_decay}")
 
-    _require(rt.backend in BACKENDS, "runtime.backend",
-             f"unknown kernel backend {rt.backend!r}; pick from {BACKENDS}")
-    _require(rt.forward_backend in costs.FORWARD_BACKENDS,
+    _require(rt.forward_backend in costs.FORWARD_MODES,
              "runtime.forward_backend",
              f"unknown forward_backend {rt.forward_backend!r}; pick from "
-             f"{costs.FORWARD_BACKENDS}")
+             f"{costs.FORWARD_MODES}")
     _require(rt.mesh in MESHES, "runtime.mesh",
              f"unknown mesh {rt.mesh!r}; pick from {MESHES}")
     _require(rt.peft in PEFTS, "runtime.peft",
@@ -131,10 +128,6 @@ def validate(spec: Experiment):
              f"must be in (0, 1], got {rt.quorum}")
 
     # the hoisted cross-section invariants (formerly trainer.py / zo.py)
-    if rt.backend == "gather":
-        _require(o.policy == "stratified", "optimizer.policy",
-                 "runtime.backend='gather' requires the stratified policy "
-                 "(its compact active buffers need static per-group sizes)")
     if rt.forward_backend != "materialized":
         _require(rt.peft is None, "runtime.peft",
                  "forward_backend='virtual' covers full-parameter ZO only "

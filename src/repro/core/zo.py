@@ -40,8 +40,8 @@ groups by a low-discrepancy schedule over the step index
 (:meth:`ZOSpec.quotas`), so every layer of every group is active with
 probability (N - n_drop) / N and the step's total stays N - n_drop.
 Each group's active index vector keeps a static length (its largest
-active count) for the ``gather`` and in-place ``scan`` paths; in a step
-that drops one more, its last slot is disabled.
+active count) for the in-place row sweep (``kernels/ops.zo_axpy``); in a
+step that drops one more, its last slot is disabled.
 """
 from __future__ import annotations
 
@@ -184,8 +184,8 @@ def _slots(ranked, k_max: int, n_off, L: int):
     """Active index slots from ``ranked`` (layer ids, most-kept first):
     the first k_max - n_off of ranked[:k_max], ascending.  With a
     traced ``n_off`` the vector keeps length k_max: the disabled slots
-    come last and hold L (out of range: gathers clamp it, scatters and
-    masks drop it), and the slots travel as (vector, enabled count)."""
+    come last and hold L (out of range: masks drop it), and the slots
+    travel as (vector, enabled count)."""
     cand = ranked[:k_max]
     if isinstance(n_off, int):
         return jnp.sort(cand[:k_max - n_off]).astype(jnp.int32)
@@ -233,8 +233,7 @@ def stratified_select_weighted(spec: ZOSpec, seed, n_drop: int, weights,
     Gumbel top-k by log-weight within each group: heavier layers are kept
     more often, selection stays fully stochastic (every layer has nonzero
     keep probability), and each group's slots have the same static
-    length as :func:`stratified_select`'s, so the gather backend's
-    compact buffers keep their shapes.  Uniform weights recover the
+    length as :func:`stratified_select`'s.  Uniform weights recover the
     unweighted distribution.
     """
     def rank(g, start, L):
@@ -251,25 +250,32 @@ def stratified_select_weighted(spec: ZOSpec, seed, n_drop: int, weights,
 
 
 def uniform_select(spec: ZOSpec, seed, n_drop: int):
-    """Paper policy: global uniform drop (dynamic per-group counts)."""
+    """Paper policy: global uniform drop.  A group's active count varies
+    by step, so its slots are (its active layers ascending, padded to
+    L_g with L_g; the enabled count) — the form of ``_slots``."""
     active = selection.uniform_active(seed, spec.num_layers, n_drop)
-    return spec.split_mask(active), None, spec.num_layers - n_drop
+    masks = spec.split_mask(active)
+    idxs = {}
+    for g, m in masks.items():
+        L = m.shape[0]
+        ids = jnp.where(m, jnp.arange(L, dtype=jnp.int32), L)
+        idxs[g] = jnp.sort(ids), jnp.sum(m, dtype=jnp.int32)
+    return masks, idxs, spec.num_layers - n_drop
 
 
 # ----------------------------------------------------------------- axpy
-def tree_axpy(params, spec: ZOSpec, seed, scale, masks, idxs=None, *,
-              decay=1.0, backend="dense"):
-    """theta <- decay*theta + scale*z on active layers, identity elsewhere."""
+def tree_axpy(params, spec: ZOSpec, seed, scale, idxs, *, decay=1.0):
+    """theta <- decay*theta + scale*z on the active rows ``idxs`` of
+    each stacked leaf (a selection policy's slots), and over the whole
+    of every leaf with no group."""
     obs.get_tracer().count(obs.CTR_AXPY)
     leaves, treedef = jax.tree_util.tree_flatten(params)
     assert len(leaves) == len(spec.paths), "params tree changed since build_spec"
     out = []
     for leaf, path, group in zip(leaves, spec.paths, spec.groups):
-        mask = None if group is None else masks[group]
-        aidx = None if (group is None or idxs is None) else idxs[group]
         out.append(kops.zo_axpy(
             leaf, path=path, seed=seed, scale=scale, decay=decay,
-            mask=mask, active_idx=aidx, backend=backend))
+            active_idx=None if group is None else idxs[group]))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -334,7 +340,6 @@ class ZOConfig:
     lr: float = 1e-6
     n_drop: int = 0               # 0 => MeZO; >0 => LeZO
     policy: str = "stratified"    # stratified | uniform
-    backend: str = "dense"        # dense | scan | gather | pallas
     fused_update: bool = True     # beyond-paper single restore+update pass
     weight_decay: float = 0.0
     # materialized = classic perturb/forward/restore sweeps;
@@ -361,8 +366,6 @@ def make_zo_step(loss_fn: Callable, spec: ZOSpec, cfg: ZOConfig,
     want a different estimator (one_sided, averaged, importance) use
     ``estimators.make_step`` directly, which also threads estimator state.
     """
-    if cfg.backend == "gather" and cfg.policy != "stratified":
-        raise ValueError("gather backend requires the stratified policy")
     from repro import estimators  # local import: estimators builds on zo
 
     ecfg = estimators.from_zo(cfg)

@@ -43,7 +43,6 @@ class ZOMomentumConfig:
     beta: float = 0.9
     history: int = 8              # K regenerated directions
     n_drop: int = 0
-    backend: str = "dense"
     adam: bool = False            # scale by 1/sqrt(v) of projected grads
     adam_beta2: float = 0.999
     adam_eps: float = 1e-8
@@ -68,7 +67,7 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
     est = estimators.build_estimator(
         spec, estimators.EstimatorConfig(
             name="two_point", eps=cfg.eps, lr=cfg.lr, n_drop=cfg.n_drop,
-            policy="stratified", backend=cfg.backend, fused_update=False))
+            policy="stratified", fused_update=False))
 
     def init_state():
         return {"g_hist": jnp.zeros((K,), jnp.float32),
@@ -78,9 +77,6 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
     def step(params, state, batch, step_idx, base_seed):
         seed = rng.fold(jnp.asarray(base_seed, jnp.uint32),
                         jnp.asarray(step_idx, jnp.uint32))
-        ax = lambda p, s, sd, m, i: zo.tree_axpy(
-            p, spec, sd, s, m, i, backend=cfg.backend)
-
         # SPSA probe + immediate restore (unfused: momentum owns the update)
         p, dirs, em = est.estimate(loss_fn, params, batch, seed, state,
                                    step_idx)
@@ -101,10 +97,10 @@ def make_zo_momentum_step(loss_fn: Callable, spec: zo.ZOSpec,
             t_j = step_idx - j
             seed_j = rng.fold(jnp.asarray(base_seed, jnp.uint32),
                               jnp.asarray(t_j, jnp.uint32))
-            masks_j, idxs_j, _ = est.select(seed_j, state, t_j)
+            _, idxs_j, _ = est.select(seed_j, state, t_j)
             scale = -lr * (cfg.beta ** j.astype(jnp.float32)) * g_hist[j]
             valid = (t_j >= 0).astype(jnp.float32)
-            return ax(p, scale * valid, seed_j, masks_j, idxs_j)
+            return zo.tree_axpy(p, spec, seed_j, scale * valid, idxs_j)
 
         p = jax.lax.fori_loop(0, K, apply_j, p)
         new_state = {"g_hist": g_hist, "v": v, "count": count}

@@ -10,8 +10,7 @@ consume ZO gradients through this package's API:
 
 Estimators return :class:`DirectionSet`s — (seed, coefficient) pairs
 whose perturbations regenerate from seeds and are never materialized —
-so optimizer memory stays params + O(q) scalars under every estimator
-and every kernel backend (dense | scan | gather | pallas).
+so optimizer memory stays params + O(q) scalars under every estimator.
 
 Estimator subsystem (DESIGN.md §6).
 """
@@ -51,10 +50,10 @@ def build_estimator(spec: zo.ZOSpec, cfg: EstimatorConfig,
             f"unknown estimator {cfg.name!r}; pick from {ESTIMATORS}")
     if cfg.q < 1:
         raise ValueError(f"q must be >= 1, got {cfg.q}")
-    if cfg.forward_backend not in costs.FORWARD_BACKENDS:
+    if cfg.forward_backend not in costs.FORWARD_MODES:
         raise ValueError(
             f"unknown forward_backend {cfg.forward_backend!r}; pick from "
-            f"{costs.FORWARD_BACKENDS}")
+            f"{costs.FORWARD_MODES}")
     return REGISTRY[cfg.name](spec, cfg, select_fn=select_fn)
 
 
@@ -63,8 +62,8 @@ def from_zo(zo_cfg, name: str = "two_point", q: int = 1,
     """Lift a legacy ``zo.ZOConfig`` into an EstimatorConfig."""
     return EstimatorConfig(
         name=name, eps=zo_cfg.eps, lr=zo_cfg.lr, q=q, n_drop=zo_cfg.n_drop,
-        policy=zo_cfg.policy, backend=zo_cfg.backend,
-        fused_update=zo_cfg.fused_update, weight_decay=zo_cfg.weight_decay,
+        policy=zo_cfg.policy, fused_update=zo_cfg.fused_update,
+        weight_decay=zo_cfg.weight_decay,
         forward_backend=getattr(zo_cfg, "forward_backend", "materialized"),
         paired_probes=getattr(zo_cfg, "paired_probes", True),
         **kw)

@@ -45,11 +45,11 @@ class AveragedSPSA(Estimator):
                 l_plus = self._vloss(loss_fn, p, batch, s, cfg.eps, m)
                 l_minus = self._vloss(loss_fn, p, batch, s, -cfg.eps, m)
             else:
-                p = self._ax(p, cfg.eps, s, m, ix)
+                p = self._ax(p, cfg.eps, s, ix)
                 l_plus = loss_fn(p, batch)
-                p = self._ax(p, -2.0 * cfg.eps, s, m, ix)
+                p = self._ax(p, -2.0 * cfg.eps, s, ix)
                 l_minus = loss_fn(p, batch)
-                p = self._ax(p, cfg.eps, s, m, ix)  # restore before next
+                p = self._ax(p, cfg.eps, s, ix)  # restore before next
             g = (l_plus - l_minus) / (2.0 * cfg.eps)
             coeffs.append(g / q)
             gs.append(jnp.asarray(g, jnp.float32))

@@ -49,7 +49,6 @@ class EstimatorConfig:
                                   # (0 = all q in one widened forward)
     n_drop: int = 0               # 0 => MeZO; >0 => LeZO layer sparsity
     policy: str = "stratified"    # stratified | uniform
-    backend: str = "dense"        # dense | scan | gather | pallas
     fused_update: bool = True
     weight_decay: float = 0.0
     inner: str = "two_point"      # estimator the importance wrapper drives
@@ -75,8 +74,8 @@ class DirectionSet:
     perturbation still sitting in the returned params (0.0 when the probe
     already restored; +eps for two-point's ``-eps`` exit state).
     ``masks``/``idxs``: per-direction layer subsets as returned by the
-    selection policy — (L,) bools / static-size int32 vectors per group,
-    themselves regenerable from the direction seed.
+    selection policy — (L,) bools / active slots per group, themselves
+    regenerable from the direction seed.
     """
     seeds: Tuple
     coeffs: Tuple
@@ -108,15 +107,12 @@ class Estimator:
 
     def __init__(self, spec: zo.ZOSpec, cfg: EstimatorConfig,
                  select_fn: Optional[Callable] = None):
-        if (cfg.backend == "gather" and cfg.policy != "stratified"
-                and select_fn is None and cfg.name != "importance"):
-            raise ValueError("gather backend requires the stratified policy")
         self.spec, self.cfg = spec, cfg
         self._select = select_fn
 
     # -------------------------------------------------------- selection
     def select(self, seed, state, step=None):
-        """-> (masks: {g: (L_g,) bool}, idxs: {g: (k_g,) int32} | None,
+        """-> (masks: {g: (L_g,) bool}, idxs: {g: active slots},
         n_active); ``step`` is the step index (``zo.ZOSpec.quotas``)."""
         if self._select is not None:
             sel = self._select(seed, state, step)
@@ -139,9 +135,8 @@ class Estimator:
         return state
 
     # ------------------------------------------------------------- axpy
-    def _ax(self, p, scale, seed, masks, idxs, decay=1.0, backend=None):
-        return zo.tree_axpy(p, self.spec, seed, scale, masks, idxs,
-                            decay=decay, backend=backend or self.cfg.backend)
+    def _ax(self, p, scale, seed, idxs, decay=1.0):
+        return zo.tree_axpy(p, self.spec, seed, scale, idxs, decay=decay)
 
     # -------------------------------------------------- virtual probing
     @property
@@ -190,8 +185,7 @@ class Estimator:
     def restore_probe(self, params, dirs: DirectionSet):
         for i, r in enumerate(dirs.restore):
             if r != 0.0:
-                params = self._ax(params, r, dirs.seeds[i], dirs.masks[i],
-                                  dirs.idxs[i])
+                params = self._ax(params, r, dirs.seeds[i], dirs.idxs[i])
         return params
 
     def apply_update(self, params, dirs: DirectionSet, lr, decay=1.0):
@@ -202,12 +196,11 @@ class Estimator:
             if self.cfg.fused_update and q == 1 and dirs.restore[0] != 0.0:
                 return sp.fence(self._ax(
                     params, dirs.restore[0] - lr * dirs.coeffs[0],
-                    dirs.seeds[0], dirs.masks[0], dirs.idxs[0], decay))
+                    dirs.seeds[0], dirs.idxs[0], decay))
             params = self.restore_probe(params, dirs)
             for i in range(q):
                 params = self._ax(params, -lr * dirs.coeffs[i], dirs.seeds[i],
-                                  dirs.masks[i], dirs.idxs[i],
-                                  decay if i == 0 else 1.0)
+                                  dirs.idxs[i], decay if i == 0 else 1.0)
             return sp.fence(params)
 
     def step_counts(self) -> Dict:

@@ -26,7 +26,7 @@ from __future__ import annotations
 from typing import Dict
 
 ESTIMATORS = ("two_point", "one_sided", "averaged", "importance")
-FORWARD_BACKENDS = ("materialized", "virtual", "virtual_ref")
+FORWARD_MODES = ("materialized", "virtual", "virtual_ref")
 
 # Baseline the lowered train graph corresponds to (launch/specs.py lowers
 # a fused two-point step: 2 forwards + 3 axpy sweeps).
@@ -45,9 +45,9 @@ def step_counts(name: str, q: int = 1, fused_update: bool = True,
     """
     if q < 1:
         raise ValueError(f"q must be >= 1, got {q}")
-    if forward_backend not in FORWARD_BACKENDS:
+    if forward_backend not in FORWARD_MODES:
         raise ValueError(f"unknown forward_backend {forward_backend!r}; "
-                         f"pick from {FORWARD_BACKENDS}")
+                         f"pick from {FORWARD_MODES}")
     virtual = forward_backend != "materialized"
     if name == "two_point":
         # perturb(+eps), perturb(-2eps), then fused restore+update — or

@@ -6,8 +6,7 @@ the magnitude of that step's projected gradient (the only attribution a
 ZO step yields without extra forwards — a layer that was active while
 |g| was large is credited).  Selection is Gumbel top-k by score within
 each group under the same per-group quotas as
-``stratified_select``, so the gather backend's compact buffers keep
-their static shapes and every backend works unchanged.
+``stratified_select``, with slots of the same static shapes.
 
 State is ``num_layers`` floats — for OPT-13B that is 40 floats next to
 13B parameters, preserving the zero-extra-memory story.

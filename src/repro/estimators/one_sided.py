@@ -12,10 +12,9 @@ so no (q, params) tree outlives the fused forward).  Compute still scales
 with q — see estimators/costs.py — but per-probe overhead (dispatch,
 baseline loss, non-width-scaling work) is paid once.
 
-The probe perturbation inside the vmap always uses the dense axpy path:
-a widened forward wants one fused elementwise RNG+axpy that XLA batches
-across the q-axis.  The configured backend (scan/gather/pallas) governs
-the q sequential update sweeps that follow, where layer skipping pays.
+The probe perturbation inside the vmap is the same active-row sweep
+as the update's, vmapped: each probe sweeps its own rows into its own
+perturbed copy of the parameters.
 
 Memory: optimizer *state* stays O(q) scalars (the DirectionSet), but the
 widened forward transiently holds up to q perturbed copies of the active
@@ -35,7 +34,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import zo
 from repro.estimators.base import DirectionSet, Estimator, direction_seeds
 from repro.obs import trace as obs
 
@@ -55,19 +53,18 @@ class OneSidedBatched(Estimator):
         with obs.stage(obs.FWD_BASE) as sp:
             l0 = sp.fence(loss_fn(params, batch))
         seeds_arr = jnp.stack([jnp.asarray(s, jnp.uint32) for s in seeds])
-        stacked_masks = ({g: jnp.stack([m[g] for m in masks])
-                          for g in masks[0]} if masks[0] else {})
+        stack = lambda *xs: jnp.stack(xs)
+        stacked_masks = jax.tree.map(stack, *masks)
+        stacked_idxs = jax.tree.map(stack, *idxs)
 
-        def probe(seed_i, masks_i):
+        def probe(seed_i, masks_i, idxs_i):
             if self.virtual:
                 # q probes are q *seeds* of the same weights: the vmapped
                 # fused forward regenerates each z_i in-kernel, so no
                 # widened (q, params) perturbed copies ever exist
                 return self._vloss(loss_fn, params, batch, seed_i,
                                    cfg.eps, masks_i)
-            p = zo.tree_axpy(params, self.spec, seed_i, cfg.eps, masks_i,
-                             None, backend="dense")
-            return loss_fn(p, batch)
+            return loss_fn(self._ax(params, cfg.eps, seed_i, idxs_i), batch)
 
         chunk = cfg.q_chunk if 0 < cfg.q_chunk < q else q
         # One stage over all q probes: the vmapped region itself traces,
@@ -75,8 +72,8 @@ class OneSidedBatched(Estimator):
         with obs.stage(obs.FWD_PLUS) as sp:
             parts = []
             for c0 in range(0, q, chunk):
-                sub_masks = {g: m[c0:c0 + chunk]
-                             for g, m in stacked_masks.items()}
+                sub = lambda x: x[c0:c0 + chunk]
+                sub_masks = jax.tree.map(sub, stacked_masks)
                 if self.virtual and cfg.paired_probes:
                     # stacked kernel pass: the chunk's probes share one
                     # sweep over W (per-probe z streams stay intact) —
@@ -85,8 +82,9 @@ class OneSidedBatched(Estimator):
                         loss_fn, params, batch, seeds_arr[c0:c0 + chunk],
                         cfg.eps, sub_masks))
                 else:
-                    parts.append(jax.vmap(probe)(seeds_arr[c0:c0 + chunk],
-                                                 sub_masks))
+                    parts.append(jax.vmap(probe)(
+                        seeds_arr[c0:c0 + chunk], sub_masks,
+                        jax.tree.map(sub, stacked_idxs)))
             losses = sp.fence(parts[0] if len(parts) == 1
                               else jnp.concatenate(parts))
         obs.get_tracer().count(obs.CTR_PROBES, q)

@@ -43,11 +43,11 @@ class TwoPointSPSA(Estimator):
             p, restore = params, 0.0
         else:
             with obs.stage(obs.PERTURB) as sp:
-                p = sp.fence(self._ax(params, cfg.eps, seed, masks, idxs))
+                p = sp.fence(self._ax(params, cfg.eps, seed, idxs))
             with obs.stage(obs.FWD_PLUS) as sp:
                 l_plus = sp.fence(loss_fn(p, batch))
             with obs.stage(obs.PERTURB) as sp:
-                p = sp.fence(self._ax(p, -2.0 * cfg.eps, seed, masks, idxs))
+                p = sp.fence(self._ax(p, -2.0 * cfg.eps, seed, idxs))
             with obs.stage(obs.FWD_MINUS) as sp:
                 l_minus = sp.fence(loss_fn(p, batch))
             restore = cfg.eps

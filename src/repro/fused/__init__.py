@@ -32,23 +32,23 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.estimators.costs import FORWARD_BACKENDS
+from repro.estimators.costs import FORWARD_MODES
 from repro.fused import ref
 from repro.fused.matmul import pmatmul, pmatmul_stack
 from repro.fused.sharded import pmatmul_col_sharded, pmatmul_row_sharded
 from repro.fused.view import IMPLS, LayerPerturb, PerturbCtx, ProbePair
 
-__all__ = ["FORWARD_BACKENDS", "IMPLS", "LayerPerturb", "PerturbCtx",
+__all__ = ["FORWARD_MODES", "IMPLS", "LayerPerturb", "PerturbCtx",
            "ProbePair", "make_ctx", "make_pair_ctx",
            "make_stack_ctx", "pmatmul", "pmatmul_col_sharded",
            "pmatmul_row_sharded", "pmatmul_stack", "ref"]
 
 
 def _impl_of(forward_backend: str) -> str:
-    if forward_backend not in FORWARD_BACKENDS[1:]:
+    if forward_backend not in FORWARD_MODES[1:]:
         raise ValueError(
             f"not a virtual forward backend: {forward_backend!r}; "
-            f"pick from {FORWARD_BACKENDS[1:]}")
+            f"pick from {FORWARD_MODES[1:]}")
     return "ref" if forward_backend == "virtual_ref" else "pallas"
 
 

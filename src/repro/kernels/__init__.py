@@ -1,6 +1,6 @@
-"""Kernel backends of the ZO core (DESIGN.md §2): the fused axpy in
-four interchangeable backends (ops.py dispatch, ref.py jnp oracle,
-zo_axpy.py Pallas kernel) plus the Pallas flash-attention kernel.
+"""Kernels of the ZO core (DESIGN.md §2): the parameter sweep (ops.py,
+with its jnp oracle ref.py), the MoE experts' grouped matmul (gmm.py)
+and the Pallas flash-attention kernel.
 """
 
 

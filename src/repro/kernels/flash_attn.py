@@ -13,7 +13,7 @@ Layout choices: q/k/v arrive flattened to (BH, S, dh) with BH =
 B*KV*G; dh padded to a multiple of 128 by the wrapper (ops-level
 contract) so the MXU matmul dims are hardware-aligned.
 
-Kernel backends of the ZO core (DESIGN.md §2).
+Kernels of the ZO core (DESIGN.md §2).
 """
 from __future__ import annotations
 

@@ -19,7 +19,7 @@ Two paths, chosen from what the call observes:
     beside the batch axes may split the experts, or the batch does not
     divide over the data shards) — GSPMD cannot partition a Pallas call.
 
-Kernel backends (DESIGN.md §2); the MoE FFN (DESIGN.md §8).
+Kernels (DESIGN.md §2); the MoE FFN (DESIGN.md §8).
 """
 from __future__ import annotations
 

@@ -1,7 +1,4 @@
-"""Pure-jnp oracle for the ZO axpy kernel.
-
-Shares the counter RNG with the Pallas kernel body, so results are
-bit-exact (identical element-wise float ops, just without tiling).
+"""Pure-jnp oracle for the ZO parameter sweep (``ops.zo_axpy``).
 
 ``leaf_normal_nd`` generates z for a leaf in its *natural* shape: the
 counter of element (l, i1, ..., ik) is its flat index within layer l and
@@ -9,13 +6,12 @@ the seed is fold(seed, l).  Both are computed from broadcasted iotas —
 pure element-wise ops — so under pjit every device materializes exactly
 its shard of z with no communication and no reshape/reshard.
 
-Kernel backends of the ZO core (DESIGN.md §2).
+Kernels of the ZO core (DESIGN.md §2).
 """
 from __future__ import annotations
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -32,27 +28,21 @@ def _within_layer_index(shape):
     return idx
 
 
-def leaf_normal_nd(seed, shape, layer_ids=None):
-    """z ~ N(0,1) for a (L, ...) leaf: z[l, i] = f(fold(seed, lid[l]), i).
-
-    ``layer_ids``: optional (L,) uint32 — the *global* layer id of each
-    row (defaults to arange).  Lets the gather backend generate z for a
-    compacted subset of layers that matches the dense full-stack values.
-    """
+def leaf_normal_nd(seed, shape):
+    """z ~ N(0,1) for a (L, ...) leaf: z[l, i] = f(fold(seed, l), i)."""
     L = shape[0]
-    if layer_ids is None:
-        layer_ids = jnp.arange(L, dtype=jnp.uint32)
-    seeds = rng.fold(jnp.asarray(seed, jnp.uint32), layer_ids)
+    seeds = rng.fold(jnp.asarray(seed, jnp.uint32),
+                     jnp.arange(L, dtype=jnp.uint32))
     seeds = seeds.reshape((L,) + (1,) * (len(shape) - 1))
     idx = _within_layer_index(shape)
     return rng.counter_normal(seeds, idx)
 
 
-def zo_axpy_nd(theta, mask, seed, scale, decay, layer_ids=None):
+def zo_axpy_nd(theta, mask, seed, scale, decay):
     """decay*theta + scale*z on rows where mask, theta elsewhere.
 
     theta: (L, ...); mask: (L,) bool or None (all active)."""
-    z = leaf_normal_nd(seed, theta.shape, layer_ids)
+    z = leaf_normal_nd(seed, theta.shape)
     x = theta.astype(jnp.float32)
     y = (jnp.asarray(decay, jnp.float32) * x
          + jnp.asarray(scale, jnp.float32) * z).astype(theta.dtype)
@@ -61,18 +51,3 @@ def zo_axpy_nd(theta, mask, seed, scale, decay, layer_ids=None):
     mshape = (theta.shape[0],) + (1,) * (theta.ndim - 1)
     return jnp.where(mask.reshape(mshape), y, theta)
 
-
-# 2-D view kept as the direct oracle for the Pallas kernel's layout.
-def leaf_normal(seed, L, n):
-    seeds = rng.fold(jnp.asarray(seed, jnp.uint32), jnp.arange(L, dtype=jnp.uint32))
-    idx = jnp.arange(n, dtype=jnp.uint32)
-    return jax.vmap(lambda s: rng.counter_normal(s, idx))(seeds)
-
-
-def zo_axpy_2d(theta, mask, seed, scale, decay):
-    L, n = theta.shape
-    z = leaf_normal(seed, L, n)
-    x = theta.astype(jnp.float32)
-    y = jnp.asarray(decay, jnp.float32) * x + jnp.asarray(scale, jnp.float32) * z
-    y = y.astype(theta.dtype)
-    return jnp.where(mask[:, None], y, theta)

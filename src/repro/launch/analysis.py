@@ -15,8 +15,7 @@ recursively through the call graph with loop multipliers:
   * collective bytes per kind, with trip-count multipliers; all-reduce
     counted 2x (ring reduce + broadcast), all-gather / all-to-all /
     collective-permute / reduce-scatter counted at result size.
-  * conditional: max over branches (conservative for LeZO's scan+cond
-    backend; the gather backend needs no conditionals).
+  * conditional: max over branches (conservative).
 
 Shapes are post-SPMD-partitioning, so everything is per-device.
 

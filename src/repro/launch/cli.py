@@ -44,7 +44,6 @@ ALIASES = {
     "--sparsity": "optimizer.sparsity",
     "--estimator": "estimator.name",
     "--q": "estimator.q",
-    "--backend": "runtime.backend",
     "--forward-backend": "runtime.forward_backend",
     "--peft": "runtime.peft",
     "--quorum": "runtime.quorum",

@@ -59,19 +59,18 @@ def input_specs(cfg: ModelConfig, shape) -> Dict[str, Any]:
 
 
 def zo_variant(cfg: ModelConfig, variant: str) -> zo.ZOConfig:
-    """faithful = the paper's MeZO-style LeZO (dense masked passes,
-    separate restore+update, uniform policy); optimized = beyond-paper
-    (static-gather active subset, fused restore+update)."""
+    """faithful = the paper's MeZO-style LeZO (separate restore+update,
+    uniform policy); optimized = beyond-paper (stratified policy, fused
+    restore+update)."""
     n_drop = int(0.75 * cfg.num_layers)
     if variant == "faithful":
-        return zo.ZOConfig(n_drop=n_drop, policy="uniform", backend="dense",
+        return zo.ZOConfig(n_drop=n_drop, policy="uniform",
                            fused_update=False)
     if variant == "optimized":
         return zo.ZOConfig(n_drop=n_drop, policy="stratified",
-                           backend="gather", fused_update=True)
+                           fused_update=True)
     if variant == "mezo":
-        return zo.ZOConfig(n_drop=0, policy="uniform", backend="dense",
-                           fused_update=False)
+        return zo.ZOConfig(n_drop=0, policy="uniform", fused_update=False)
     raise ValueError(variant)
 
 

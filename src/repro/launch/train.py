@@ -2,7 +2,7 @@
 spec CLI (``python -m repro.launch train``, see launch/cli.py).
 
 Every historical flag (``--arch --optimizer --estimator --q --lr --eps
---sparsity --backend --forward-backend --peft --task --seq-len
+--sparsity --forward-backend --peft --task --seq-len
 --ckpt-dir --ckpt-every --quorum --loss-shards --seed --steps
 --batch-size --out``) is accepted unchanged: they are exactly the
 generated alias flags of the spec CLI, so there is no per-command

@@ -87,18 +87,18 @@ class ShardedZOStep:
                 lp = est._vloss(loss_fn, params, shard, seed, cfg.eps, masks)
                 lm = est._vloss(loss_fn, params, shard, seed, -cfg.eps, masks)
                 return jnp.stack([lp, lm])
-            p = est._ax(params, cfg.eps, seed, masks, idxs)
+            p = est._ax(params, cfg.eps, seed, idxs)
             lp = loss_fn(p, shard)
-            p = est._ax(p, -2.0 * cfg.eps, seed, masks, idxs)
+            p = est._ax(p, -2.0 * cfg.eps, seed, idxs)
             lm = loss_fn(p, shard)
             # p (= params - eps*z) dies here: probes never mutate params
             return jnp.stack([lp, lm])
 
         def commit(params, seed, g, step):
-            masks, idxs, _ = est.select(seed, {}, step)
+            _, idxs, _ = est.select(seed, {}, step)
             decay = 1.0 - cfg.lr * cfg.weight_decay
-            return est._ax(params, -jnp.float32(cfg.lr) * g, seed, masks,
-                           idxs, decay)
+            return est._ax(params, -jnp.float32(cfg.lr) * g, seed, idxs,
+                           decay)
 
         def sel_metrics(seed, step):
             masks, _, n_active = est.select(seed, {}, step)

@@ -253,7 +253,7 @@ class Trainer:
         elif self.tcfg.mode == "zo_momentum":
             mcfg = zo_adaptive.ZOMomentumConfig(
                 eps=self.zo_cfg.eps, lr=self.zo_cfg.lr,
-                n_drop=self.zo_cfg.n_drop, backend=self.zo_cfg.backend)
+                n_drop=self.zo_cfg.n_drop)
             step, init = zo_adaptive.make_zo_momentum_step(
                 self.loss_fn, self.spec, mcfg)
             self._mom_step = jax.jit(step, donate_argnums=(0, 1))

@@ -49,6 +49,17 @@ def test_from_dict_defaults_and_unknown_keys():
         api.from_dict({"nonsection": {}})
 
 
+def test_stored_spec_naming_the_removed_sweep_option_is_refused():
+    """The runtime section's ``backend`` field chose among four
+    parameter sweeps; one is left, so a spec saved with the field fails
+    as any unknown field does."""
+    stored = api.to_dict(api.presets.get("lezo-opt13b"))
+    stored["runtime"]["backend"] = "scan"
+    with pytest.raises(api.SpecError, match="backend") as ei:
+        api.from_dict(stored)
+    assert "runtime" in str(ei.value)
+
+
 def test_with_overrides_coercion_and_errors():
     s = api.Experiment()
     s2 = api.with_overrides(s, {
@@ -93,15 +104,14 @@ ILLEGAL = [
       "model.arch": "granite-moe-1b-a400m"}, "runtime.forward_backend"),
     ({"runtime.forward_backend": "virtual_ref",
       "model.arch": "xlstm-350m"}, "runtime.forward_backend"),
-    ({"runtime.backend": "gather", "optimizer.policy": "uniform"},
-     "optimizer.policy"),
+    ({"optimizer.policy": "round_robin"}, "optimizer.policy"),
     ({"estimator.q": 0}, "estimator.q"),
     ({"estimator.q": -4}, "estimator.q"),
     ({"runtime.quorum": 0.0}, "runtime.quorum"),
     ({"runtime.quorum": 1.5}, "runtime.quorum"),
     ({"estimator.name": "three_point"}, "estimator.name"),
     ({"estimator.inner": "importance"}, "estimator.inner"),
-    ({"runtime.backend": "cuda"}, "runtime.backend"),
+    ({"runtime.mesh": "torus"}, "runtime.mesh"),
     ({"runtime.forward_backend": "imaginary"}, "runtime.forward_backend"),
     ({"optimizer.mode": "sgd"}, "optimizer.mode"),
     ({"optimizer.policy": "fancy"}, "optimizer.policy"),
@@ -152,18 +162,18 @@ def test_validate_accepts_every_preset():
     q=st.integers(min_value=1, max_value=64),
     estimator=st.sampled_from(("two_point", "one_sided", "averaged",
                                "importance")),
-    backend=st.sampled_from(("dense", "scan", "gather", "pallas")),
+    policy=st.sampled_from(("stratified", "uniform")),
 )
 def test_legal_space_always_validates(lr, eps, sparsity, q, estimator,
-                                      backend):
+                                      policy):
     """No legal combination of the core hyperparameters is rejected —
     validate() only hoists real invariants, it adds no new constraints."""
     spec = api.with_overrides(api.presets.get("default"), {
         "optimizer.lr": lr, "optimizer.eps": eps,
         "optimizer.sparsity": sparsity, "estimator.q": q,
-        "estimator.name": estimator, "runtime.backend": backend,
+        "estimator.name": estimator, "optimizer.policy": policy,
     })
-    api.validate(spec)   # gather+stratified (the default policy) is legal
+    api.validate(spec)
 
 
 @settings(max_examples=30, deadline=None)
@@ -230,7 +240,7 @@ def test_legacy_vs_spec_bit_identical(estimator, fb):
                        seed=0, estimator=estimator, est_q=q,
                        forward_backend=fb)
     zcfg = zo.ZOConfig(eps=1e-3, lr=1e-4,
-                       n_drop=int(0.75 * mcfg.num_layers), backend="scan",
+                       n_drop=int(0.75 * mcfg.num_layers),
                        forward_backend=fb)
     hist = Trainer(mcfg, task, tcfg, zo_cfg=zcfg).train()
     assert hist["loss"] == res["history"]["loss"]
@@ -280,7 +290,7 @@ def test_legacy_checkpoints_have_no_spec_and_still_resume(tmp_path):
     task = synthetic.TaskConfig(vocab=mcfg.vocab, seq_len=32, n_classes=2)
     tcfg = TrainConfig(steps=4, batch_size=4, eval_every=0, log_every=1,
                        ckpt_dir=str(tmp_path / "l"), ckpt_every=2)
-    zcfg = zo.ZOConfig(n_drop=1, backend="scan")
+    zcfg = zo.ZOConfig(n_drop=1)
     Trainer(mcfg, task, tcfg, zo_cfg=zcfg).train()
     tr = Trainer(mcfg, task, tcfg, zo_cfg=zcfg)
     assert "spec" not in tr.ckpt.read_manifest()["extra"]

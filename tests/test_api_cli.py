@@ -133,7 +133,7 @@ def test_legacy_train_shim_accepts_historical_flags(tmp_path):
         "--arch", "opt-13b", "--variant", "smoke", "--optimizer", "mezo",
         "--estimator", "two_point", "--q", "1", "--steps", "3",
         "--batch-size", "4", "--lr", "1e-4", "--eps", "1e-3",
-        "--backend", "scan", "--seq-len", "32", "--seed", "0",
+        "--seq-len", "32", "--seed", "0",
         "--out", str(out)])
     assert result["summary"]["n_drop"] == 0          # mezo implication
     assert os.path.exists(out)

@@ -37,7 +37,7 @@ def test_arch_smoke(arch):
     n_drop = max(1, int(0.5 * spec.num_layers))
     step = jax.jit(zo.make_zo_step(
         lambda p, b: lm.lm_loss(cfg, p, b), spec,
-        zo.ZOConfig(n_drop=n_drop, lr=1e-4, backend="gather")))
+        zo.ZOConfig(n_drop=n_drop, lr=1e-4)))
     p2, metrics = step(params, batch, jnp.int32(0), jnp.uint32(1))
     assert np.isfinite(float(metrics["loss"]))
     assert int(metrics["active_layers"]) == spec.num_layers - n_drop

@@ -31,7 +31,7 @@ task = synthetic.TaskConfig(vocab=256, seq_len=48, n_classes=2)
 data = synthetic.make_dataset(task, 256)
 params = lm.init_params(mcfg, jax.random.PRNGKey(0))
 spec = zo.build_spec(params, lm.zo_group_fn)
-zcfg = zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1, backend='gather')
+zcfg = zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1)
 loss_fn = lambda p, b: lm.lm_loss(mcfg, p, b)
 base_seed = jnp.uint32(rng.fold_py(0, 0xC0FFEE))
 

@@ -1,4 +1,4 @@
-"""Estimator subsystem: legacy equivalence, bias, invariants, backends.
+"""Estimator subsystem: legacy equivalence, bias, invariants, policies.
 
 The load-bearing test is bit-identity of ``two_point`` (through
 ``zo.make_zo_step``, now a shim over the subsystem) against an inline
@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _oracle
 from repro import estimators
 from repro.core import rng, zo
 from repro.kernels import ref as kref
@@ -42,9 +43,7 @@ def _legacy_zo_step(loss_fn, spec, cfg):
                                                          cfg.n_drop)
         else:
             masks, idxs, n_active = zo.uniform_select(spec, seed, cfg.n_drop)
-        ax = lambda p, s, d=1.0: zo.tree_axpy(
-            p, spec, seed, s, masks, idxs, decay=d,
-            backend=cfg.backend)
+        ax = lambda p, s, d=1.0: zo.tree_axpy(p, spec, seed, s, idxs, decay=d)
 
         p = ax(params, cfg.eps)
         l_plus = loss_fn(p, batch)
@@ -66,14 +65,21 @@ def _legacy_zo_step(loss_fn, spec, cfg):
     return step
 
 
+# The selection policies the one sweep serves: LeZO's stratified and
+# uniform drops, and MeZO (no drop).
+POLICIES = {"stratified": {"n_drop": 2, "policy": "stratified"},
+            "uniform": {"n_drop": 2, "policy": "uniform"},
+            "mezo": {"n_drop": 0}}
+
+
 # ----------------------------------------------------- legacy equivalence
-@pytest.mark.parametrize("backend", ["dense", "scan", "gather"])
+@pytest.mark.parametrize("policy", sorted(POLICIES))
 @pytest.mark.parametrize("fused", [True, False])
-def test_two_point_bit_identical_to_legacy(backend, fused):
+def test_two_point_bit_identical_to_legacy(policy, fused):
     params = _params()
     spec = _spec(params)
-    cfg = zo.ZOConfig(n_drop=2, lr=1e-3, weight_decay=0.1, backend=backend,
-                      fused_update=fused)
+    cfg = zo.ZOConfig(lr=1e-3, weight_decay=0.1, fused_update=fused,
+                      **POLICIES[policy])
     old = jax.jit(_legacy_zo_step(_loss, spec, cfg))
     new = jax.jit(zo.make_zo_step(_loss, spec, cfg))
     p_old, m_old = old(params, None, jnp.int32(3), jnp.uint32(9))
@@ -238,24 +244,27 @@ def test_state_is_o_q_scalars(name, q):
         num_layers=spec.num_layers)
 
 
-# ------------------------------------------------- cross-backend property
-@pytest.mark.parametrize("backend", ["scan", "gather", "pallas"])
+# -------------------------------------------------- sweep vs dense oracle
+@pytest.mark.parametrize("policy", sorted(POLICIES))
 @pytest.mark.parametrize("name,q", [("two_point", 1), ("one_sided", 4),
                                     ("averaged", 2), ("importance", 1)])
-def test_backend_matches_dense_per_estimator(name, q, backend):
+def test_backend_matches_dense_per_estimator(name, q, policy, monkeypatch):
+    """Each estimator's step through the in-place row sweep matches the
+    same step with every sweep replaced by the dense oracle."""
     params = _params()
     spec = _spec(params)
-    want = got = None
-    for be in ("dense", backend):
-        ecfg = estimators.EstimatorConfig(name=name, q=q, n_drop=2, lr=1e-3,
-                                          eps=1e-3, backend=be)
+    ecfg = estimators.EstimatorConfig(name=name, q=q, lr=1e-3, eps=1e-3,
+                                      **POLICIES[policy])
+
+    def run():
         step, init = estimators.make_step(_loss, spec, ecfg)
         p, _, _ = jax.jit(step)(params, init(), None, jnp.int32(1),
                                 jnp.uint32(5))
-        if be == "dense":
-            want = p
-        else:
-            got = p
+        return p
+
+    got = run()
+    monkeypatch.setattr(zo, "tree_axpy", _oracle.tree_axpy)
+    want = run()
     for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 

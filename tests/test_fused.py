@@ -147,7 +147,7 @@ def test_virtual_weight_matches_axpy_unstacked_and_stacked():
     ws = jax.random.normal(key, (6, 24, 40))
     mask = jnp.asarray([1, 0, 1, 1, 0, 1], bool)
     wm = kops.zo_axpy(ws, path="stages/s0/b0/mix/wq", seed=step_seed,
-                      scale=1e-3, mask=mask)
+                      scale=1e-3, active_idx=jnp.nonzero(mask)[0])
     for l in range(6):
         weff = fref.pvec(ws[l],
                          fref.layer_seed(step_seed, "stages/s0/b0/mix/wq", l),
@@ -190,9 +190,9 @@ def test_virtual_loss_equals_materialized(n_drop):
     batch = _batch(mcfg.vocab)
     for t in range(3):
         seed = rng.fold(jnp.uint32(9), jnp.uint32(t))
-        masks, _, _ = zo.stratified_select(spec, seed, n_drop)
+        masks, idxs, _ = zo.stratified_select(spec, seed, n_drop)
         for sign in (1.0, -1.0):
-            pmat = zo.tree_axpy(params, spec, seed, sign * 1e-3, masks)
+            pmat = zo.tree_axpy(params, spec, seed, sign * 1e-3, idxs)
             want = float(lm.lm_loss(mcfg, pmat, batch))
             ctx = fused.make_ctx(seed, sign * 1e-3, masks, "virtual_ref")
             got = float(lm.lm_loss(mcfg, params, batch, perturb=ctx))
@@ -208,8 +208,8 @@ def test_virtual_pallas_loss_close_to_materialized():
     spec = zo.build_spec(params, lm.zo_group_fn)
     batch = _batch(mcfg.vocab, B=2, S=16)
     seed = jnp.uint32(11)
-    masks, _, _ = zo.stratified_select(spec, seed, 1)
-    pmat = zo.tree_axpy(params, spec, seed, 1e-3, masks)
+    masks, idxs, _ = zo.stratified_select(spec, seed, 1)
+    pmat = zo.tree_axpy(params, spec, seed, 1e-3, idxs)
     want = float(lm.lm_loss(mcfg, pmat, batch))
     ctx = fused.make_ctx(seed, 1e-3, masks, "virtual")
     got = float(lm.lm_loss(mcfg, params, batch, perturb=ctx))
@@ -398,7 +398,8 @@ def test_paired_z_streams_match_axpy():
             jnp.broadcast_to(mask[l], (2,)))
         for p, sign in enumerate((1.0, -1.0)):
             wm = kops.zo_axpy(ws, path=path, seed=step_seed,
-                              scale=sign * 1e-3, mask=mask)
+                              scale=sign * 1e-3,
+                              active_idx=jnp.nonzero(mask)[0])
             assert np.array_equal(np.asarray(got[p]),
                                   np.asarray(x[p] @ wm[l])), (l, p)
     # tied head: trans counters over embed/tok.T
@@ -581,12 +582,12 @@ def test_interpret_autodetects_platform():
     on it — no kernel entry point defaults to interpret=True."""
     import inspect
     from repro import kernels
-    from repro.kernels import flash_attn, zo_axpy
+    from repro.kernels import flash_attn, gmm
     assert kernels.default_interpret() == (jax.default_backend() != "tpu")
     assert kernels.resolve_interpret(None) == kernels.default_interpret()
     assert kernels.resolve_interpret(False) is False
     for fn in (fused_matmul.pmatmul, fused_matmul.pmatmul_stack,
-               zo_axpy.zo_axpy_2d, flash_attn.flash_attention_pallas):
+               gmm.grouped_matmul, flash_attn.flash_attention_pallas):
         sig = inspect.signature(inspect.unwrap(fn))
         assert sig.parameters["interpret"].default is None, fn
 

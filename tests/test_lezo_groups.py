@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _oracle
 from repro import obs
 from repro.core import zo
 from repro.kernels import ops
@@ -90,8 +91,7 @@ def test_disabled_slots_sweep_nothing():
                                                      step=jnp.int32(t))
         with obs.use(tr):
             tr.counters.clear()
-            out = zo.tree_axpy(params, spec, seed, 1.0, masks, idxs,
-                               backend="scan")
+            out = zo.tree_axpy(params, spec, seed, 1.0, idxs)
         # two stacked leaves a group, each its enabled rows
         assert tr.counters[obs.CTR_AXPY_ROWS] == 2 * n_active
         for g in ("s0", "s1"):
@@ -99,17 +99,46 @@ def test_disabled_slots_sweep_nothing():
             assert np.array_equal(moved, np.asarray(masks[g]))
 
 
-@pytest.mark.parametrize("backend", ["dense", "scan", "gather", "pallas"])
-def test_backends_agree_on_uneven_groups(backend):
+@pytest.mark.parametrize("n_drop", [0, 3, 6])
+def test_uniform_select_slots_match_its_masks(n_drop):
+    """The uniform policy's slots per group: its active layers ascending,
+    padded to L_g with L_g, and the enabled count; the counts add up to
+    the step's active layers."""
+    _, spec = _spec({"s0": 1, "s1": 6})
+    for seed in range(20):
+        masks, idxs, n_active = zo.uniform_select(spec, jnp.uint32(seed),
+                                                  n_drop)
+        total = 0
+        for g, (_, L) in spec.slices.items():
+            vec, n = (np.asarray(x) for x in idxs[g])
+            m = np.asarray(masks[g])
+            assert vec.shape == (L,) and int(n) == m.sum()
+            assert vec[:n].tolist() == np.flatnonzero(m).tolist()
+            assert (vec[n:] == L).all()
+            total += int(n)
+        assert total == n_active == spec.num_layers - n_drop
+
+
+_SELECT = {
+    "stratified": lambda spec, s: zo.stratified_select(spec, s, 5,
+                                                       step=jnp.int32(4)),
+    "weighted": lambda spec, s: zo.stratified_select_weighted(
+        spec, s, 5, jnp.arange(1.0, 8.0), step=jnp.int32(4)),
+    "uniform": lambda spec, s: zo.uniform_select(spec, s, 5),
+    "mezo": lambda spec, s: zo.stratified_select(spec, s, 0),
+}
+
+
+@pytest.mark.parametrize("policy", sorted(_SELECT))
+def test_backends_agree_on_uneven_groups(policy):
+    """The jitted row sweep over each policy's slots for a 1 + 6 layer
+    stack matches the dense oracle."""
     params, spec = _spec({"s0": 1, "s1": 6})
     params = jax.tree.map(lambda x: x + 1.0, params)
-    masks, idxs, _ = zo.stratified_select(spec, jnp.uint32(3), 5,
-                                          step=jnp.int32(4))
-    want = zo.tree_axpy(params, spec, jnp.uint32(3), 0.5, masks, idxs,
-                        backend="dense")
-    got = jax.jit(lambda p, m, i: zo.tree_axpy(p, spec, jnp.uint32(3), 0.5,
-                                               m, i, backend=backend))(
-        params, masks, idxs)
+    _, idxs, _ = _SELECT[policy](spec, jnp.uint32(3))
+    want = _oracle.tree_axpy(params, spec, jnp.uint32(3), 0.5, idxs)
+    got = jax.jit(lambda p, i: zo.tree_axpy(p, spec, jnp.uint32(3), 0.5,
+                                            i))(params, idxs)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
@@ -141,6 +170,6 @@ def test_rows_sweep_stops_at_the_enabled_count():
     theta = jnp.ones((4, 3), jnp.float32)
     idx = jnp.asarray([0, 2, 4], jnp.int32)          # slot 2 disabled
     out = ops.zo_axpy(theta, path="w", seed=jnp.uint32(1), scale=1.0,
-                      active_idx=(idx, jnp.int32(2)), backend="scan")
+                      active_idx=(idx, jnp.int32(2)))
     moved = np.asarray(jnp.any(out != 1.0, axis=1))
     assert moved.tolist() == [True, False, True, False]

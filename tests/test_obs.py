@@ -296,16 +296,16 @@ def test_eager_step_emits_stage_spans():
     assert counters[obs.CTR_SELECTS] == 1
 
 
-@pytest.mark.parametrize("policy,backend,rows", [
-    ("stratified", "scan", 2),      # in place: the k = 4 - 2 active rows
-    ("uniform", "scan", 4),         # masked scan: every stacked row
-    ("stratified", "dense", 4),
-    ("stratified", "gather", 2),
+@pytest.mark.parametrize("policy,kw,rows", [
+    ("stratified", {"n_drop": 2}, 2),   # the k = 4 - 2 active rows
+    ("uniform", {"n_drop": 2, "policy": "uniform"}, 2),  # enabled slots
+    ("mezo", {"n_drop": 0}, 4),         # every stacked row
+    ("importance", {"n_drop": 2, "name": "importance"}, 2),
 ])
-def test_axpy_rows_swept_counts_rows_each_sweep_touches(policy, backend,
-                                                       rows):
-    """One eager LeZO step on a 4-layer toy with n_drop=2: three sweeps
-    (perturb, perturb, fused update) over two stacked leaves."""
+def test_axpy_rows_swept_counts_rows_each_sweep_touches(policy, kw, rows):
+    """One eager ZO step on a 4-layer toy: three sweeps (perturb,
+    perturb, fused update) over two stacked leaves, each sweeping only
+    the rows its slots enable."""
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
     from repro import estimators
@@ -314,8 +314,7 @@ def test_axpy_rows_swept_counts_rows_each_sweep_touches(policy, backend,
               "head": jnp.ones((3,))}
     spec = zo.build_spec(
         params, lambda p: "layers" if p.startswith("layers") else None)
-    est = estimators.build_estimator(spec, estimators.EstimatorConfig(
-        name="two_point", n_drop=2, policy=policy, backend=backend))
+    est = estimators.build_estimator(spec, estimators.EstimatorConfig(**kw))
     loss = lambda p, b, perturb=None: (
         (p["layers"]["w"].sum(0) @ b["x"]).sum() + p["head"].sum()) ** 2
     with jax.disable_jit():

@@ -5,7 +5,8 @@ Under jit a stage is a ``jax.named_scope``: its name lands in the
 device trace reports as each op's ``tf_op``.  Eagerly the same call is
 the installed tracer's span.  These tests check both on the CPU at the
 smoke size: every stage an eager step records appears in the compiled
-step's metadata, for each estimator and backend the trainer can run.
+step's metadata, for each estimator, selection policy and forward
+backend the trainer can run.
 """
 import codecs
 import glob
@@ -23,12 +24,12 @@ B, S = 2, 16
 
 # spec overrides -> the stages one step opens, eagerly and compiled
 CASES = {
-    "two_point-scan": ({"runtime.backend": "scan"},
-                       {obs.PERTURB, obs.FWD_PLUS, obs.FWD_MINUS,
-                        obs.LOSS_HEAD, obs.UPDATE}),
-    "two_point-dense": ({"runtime.backend": "dense"},
-                        {obs.PERTURB, obs.FWD_PLUS, obs.FWD_MINUS,
-                         obs.LOSS_HEAD, obs.UPDATE}),
+    "two_point-stratified": ({},
+                             {obs.PERTURB, obs.FWD_PLUS, obs.FWD_MINUS,
+                              obs.LOSS_HEAD, obs.UPDATE}),
+    "two_point-uniform": ({"optimizer.policy": "uniform"},
+                          {obs.PERTURB, obs.FWD_PLUS, obs.FWD_MINUS,
+                           obs.LOSS_HEAD, obs.UPDATE}),
     "virtual-paired": ({"runtime.forward_backend": "virtual_ref",
                         "runtime.paired_probes": True},
                        {obs.FWD_PAIR, obs.LOSS_HEAD, obs.UPDATE}),

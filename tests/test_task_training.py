@@ -27,8 +27,7 @@ def _zo_run(task, steps=300):
     tr = Trainer(MCFG, task,
                  TrainConfig(steps=steps, batch_size=32, eval_every=steps // 3,
                              log_every=0, seed=0),
-                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=1e-3, n_drop=1,
-                                    backend="scan"))
+                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=1e-3, n_drop=1))
     val = tr.make_dataset(256, seed_shift=1)
     zs_loss, zeroshot = tr.evaluate(tr.trainable, val)
     hist = tr.train(val_data=val)
@@ -58,8 +57,7 @@ def test_best_checkpoint_selected_on_task_metric():
     tr = Trainer(MCFG, task,
                  TrainConfig(steps=200, batch_size=32, eval_every=100,
                              log_every=0),
-                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=1e-3, n_drop=1,
-                                    backend="scan"))
+                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=1e-3, n_drop=1))
     val = tr.make_dataset(256, seed_shift=1)
     hist = tr.train(val_data=val)
     assert hist["metric_name"] == "accuracy"

@@ -6,8 +6,8 @@ at real OPT widths (and the MoE experts' grouped matmul at
 DeepSeek-V2-Lite's) for one v5e core and assert that Mosaic accepted it
 (a ``tpu_custom_call`` in the compiled HLO).  They catch what interpret
 mode cannot — casts Mosaic refuses, block shapes that break the (8, 128)
-tiling, kernels that overflow VMEM — at no chip time.  The ``scan``
-axpy's sweeps are checked the same way for what the CPU cannot show:
+tiling, kernels that overflow VMEM — at no chip time.  The parameter
+sweeps are checked the same way for what the CPU cannot show:
 that the TPU compiler updates the active rows of a donated stacked leaf
 in place, with no fill or copy of the whole stack.
 
@@ -29,7 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import estimators
 from repro.core import zo
 from repro.fused import matmul as fused_matmul
-from repro.kernels import ops, zo_axpy
+from repro.kernels import ops
 from repro.models import lm
 from repro.models.config import dense_lm
 
@@ -67,19 +67,6 @@ def _spec(shape, dtype, sharding):
 def _assert_mosaic(fn, *args):
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
-
-
-def test_zo_axpy_stacked_ffn_leaf(one_chip):
-    """OPT-1.3B's stacked FFN leaf: 24 layers of 2048 x 8192 bf16."""
-    def f(theta, mask, seed, scale, decay):
-        return zo_axpy.zo_axpy_2d(theta, mask, seed, scale, decay,
-                                  interpret=False)
-
-    _assert_mosaic(f, _spec((24, 2048 * 8192), jnp.bfloat16, one_chip),
-                   _spec((24,), jnp.bool_, one_chip),
-                   _spec((), jnp.uint32, one_chip),
-                   _spec((), jnp.float32, one_chip),
-                   _spec((), jnp.float32, one_chip))
 
 
 def test_pmatmul_stack_opt13b_ffn(one_chip):
@@ -234,7 +221,7 @@ def test_scan_sweep_updates_active_rows_in_place(one_chip):
 
     def sweep(theta, aidx, seed, scale):
         return ops.zo_axpy(theta, path="w", seed=seed, scale=scale,
-                           active_idx=aidx, backend="scan")
+                           active_idx=aidx)
 
     leaf = _spec((L,) + shape, jnp.bfloat16, one_chip)
     compiled = jax.jit(sweep, donate_argnums=0).lower(
@@ -248,11 +235,10 @@ def test_scan_sweep_updates_active_rows_in_place(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < row_bytes
 
 
-def test_two_point_step_sweeps_in_place(one_chip):
-    """A whole materialized LeZO step (perturb, probe, -2eps sweep,
-    probe, update) on a 10-layer bf16 model, params donated: each sweep
-    writes the buffer the probe before it read, with no copy of any
-    stacked leaf between them and no fresh stacked buffer."""
+def _aot_step_ops(one_chip, **ecfg):
+    """Compile a whole materialized LeZO step of a 10-layer bf16 model
+    with the params donated; -> (ops on stacked-leaf shapes, number of
+    stacked leaves)."""
     mcfg = dense_lm("opt-aot", 10, 256, 4, 4, 1024, 512, act="relu",
                     norm="ln", pos_emb="learned", tie_embeddings=True,
                     max_seq=128)
@@ -262,7 +248,7 @@ def test_two_point_step_sweeps_in_place(one_chip):
     step, init = estimators.make_step(
         lambda p, b, perturb=None: lm.lm_loss(mcfg, p, b, perturb=perturb),
         spec, estimators.EstimatorConfig(name="two_point", n_drop=7,
-                                         backend="scan"))
+                                         **ecfg))
     on_chip = lambda x: _spec(x.shape, x.dtype, one_chip)
     B, S = 2, 16
     batch = {"tokens": _spec((B, S), jnp.int32, one_chip),
@@ -275,10 +261,27 @@ def test_two_point_step_sweeps_in_place(one_chip):
         _spec((), jnp.uint32, one_chip)).compile()
     stacked = [leaf for leaf, g in zip(jax.tree.leaves(params), spec.groups)
                if g is not None]
-    found = _ops_of_shapes(compiled.as_text(),
-                           {_hlo_shape(leaf) for leaf in stacked})
+    return (_ops_of_shapes(compiled.as_text(),
+                           {_hlo_shape(leaf) for leaf in stacked}),
+            len(stacked))
+
+
+def test_two_point_step_sweeps_in_place(one_chip):
+    """A whole materialized LeZO step (perturb, probe, -2eps sweep,
+    probe, update), params donated: each sweep writes the buffer the
+    probe before it read, with no copy of any stacked leaf between them
+    and no fresh stacked buffer."""
+    found, n_stacked = _aot_step_ops(one_chip)
     # three sweeps, each one in-place row write per stacked leaf
-    assert found["dynamic-update-slice"] == 3 * len(stacked), found
+    assert found["dynamic-update-slice"] == 3 * n_stacked, found
+    assert not any(found[op] for op in _WHOLE_LEAF), found
+
+
+def test_uniform_step_sweeps_in_place(one_chip):
+    """The same under the paper's uniform policy, whose slots carry a
+    traced count: the sweeps stay in place."""
+    found, n_stacked = _aot_step_ops(one_chip, policy="uniform")
+    assert found["dynamic-update-slice"] == 3 * n_stacked, found
     assert not any(found[op] for op in _WHOLE_LEAF), found
 
 
@@ -298,8 +301,7 @@ def test_dsv2lite_stage_step_sweeps_experts_in_place(one_chip):
     spec = zo.build_spec(params, lm.zo_group_fn)
     step, init = estimators.make_step(
         lambda p, b, perturb=None: lm.lm_loss(mcfg, p, b, perturb=perturb),
-        spec, estimators.EstimatorConfig(name="two_point", n_drop=5,
-                                         backend="scan"))
+        spec, estimators.EstimatorConfig(name="two_point", n_drop=5))
     on_chip = lambda x: _spec(x.shape, x.dtype, one_chip)
     B, S = 16, 512
     batch = {"tokens": _spec((B, S), jnp.int32, one_chip),

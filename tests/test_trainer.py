@@ -18,8 +18,7 @@ def test_lezo_converges():
     tr = Trainer(MCFG, TASK,
                  TrainConfig(steps=200, batch_size=16, eval_every=0,
                              log_every=50),
-                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1,
-                                    backend="scan"))
+                 zo_cfg=zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1))
     h = tr.train()
     assert h["loss"][-1] < h["loss"][0] - 0.5
 
@@ -85,8 +84,7 @@ def test_zo_momentum_beats_zo_sgd():
         tr = Trainer(MCFG, TASK,
                      TrainConfig(steps=120, batch_size=16, eval_every=0,
                                  log_every=119, mode=mode),
-                     zo_cfg=zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1,
-                                        backend="scan"))
+                     zo_cfg=zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1))
         res[mode] = tr.train()["loss"][-1]
     assert res["zo_momentum"] < res["zo"]
 

@@ -20,7 +20,7 @@ from repro.train.trainer import Trainer, TrainConfig
 MCFG = opt.opt_tiny(layers=2, d_model=64, vocab=256)
 TASK = synthetic.TaskConfig(vocab=256, seq_len=32, n_classes=2,
                             signal_rate=0.35)
-ZCFG = zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1, backend="scan")
+ZCFG = zo.ZOConfig(eps=1e-3, lr=2e-4, n_drop=1)
 STEPS, CKPT_AT = 24, 8
 
 

@@ -30,22 +30,23 @@ def test_perturb_restore_identity():
     params = _params()
     spec = _spec(params)
     seed = jnp.uint32(11)
-    masks, idxs, _ = zo.stratified_select(spec, seed, 3)
-    p = zo.tree_axpy(params, spec, seed, 1e-3, masks, idxs)
-    p = zo.tree_axpy(p, spec, seed, -2e-3, masks, idxs)
-    p = zo.tree_axpy(p, spec, seed, 1e-3, masks, idxs)
+    _, idxs, _ = zo.stratified_select(spec, seed, 3)
+    p = zo.tree_axpy(params, spec, seed, 1e-3, idxs)
+    p = zo.tree_axpy(p, spec, seed, -2e-3, idxs)
+    p = zo.tree_axpy(p, spec, seed, 1e-3, idxs)
     for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-@pytest.mark.parametrize("backend", ["dense", "scan", "gather"])
-def test_fused_equals_unfused(backend):
+@pytest.mark.parametrize("policy", ["stratified", "uniform", "mezo"])
+def test_fused_equals_unfused(policy):
     params = _params()
     spec = _spec(params)
+    kw = {"n_drop": 0} if policy == "mezo" else {"n_drop": 2,
+                                                 "policy": policy}
     outs = []
     for fused in (True, False):
-        cfg = zo.ZOConfig(n_drop=2, lr=1e-3, backend=backend,
-                          fused_update=fused)
+        cfg = zo.ZOConfig(lr=1e-3, fused_update=fused, **kw)
         step = jax.jit(zo.make_zo_step(_loss, spec, cfg))
         p, _ = step(params, None, jnp.int32(0), jnp.uint32(7))
         outs.append(p)
@@ -71,7 +72,7 @@ def test_dropped_layers_untouched():
     seed = jnp.uint32(5)
     masks, idxs, _ = zo.stratified_select(spec, rng.fold(seed, jnp.uint32(0)),
                                           4)
-    cfg = zo.ZOConfig(n_drop=4, lr=1e-2, backend="gather")
+    cfg = zo.ZOConfig(n_drop=4, lr=1e-2)
     step = jax.jit(zo.make_zo_step(_loss, spec, cfg))
     p, _ = step(params, None, jnp.int32(0), seed)
     m = np.asarray(masks["blk"])

@@ -4,13 +4,13 @@ Tier-1 (`make test-fast`, `pytest -m "not slow"`) is the per-push CI
 gate and must stay under ~90s on an idle CPU; tier-2 (`make test`) is
 everything.  Tests land here when they measure over ~2-3s on the CI
 reference box (`pytest --durations=0`) — mostly convergence runs, the
-full kernel-backend x estimator matrices, decode-consistency sweeps,
+full selection-policy x estimator matrices, decode-consistency sweeps,
 and the heavyweight arch smokes.
 
 Patterns are fnmatch'd against the full node id, so individual
 parametrized cases can be tiered while cheap siblings of the same test
 stay in tier-1 as representatives (e.g. ``two_point_bit_identical
-[False-dense]`` remains fast while the other seven cases are tier-2).
+[False-mezo]`` remains fast while the other five cases are tier-2).
 Decorator ``@pytest.mark.slow`` still works and is preferred for tests
 that are slow by design (end-to-end training); this manifest exists so
 per-case tiering doesn't require rewriting parametrize lists.
@@ -29,20 +29,13 @@ SLOW_NODE_PATTERNS = [
     #    stack itself is covered fast by the task/trainer/kernel tests
     "tests/test_archs_smoke.py::test_arch_smoke[*",
     # -- estimator subsystem: full matrices are tier-2; the cheapest
-    #    bit-identical case and the dense/bf16 kernel cases stay tier-1
+    #    bit-identical case and the static-slot kernel cases stay tier-1
     "tests/test_estimators.py::test_trainer_selects_estimators",
     "tests/test_estimators.py::test_one_sided_q_chunk_equivalent",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[one_sided-*",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[averaged-*",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[importance-1-scan]",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[importance-1-gather]",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[importance-1-pallas]",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[two_point-1-scan]",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[two_point-1-gather]",
-    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[two_point-1-pallas]",
+    "tests/test_estimators.py::test_backend_matches_dense_per_estimator[*",
     "tests/test_estimators.py::test_two_point_bit_identical_to_legacy[True-*",
-    "tests/test_estimators.py::test_two_point_bit_identical_to_legacy[False-gather]",
-    "tests/test_estimators.py::test_two_point_bit_identical_to_legacy[False-scan]",
+    "tests/test_estimators.py::test_two_point_bit_identical_to_legacy[False-stratified]",
+    "tests/test_estimators.py::test_two_point_bit_identical_to_legacy[False-uniform]",
     "tests/test_estimators.py::test_averaged_q1_matches_two_point",
     "tests/test_estimators.py::test_dropped_layers_untouched_under_estimators",
     "tests/test_estimators.py::test_one_sided_converges_quadratic",
@@ -67,15 +60,14 @@ SLOW_NODE_PATTERNS = [
     "tests/test_moe.py::test_capacity_drop_bounded",
     "tests/test_zo_adaptive.py::test_momentum_matches_explicit_buffer",
     "tests/test_peft.py::test_prefix_changes_forward",
-    # -- ZO core / kernels: the scan sweeps and the 64Ki boundary tiles;
-    #    gather/pallas/dense cases stay tier-1 as backend representatives
+    # -- ZO core / kernels: the (vector, count) slot forms and the rows
+    #    around 64Ki elements; static slots stay tier-1 as representatives
     "tests/test_zo.py::test_fused_equals_unfused[*",
     "tests/test_zo.py::test_perturb_restore_identity",
-    "tests/test_kernels.py::test_backend_matches_dense[scan-float32-*",
-    "tests/test_kernels.py::test_backend_matches_dense[scan-bfloat16-*",
-    "tests/test_kernels.py::test_backend_matches_dense[gather-float32-*",
-    # ragged/boundary tiles are pinned fast by test_kernels_golden.py
-    "tests/test_kernels.py::test_pallas_tile_boundaries[*",
+    "tests/test_kernels.py::test_backend_matches_dense[pair_one_off-*",
+    "tests/test_kernels.py::test_backend_matches_dense[pair_all_on-float32-*",
+    # ragged rows are pinned fast by test_kernels_golden.py
+    "tests/test_kernels.py::test_sweep_row_lengths[*",
     "tests/test_rng.py::test_layer_ids_subset",
     "tests/test_estimators.py::test_one_sided_bias_quadratic",
     # -- fused virtual-perturbation runtime: the acceptance gates
