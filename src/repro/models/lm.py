@@ -268,8 +268,37 @@ def logits_fn(cfg, params, hidden):
     return (hidden @ _head_matrix(cfg, params)).astype(F32)
 
 
-def chunked_ce(cfg, params, hidden, labels, loss_mask, perturb=None):
+def head_widths(S: int) -> Tuple[int, ...]:
+    """The trailing windows ``chunked_ce`` can run the head over: powers
+    of two from 8 below S, then S itself (the whole sequence)."""
+    ws, w = [], 8
+    while w < S:
+        ws.append(w)
+        w *= 2
+    return tuple(ws) + (S,)
+
+
+def head_rung(loss_mask):
+    """Index into ``head_widths(S)`` of the narrowest trailing window
+    that holds every nonzero of ``loss_mask`` (..., S); an all-zero
+    mask takes the narrowest.  Computed on the device."""
+    S = loss_mask.shape[-1]
+    cols = jnp.any(loss_mask != 0, axis=tuple(range(loss_mask.ndim - 1)))
+    need = jnp.where(jnp.any(cols), S - jnp.argmax(cols), 0)
+    return jnp.searchsorted(jnp.asarray(head_widths(S)), need).astype(
+        jnp.int32)
+
+
+def chunked_ce(cfg, params, hidden, labels, loss_mask, perturb=None,
+               rung=None):
     """Mean CE over masked positions without materializing (B,S,V) logits.
+
+    The head runs only over the trailing window ``head_widths(S)[rung]``
+    (``rung`` defaults to ``head_rung(loss_mask)``; ``lax.switch``
+    picks the window on the device): every masked position lies inside
+    it, so the loss is the same mean over the same positions.  A caller
+    that vmaps over batch shards passes the whole batch's ``rung`` in
+    unbatched, so the switch stays a switch.
 
     Under a paired ctx ``hidden`` is (P*B, S, D) (p-major); each probe's
     CE runs the *literally unpaired* program on its slice (a Python loop
@@ -278,17 +307,20 @@ def chunked_ce(cfg, params, hidden, labels, loss_mask, perturb=None):
     the fused scan body is not stable across batch shapes, so a stacked
     head reduction cannot make that guarantee (the transformer blocks,
     where the W traffic lives, still share the paired pass)."""
+    if rung is None:
+        rung = head_rung(loss_mask)
     P = 0 if (perturb is None or perturb.pair is None) else perturb.pair.n
     if P:
         B0 = hidden.shape[0] // P
         return jnp.stack([
             chunked_ce(cfg, params, hidden[pi * B0:(pi + 1) * B0], labels,
-                       loss_mask, perturb=perturb.probe(pi))
+                       loss_mask, perturb=perturb.probe(pi), rung=rung)
             for pi in range(P)])
     B, S, D = hidden.shape
-    chunk = min(CE_CHUNK, S)
-    assert S % chunk == 0
-    n = S // chunk
+    widths = head_widths(S)
+    tr = obs.get_tracer()
+    if tr.enabled and not obs.tracing():
+        tr.count(obs.CTR_LOSS_HEAD_ROWS, B * widths[int(rung)])
     W = _head_matrix(cfg, params)
     if perturb is not None:
         # tied head reads embed/tok through a transpose: trans counters
@@ -296,7 +328,6 @@ def chunked_ce(cfg, params, hidden, labels, loss_mask, perturb=None):
         head = perturb.leaf("embed/tok" if cfg.tie_embeddings else "head/w")
         head_kw = ({"trans": True, "ld": cfg.d_model}
                    if cfg.tie_embeddings else {})
-    resh = lambda a: a.reshape(B, n, chunk, *a.shape[2:]).swapaxes(0, 1)
 
     def body(carry, inp):
         h, y, m = inp
@@ -309,25 +340,37 @@ def chunked_ce(cfg, params, hidden, labels, loss_mask, perturb=None):
         nll = (lse - gold) * m
         return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(m)), None
 
-    (tot, cnt), _ = lax.scan(body, (jnp.zeros((), F32), jnp.zeros((), F32)),
-                             (resh(hidden), resh(labels), resh(loss_mask.astype(F32))))
-    return tot / jnp.maximum(cnt, 1.0)
+    def window(w, h, y, m):
+        chunk = min(CE_CHUNK, w)
+        assert w % chunk == 0
+        n = w // chunk
+        resh = lambda a: (a[:, S - w:].reshape(B, n, chunk, *a.shape[2:])
+                          .swapaxes(0, 1))
+        (tot, cnt), _ = lax.scan(
+            body, (jnp.zeros((), F32), jnp.zeros((), F32)),
+            (resh(h), resh(y), resh(m.astype(F32))))
+        return tot / jnp.maximum(cnt, 1.0)
+
+    return lax.switch(rung, [functools.partial(window, w) for w in widths],
+                      hidden, labels, loss_mask)
 
 
-def lm_loss(cfg: ModelConfig, params, batch, aux_coef=0.0, perturb=None):
+def lm_loss(cfg: ModelConfig, params, batch, aux_coef=0.0, perturb=None,
+            rung=None):
     """batch: {tokens (B,S) int32, labels (B,S) int32, loss_mask (B,S)} or
     {embeds (B,S,D), labels, loss_mask} for stub-frontend archs.
 
     ``perturb`` (fused.PerturbCtx): evaluate loss(theta + s*eps*z)
     virtually — see forward().  A paired ctx (``perturb.pair``) runs all
     P stacked probes through ONE forward and returns a (P,) loss vector
-    (probe order = ctx order; ``fused.make_pair_ctx`` puts +eps first)."""
+    (probe order = ctx order; ``fused.make_pair_ctx`` puts +eps first).
+    ``rung``: the loss head's window, as ``chunked_ce`` takes it."""
     hidden, _, aux = forward(cfg, params, batch.get("tokens"),
                              embeds=batch.get("embeds"), mode="train",
                              perturb=perturb)
     with obs.stage(obs.LOSS_HEAD):
         loss = chunked_ce(cfg, params, hidden, batch["labels"],
-                          batch["loss_mask"], perturb=perturb)
+                          batch["loss_mask"], perturb=perturb, rung=rung)
     return loss + aux_coef * aux
 
 

@@ -39,7 +39,8 @@ from repro.obs.runlog import (DEFAULT_RUNS_DIR, RunDir, RunLog, list_runs,
 from repro.obs.runtime import NULL_SESSION, Session, session
 from repro.obs.sinks import (JSONLSink, RingSink, read_jsonl,
                              spans_from_jsonl)
-from repro.obs.trace import (CTR_AXPY, CTR_AXPY_ROWS, CTR_MOE_GMM_ROWS,
+from repro.obs.trace import (CTR_AXPY, CTR_AXPY_ROWS, CTR_LOSS_HEAD_ROWS,
+                             CTR_MOE_GMM_ROWS,
                              CTR_MOE_ROUTED, CTR_PROBES, CTR_SELECTS,
                              CTR_WLOAD, CTR_ZREGEN,
                              FWD_BASE, FWD_MINUS, FWD_PAIR, FWD_PLUS,
@@ -51,7 +52,8 @@ from repro.obs.trace import (CTR_AXPY, CTR_AXPY_ROWS, CTR_MOE_GMM_ROWS,
                              set_tracer, stage, tracing, use)
 
 __all__ = [
-    "CTR_AXPY", "CTR_AXPY_ROWS", "CTR_MOE_GMM_ROWS", "CTR_MOE_ROUTED",
+    "CTR_AXPY", "CTR_AXPY_ROWS", "CTR_LOSS_HEAD_ROWS", "CTR_MOE_GMM_ROWS",
+    "CTR_MOE_ROUTED",
     "CTR_PROBES",
     "CTR_SELECTS", "CTR_WLOAD", "CTR_ZREGEN",
     "Counter", "DEFAULT_RUNS_DIR", "FWD_BASE", "FWD_MINUS", "FWD_PAIR",

@@ -71,6 +71,7 @@ CTR_AXPY_ROWS = "axpy_rows_swept"   # stacked-leaf rows a sweep touches
 CTR_SELECTS = "layer_selections"
 CTR_MOE_ROUTED = "moe_assignments_routed"  # token-expert pairs computed
 CTR_MOE_GMM_ROWS = "moe_gmm_rows_computed"  # grouped-matmul kernel tile rows
+CTR_LOSS_HEAD_ROWS = "loss_head_rows"  # B x the loss head's window, a probe
 # Fused-forward W-traffic counters (repro.fused): VMEM tile loads of
 # weight matrices and z-tile regenerations per step — the structural
 # numbers the paired ±εz probe halves (counted host-side from the same

@@ -197,9 +197,9 @@ class Trainer:
     def _build_loss(self):
         mcfg, tcfg = self.mcfg, self.tcfg
 
-        def base_loss(trainable, batch, perturb=None):
+        def base_loss(trainable, batch, perturb=None, rung=None):
             return lm.lm_loss(mcfg, self._to_model(trainable), batch,
-                              perturb=perturb)
+                              perturb=perturb, rung=rung)
 
         if tcfg.n_loss_shards <= 1 or tcfg.quorum >= 1.0:
             self.loss_fn = base_loss
@@ -217,8 +217,11 @@ class Trainer:
             shards = jax.tree.map(
                 lambda x: x.reshape(n_sh, x.shape[0] // n_sh, *x.shape[1:]),
                 batch)
-            losses = jax.vmap(
-                lambda b: base_loss(trainable, b, perturb=perturb))(shards)
+            # the head's window from the whole batch, outside the vmap:
+            # a batched index would make XLA run every window
+            rung = lm.head_rung(batch["loss_mask"])
+            losses = jax.vmap(lambda b: base_loss(
+                trainable, b, perturb=perturb, rung=rung))(shards)
             w = arrived.astype(jnp.float32)
             return jnp.sum(losses * w) / jnp.sum(w)
 

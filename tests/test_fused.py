@@ -444,6 +444,28 @@ def test_paired_loss_bitwise_matches_two_forwards(fb):
         assert np.array_equal(np.asarray(want), np.asarray(pair[i])), sign
 
 
+@pytest.mark.parametrize("fb", ["virtual_ref", "virtual"])
+def test_paired_loss_bitwise_matches_two_forwards_tail_mask(fb):
+    """On a loss mask over the last tokens only (the head runs over a
+    trailing window), the paired [l+, l-] stays bit-identical to the two
+    single-probe forwards."""
+    mcfg = _tiny_cfg()
+    params = lm.init_params(mcfg, jax.random.PRNGKey(0))
+    spec = zo.build_spec(params, lm.zo_group_fn)
+    batch = _batch(mcfg.vocab, B=2, S=32)
+    batch["loss_mask"] = jnp.zeros((2, 32), jnp.float32).at[:, -3:].set(1.0)
+    assert lm.head_widths(32)[int(lm.head_rung(batch["loss_mask"]))] == 8
+    seed = jnp.uint32(17)
+    masks, _, _ = zo.stratified_select(spec, seed, 1)
+    pair = lm.lm_loss_pair(mcfg, params, batch,
+                           perturb=fused.make_pair_ctx(seed, 1e-3, masks,
+                                                       fb))
+    for i, sign in enumerate((1.0, -1.0)):
+        ctx = fused.make_ctx(seed, sign * 1e-3, masks, fb)
+        want = lm.lm_loss(mcfg, params, batch, perturb=ctx)
+        assert np.array_equal(np.asarray(want), np.asarray(pair[i])), sign
+
+
 def test_stacked_probes_bitwise_match_sequential():
     """make_stack_ctx (one_sided's q probes, unshared seeds) returns a
     (P,) loss vector bit-identical to P single-probe forwards."""

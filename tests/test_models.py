@@ -226,3 +226,80 @@ def test_chunked_ce_matches_dense():
     gold = jnp.take_along_axis(lg, y[..., None], -1)[..., 0]
     want = jnp.sum((lse - gold) * m) / jnp.sum(m)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+
+
+def _ce_case(seed=0):
+    cfg = dense_lm("d", 1, 32, 2, 2, 64, 128, dtype="float32", max_seq=64)
+    params = lm.init_params(cfg, jax.random.PRNGKey(seed))
+    B, S = 2, 64
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, S, 32))
+    y = jnp.asarray(np.random.default_rng(seed).integers(0, 128, (B, S)))
+    return cfg, params, h, y
+
+
+def _dense_ce(cfg, params, h, y, m):
+    """The head over every position, then masked: the loss head's oracle."""
+    lg = lm.logits_fn(cfg, params, h)
+    lse = jax.nn.logsumexp(lg, -1)
+    gold = jnp.take_along_axis(lg, y[..., None], -1)[..., 0]
+    return jnp.sum((lse - gold) * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
+def _tail_mask(kind, B=2, S=64):
+    m = np.zeros((B, S), np.float32)
+    if kind == "last":
+        m[:, -1] = 1
+    elif kind == "span8":
+        m[:, -8:] = 1
+    elif kind == "row_varying":
+        m[0, -3:] = 1
+        m[1, -13:-2] = 1
+    elif kind == "early":            # one entry outside the last S/2
+        m[:, -1] = 1
+        m[1, 5] = 1
+    return jnp.asarray(m)
+
+
+@pytest.mark.parametrize("kind,width", [("last", 8), ("span8", 8),
+                                        ("row_varying", 16), ("early", 64),
+                                        ("zero", 8)])
+def test_windowed_ce_matches_dense(kind, width):
+    """The head runs over the narrowest trailing window holding the mask
+    (an early entry falls to the whole sequence), with the dense loss."""
+    from repro import obs
+    cfg, params, h, y = _ce_case()
+    m = _tail_mask(kind)
+    assert lm.head_widths(64) == (8, 16, 32, 64)
+    tr = obs.Tracer()
+    with obs.use(tr):
+        got = lm.chunked_ce(cfg, params, h, y, m)
+    assert tr.counters[obs.CTR_LOSS_HEAD_ROWS] == 2 * width
+    jitted = jax.jit(lambda h, y, m: lm.chunked_ce(cfg, params, h, y, m))
+    for loss in (got, jitted(h, y, m)):
+        np.testing.assert_allclose(float(loss),
+                                   float(_dense_ce(cfg, params, h, y, m)),
+                                   rtol=1e-5, atol=1e-7)
+    if kind == "early":              # the top rung is the whole sequence
+        top = len(lm.head_widths(64)) - 1
+        assert int(lm.head_rung(m)) == top
+        assert np.array_equal(
+            np.asarray(got),
+            np.asarray(lm.chunked_ce(cfg, params, h, y, m, rung=top)))
+
+
+def test_windowed_ce_grad_matches_dense():
+    """``jax.grad`` through the switch: the windowed head's gradient in
+    the head matrix and the hidden rows equals the dense head's, and the
+    differentiated program keeps its conditional."""
+    cfg, params, h, y = _ce_case(3)
+    m = _tail_mask("span8")
+    f = lambda p, h: lm.chunked_ce(cfg, p, h, y, m)
+    got = jax.grad(f, argnums=(0, 1))(params, h)
+    want = jax.grad(lambda p, h: _dense_ce(cfg, p, h, y, m),
+                    argnums=(0, 1))(params, h)
+    assert float(jnp.max(jnp.abs(got[1][:, :-8]))) == 0.0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+    assert "cond[" in str(jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(
+        params, h))

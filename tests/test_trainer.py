@@ -69,6 +69,27 @@ def test_quorum_still_converges():
     assert h["loss"][-1] < h["loss"][0] - 0.3
 
 
+def test_quorum_loss_windowed_head_matches_whole_sequence(monkeypatch):
+    """``quorum_loss`` vmaps over loss shards: the head's window comes from
+    the whole batch outside the vmap, so the switch stays a switch, and
+    the loss equals the head over every position (the top rung)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import lm
+    tr = Trainer(MCFG, TASK, TrainConfig(steps=1, batch_size=8, eval_every=0,
+                                         log_every=0, n_loss_shards=4,
+                                         quorum=0.75))
+    batch = tr._model_batch(tr.make_dataset(8))
+    assert float(jnp.sum(batch["loss_mask"][:, :-1])) == 0.0  # tail-only
+    got = tr.loss_fn(tr.trainable, batch)
+    assert "cond[" in str(jax.make_jaxpr(tr.loss_fn)(tr.trainable, batch))
+    S = batch["loss_mask"].shape[-1]
+    monkeypatch.setattr(lm, "head_rung",
+                        lambda m: jnp.int32(len(lm.head_widths(S)) - 1))
+    want = tr.loss_fn(tr.trainable, batch)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+
+
 def test_eval_accuracy_classification():
     tr = Trainer(MCFG, TASK, TrainConfig(steps=1, batch_size=4, eval_every=0,
                                          log_every=0))
